@@ -20,10 +20,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 async def main() -> None:
-    from _common import force_cpu_if_requested
-
-    force_cpu_if_requested()
-
     from hocuspocus_tpu.provider import HocuspocusProvider, HocuspocusProviderWebsocket
     from hocuspocus_tpu.server import Configuration, Server
 

@@ -170,10 +170,6 @@ async def plane_served(num_docs: int, bursts: int) -> dict:
 
 
 def main() -> None:
-    from _common import force_cpu_if_requested
-
-    force_cpu_if_requested()
-
     num_docs = int(os.environ.get("C3_DOCS", 200))
     burst = int(os.environ.get("C3_BURST", 100))
     server_docs = int(os.environ.get("C3_SERVER_DOCS", 8))

@@ -25,10 +25,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 async def main() -> None:
     import numpy as np
 
-    from _common import force_cpu_if_requested
-
-    force_cpu_if_requested()
-
     from hocuspocus_tpu.extensions import Redis
     from hocuspocus_tpu.net.mini_redis import MiniRedis
     from hocuspocus_tpu.provider import HocuspocusProvider

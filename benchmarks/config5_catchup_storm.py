@@ -121,9 +121,6 @@ def main() -> None:
     host_docs = int(os.environ.get("C5_HOST_DOCS", 200))
 
     # -- part 1: device SV diff -------------------------------------------
-    from _common import force_cpu_if_requested
-
-    force_cpu_if_requested()
     import jax
     import jax.numpy as jnp
 

@@ -23,9 +23,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main() -> None:
-    from _common import force_cpu_if_requested
-
-    force_cpu_if_requested()
     import numpy as np
 
     from hocuspocus_tpu.crdt import (

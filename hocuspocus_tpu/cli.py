@@ -240,7 +240,8 @@ def build_parser() -> argparse.ArgumentParser:
         type=int,
         default=1,
         help="per-device merge cells: 0 = one cell per visible chip, "
-        "N > 1 = exactly N cells (wrapping the device roster), 1 = the "
+        "N > 1 = exactly N cells, one per chip (more cells than chips "
+        "is an error off the CPU platform), 1 = the "
         "classic single-plane layout (default). --tpu-docs/--tpu-capacity "
         "are PER-CELL sizes; mutually exclusive with --tpu-shards "
         "(docs/guides/multi-device.md)",
@@ -510,7 +511,12 @@ def build_parser() -> argparse.ArgumentParser:
     return parser
 
 
-async def run(args: argparse.Namespace) -> None:
+def build_server(args: argparse.Namespace):
+    """The server `run` listens on: the extension stack and
+    configuration these parsed flags select. Separate from `run` so a
+    caller that needs the server object (chip_smoke.py drives the
+    `--tpu-serve` wiring in-process) gets exactly the stack the CLI
+    builds, not a hand-assembled lookalike."""
     from .extensions import Logger, SQLite, S3, Webhook
     from .server import Configuration, Server
 
@@ -585,13 +591,11 @@ async def run(args: argparse.Namespace) -> None:
             )
         )
     if args.tpu_merge or args.tpu_serve:
-        # importing .tpu pins the backend to CPU when JAX_PLATFORMS=cpu
-        # (see hocuspocus_tpu/tpu/__init__.py). The supervised extension
-        # defers ALL device work (kernel imports, discovery, compiles)
-        # to a deadline-bounded worker thread: a wedged or absent TPU
-        # runtime can no longer hang boot — the server serves in
-        # CPU-merge mode and the plane hot-attaches when the runtime
-        # comes up (docs/guides/tpu-supervisor.md).
+        # the supervised extension defers ALL device work (kernel
+        # imports, discovery, compiles) to a deadline-bounded worker
+        # thread: a wedged or absent TPU runtime cannot hang boot — the
+        # server serves in CPU-merge mode and the plane hot-attaches
+        # when the runtime comes up (docs/guides/tpu-supervisor.md).
         from .tpu import SupervisedTpuMergeExtension
 
         if args.tpu_devices != 1 and args.tpu_shards > 1:
@@ -681,9 +685,12 @@ async def run(args: argparse.Namespace) -> None:
                 relay_queue_limit=args.relay_queue_limit,
             )
         )
-        server = EdgeServer(configuration)
-    else:
-        server = Server(configuration)
+        return EdgeServer(configuration)
+    return Server(configuration)
+
+
+async def run(args: argparse.Namespace) -> None:
+    server = build_server(args)
     await server.listen(port=args.port, host=args.host)
 
     stop = asyncio.Event()

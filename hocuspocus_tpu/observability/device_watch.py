@@ -92,6 +92,9 @@ class CompileTracker:
         self.fresh_compiles = 0
         self.cache_hits = 0
         self.last_compile_s: Optional[float] = None
+        # fresh compiles AFTER mark_warmed() on a non-warmup dispatch:
+        # the "site shape" keys the warm grid missed, in arrival order
+        self.unexpected_compiles: list[str] = []
 
     def mark_warmed(self) -> None:
         """The warm grid completed: from here on, fresh compiles are
@@ -140,6 +143,7 @@ class CompileTracker:
             self.observe(site, shape, time.perf_counter() - started, warmup=warmup)
 
     def _note_unexpected_compile(self, site: str, label: str, seconds: float) -> None:
+        self.unexpected_compiles.append(f"{site} {label}")
         now = time.monotonic()
         self._recent.append(now)
         while self._recent and now - self._recent[0] > self.storm_window_s:
@@ -178,6 +182,7 @@ class CompileTracker:
             "shapes_seen": len(self._seen),
             "storms": sum(self.storms._values.values()),
             "warmed": self._warmed,
+            "unexpected_compiles": list(self.unexpected_compiles),
             "last_compile_s": self.last_compile_s,
         }
 

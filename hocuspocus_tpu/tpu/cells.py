@@ -209,7 +209,8 @@ class MultiDeviceMergeExtension(Extension):
         **extension_kwargs: Any,
     ) -> None:
         """devices: cells to build (0 = one per local device; a count
-        above the physical roster wraps, so CI's single forced-host CPU
+        above the physical roster is an error on an accelerator and
+        wraps on the CPU platform, so CI's single forced-host CPU
         device still runs an 8-cell plane). rebalance_interval_s <= 0
         disables the rebalancer (placement stays pure rendezvous).
         rebalance_ratio: a cell hotter than this multiple of the mean

@@ -560,14 +560,22 @@ def _tail_probe_one_rle(state: RleState) -> tuple:
 
 
 @jax.jit
-def tail_probe_rle(state: RleState, slots) -> jax.Array:
-    """(2B,) uint32 [clients..., clocks...] rank-tail ids for the B
-    requested rows (same contract as kernels.tail_probe)."""
+def health_probe_rle(state: RleState, slots) -> jax.Array:
+    """(2D + 2B,) uint32 [lengths..., overflows..., tail clients...,
+    tail clocks...]: the flush cycle's health readback for the RLE
+    arena (same contract as kernels.health_probe)."""
     from .kernels import gather_doc_rows
 
     sub = gather_doc_rows(state, slots)
     clients, clocks = jax.vmap(_tail_probe_one_rle)(sub)
-    return jnp.concatenate([clients, clocks])
+    return jnp.concatenate(
+        [
+            state.length.astype(jnp.uint32),
+            state.overflow.astype(jnp.uint32),
+            clients,
+            clocks,
+        ]
+    )
 
 
 @partial(jax.jit, static_argnames=("width",))

@@ -36,7 +36,6 @@ from .kernels import (
     KIND_INSERT,
     NONE_CLIENT,
     OpBatch,
-    extract_live_mask,
     make_empty_state,
 )
 from .lowering import DenseOp, DocLowerer, units_to_text
@@ -277,15 +276,18 @@ class MergePlane:
             self._append_field_sharding = NamedSharding(
                 mesh, PartitionSpec(None, None)
             )
+        elif device is not None:
+            # build the arena ON its chip (never a transient copy on
+            # the default device), then COMMIT it: jit follows committed
+            # input placement, so every step (flush, canary, warm,
+            # compact) runs on this device with no resharding
+            import jax
+
+            with jax.default_device(device):
+                state = self._make_empty(num_docs, capacity)
+            self.state = jax.device_put(state, device)
         else:
             self.state = self._make_empty(num_docs, capacity)
-            if device is not None:
-                # COMMIT the arena to its chip: jit follows committed
-                # input placement, so every step (flush, canary, warm,
-                # compact) runs on this device with no resharding
-                import jax
-
-                self.state = jax.device_put(self.state, device)
         self.docs: dict[str, PlaneDoc] = {}
         self.free: list[int] = list(range(num_docs - 1, -1, -1))
         self.slot_owner: dict[int, str] = {}  # slot -> doc name
@@ -325,8 +327,8 @@ class MergePlane:
         # with client == NONE_CLIENT meaning "empty row"; _tail_known
         # gates the whole check (False -> the column takes the full
         # integrate, and the slot joins _tail_dirty so the next flush
-        # cycle's health readback re-arms it with one fused tail_probe
-        # over the dirty slots — never an O(D) sweep). Rows start, and
+        # cycle's health readback (health_probe) re-arms it in the same
+        # read, over the dirty slots — never an O(D) sweep). Rows start, and
         # are cleared back to, known-empty; full-integrate columns and
         # residency compaction (rank remaps) invalidate.
         self.run_merge_enabled = True
@@ -414,7 +416,24 @@ class MergePlane:
             "flush_batches_fast": 0,
             "flush_fast_ops": 0,
             "flush_slow_ops": 0,
+            # warm-grid programs that failed to compile or run (the
+            # detail rows are in warm_failures)
+            "warm_failures": 0,
         }
+        # listen-time warm pass ledger (TpuMergeExtension.on_listen):
+        # entries in the pass, programs this plane compiled / found
+        # covered by the shared registry, wall seconds, and whether the
+        # pass ran to its end. A failed entry is kept as {site, shape,
+        # error} — /healthz (supervisor snapshot) and chip_smoke.py
+        # read both.
+        self.warm_stats: dict = {
+            "entries": 0,
+            "compiled": 0,
+            "covered": 0,
+            "seconds": 0.0,
+            "done": False,
+        }
+        self.warm_failures: "list[dict]" = []
         # last completed flush cycle's stage breakdown (exported as
         # gauges by observability/extension.py; reported by bench.py's
         # sparse-load pass): host build / upload / device+readback ms,
@@ -510,6 +529,22 @@ class MergePlane:
         if self.lane is not None:
             self.lane.note_dispatch(site, batches)
 
+    def note_warm_failure(self, site: str, shape, error: BaseException) -> None:
+        """Keep one failed warm-grid entry where health, counters and
+        the chip smoke read it. A live flush at this shape will fault
+        to the CPU path (cpu_fallbacks), so it is an error, not a note."""
+        from ..observability.device_watch import shape_label
+        from ..server import logger as _logger_mod
+
+        label = shape_label(shape)
+        detail = f"{type(error).__name__}: {error}"[:500]
+        self.counters["warm_failures"] += 1
+        self.warm_failures.append({"site": site, "shape": label, "error": detail})
+        _logger_mod.log_error(
+            f"plane warm grid: {site} {label} FAILED on a "
+            f"{self.num_docs}x{self.capacity} {self.arena} arena: {detail}"
+        )
+
     # -- arena dispatch ----------------------------------------------------
 
     def _make_empty(self, num_docs: int, capacity: int):
@@ -573,18 +608,20 @@ class MergePlane:
 
         return append_run_slots_sparse_fast
 
-    def _tail_probe_fn(self):
-        """The rank-tail id readback kernel for this arena: (state, (W,)
-        slots) -> (2W,) uint32 [clients..., clocks...]. Used by
-        _sync_health to re-arm tails the full-integrate path or a
-        compaction invalidated."""
+    def _health_probe_fn(self):
+        """The flush cycle's health readback kernel for this arena:
+        (state, (W,) slots) -> (2D + 2W,) uint32 [lengths...,
+        overflows..., tail clients..., tail clocks...]. _sync_health
+        reads everything it validates through this ONE program; the W
+        slots are the rows whose rank tail the full-integrate path or
+        a compaction invalidated (W = 0: none)."""
         if self.arena == "rle":
-            from .kernels_rle import tail_probe_rle
+            from .kernels_rle import health_probe_rle
 
-            return tail_probe_rle
-        from .kernels import tail_probe
+            return health_probe_rle
+        from .kernels import health_probe
 
-        return tail_probe
+        return health_probe
 
     # -- native text lane --------------------------------------------------
 
@@ -1186,36 +1223,12 @@ class MergePlane:
         with self._step_lock:
             for entry in shapes:
                 site, shape_key = self._warm_site(entry)
-                if site == "append_sparse":
-                    _, k, b = entry
-                    args = self._empty_append_batch(k, b)
-                    with self.compile_watch.track(site, shape_key, warmup=True):
-                        self.state, count = self._append_step_fn()(
-                            self.state, *args
-                        )
-                        int(count)  # completion barrier (data-dependent)
-                elif site == "tail_probe":
-                    _, w = entry
-                    probe = np.zeros((w,), np.int32)  # re-reads row 0
-                    with self.compile_watch.track(site, shape_key, warmup=True):
-                        np.asarray(
-                            self._tail_probe_fn()(
-                                self.state, self._upload_slots(probe)
-                            )
-                        )
-                elif site == "integrate_dense":
-                    k, b = entry
-                    ops = self._empty_batch(k)
-                    with self.compile_watch.track(site, shape_key, warmup=True):
-                        self.state, count = self._step_fn()(self.state, ops)
-                        int(count)  # completion barrier (data-dependent)
-                else:
-                    k, b = entry
-                    ops, slots = self._empty_sparse_batch(k, b)
-                    with self.compile_watch.track(site, shape_key, warmup=True):
-                        self.state, count = self._sparse_step_fn()(
-                            self.state, ops, slots
-                        )
+                step, args = self._warm_program(entry)
+                with self.compile_watch.track(site, shape_key, warmup=True):
+                    if site == "health_probe":
+                        np.asarray(step(self.state, *args))
+                    else:
+                        self.state, count = step(self.state, *args)
                         int(count)  # completion barrier (data-dependent)
                 self._note_dispatch("warmup")
                 dispatched = True
@@ -1310,15 +1323,14 @@ class MergePlane:
         the run-append fast path's ("append", K_max, B) ladder (same
         pinned-K discipline as the sparse integrate, plus the
         num_docs-wide routing the dense regime takes) and the
-        ("tail", W) probe widths _sync_health can dispatch. Kept out
+        ("health", W) readbacks _sync_health can dispatch. Kept out
         of warmup_shapes() so its (k, b)-pair contract — relied on by
         the supervisor grid checks — survives."""
         k_max = self._k_buckets()[-1]
         shapes: "list[tuple]" = [
             ("append", k_max, b) for b in self._b_buckets() + [self.num_docs]
         ]
-        widths = [16] if self.num_docs <= 16 else [16, self._TAIL_PROBE_MAX]
-        shapes += [("tail", w) for w in widths]
+        shapes += [("health", w) for w in self._probe_widths()]
         return shapes
 
     def _warm_site(self, entry: tuple) -> "tuple[str, tuple]":
@@ -1326,12 +1338,27 @@ class MergePlane:
         plain (k, b) integrate pairs or tagged aux entries."""
         if entry[0] == "append":
             return "append_sparse", (entry[1], entry[2])
-        if entry[0] == "tail":
-            return "tail_probe", (entry[1],)
+        if entry[0] == "health":
+            return "health_probe", (entry[1],)
         k, b = entry
         if b >= self.num_docs:
             return "integrate_dense", (k, self.num_docs)
         return "integrate_sparse", (k, b)
+
+    def _warm_program(self, entry: tuple) -> tuple:
+        """(step, args) for one warm-grid entry: the callable a live
+        flush dispatches at that shape — Pallas or XLA as the plane's
+        own seams decide — and all-noop arguments to follow the state.
+        warmup_compiles runs it; the AOT pre-flight lowers it."""
+        site, _ = self._warm_site(entry)
+        if site == "append_sparse":
+            return self._append_step_fn(), self._empty_append_batch(*entry[1:])
+        if site == "health_probe":  # re-reads row 0
+            slots = self._upload_slots(np.zeros((entry[1],), np.int32))
+            return self._health_probe_fn(), (slots,)
+        if site == "integrate_dense":
+            return self._step_fn(), (self._empty_batch(entry[0]),)
+        return self._sparse_step_fn(), self._empty_sparse_batch(*entry)
 
     def _empty_append_batch(self, k: int, b: int) -> tuple:
         """All-noop append fast-path args (run_len == 0 everywhere,
@@ -1636,10 +1663,9 @@ class MergePlane:
     def _sync_health(self) -> None:
         """ONE combined device->host readback per flush cycle.
 
-        Fetches lengths + overflow as a single array (each transfer
-        costs ~a full RTT on remote-attached runtimes) — this read is
-        also the completion barrier for every batch dispatched above,
-        by data dependence. The dispatched->validated snapshot is taken
+        Fetches lengths + overflow as a single array from a single
+        program (health_probe) — this read is also the completion
+        barrier for every batch dispatched above, by data dependence. The dispatched->validated snapshot is taken
         at the same point (under the step lock), so health checks
         compare device rows against exactly the ops the device has
         integrated, never against optimistically-ahead host logs. A
@@ -1648,15 +1674,12 @@ class MergePlane:
 
         When full-integrate columns (or a compaction) invalidated
         tracked rank tails, the dirty LIVE slots' tail ids ride the
-        same fused readback via the tail_probe kernel — one transfer,
-        never a second RTT — and re-arm the run-merge classifier for
-        the next cycle. At most _TAIL_PROBE_MAX slots re-arm per cycle
-        (two compiled probe widths, never an unbounded shape ladder);
+        same program's readback — one transfer, never a second RTT —
+        and re-arm the run-merge classifier for the next cycle. At
+        most _TAIL_PROBE_MAX slots re-arm per cycle (the compiled
+        widths are _probe_widths(), never an unbounded shape ladder);
         the remainder stay dirty for the next readback."""
-        import jax.numpy as jnp
-
-        probe_slots = None
-        probe_width = 0
+        probe_slots = np.zeros(0, np.intp)
         if self._tail_dirty and self.run_merge_enabled:
             live = sorted(
                 slot for slot in self._tail_dirty if self.slot_live[slot]
@@ -1665,30 +1688,24 @@ class MergePlane:
             if len(live) > self._TAIL_PROBE_MAX:
                 self._tail_dirty.update(live[self._TAIL_PROBE_MAX :])
                 live = live[: self._TAIL_PROBE_MAX]
-            if live:
-                probe_slots = np.asarray(live, np.intp)
-                probe_width = (
-                    16 if len(live) <= 16 else self._TAIL_PROBE_MAX
-                )
-        parts = [
-            self.state.length.astype(jnp.uint32),
-            self.state.overflow.astype(jnp.uint32),
-        ]
-        if probe_slots is not None:
-            padded = np.zeros(probe_width, np.int32)
-            padded[: probe_slots.size] = probe_slots  # pad: re-read slot 0
-            with self.compile_watch.track("tail_probe", (probe_width,)):
-                parts.append(
-                    self._tail_probe_fn()(self.state, self._upload_slots(padded))
-                )
+            probe_slots = np.asarray(live, np.intp)
+        probe_width = next(
+            w for w in self._probe_widths() if w >= probe_slots.size
+        )
+        padded = np.zeros(probe_width, np.int32)
+        padded[: probe_slots.size] = probe_slots  # pad: re-read slot 0
+        with self.compile_watch.track("health_probe", (probe_width,)):
+            combined = np.asarray(
+                self._health_probe_fn()(self.state, self._upload_slots(padded))
+            )
+        if probe_slots.size:
             self._note_dispatch("tail_probe")
-        combined = np.asarray(jnp.concatenate(parts))
         lengths = combined[: self.num_docs].astype(np.int64)
         self.last_lengths = lengths
         self.last_overflows = combined[self.num_docs : 2 * self.num_docs].astype(
             bool
         )
-        if probe_slots is not None:
+        if probe_slots.size:
             probe = combined[2 * self.num_docs :]
             n = probe_slots.size
             clients = probe[:n].astype(np.uint32)
@@ -1704,8 +1721,16 @@ class MergePlane:
         self.flush_epoch += 1
 
     # per-cycle cap on tail re-arms: bounds both the probe's device
-    # work and the compiled width ladder to {16, _TAIL_PROBE_MAX}
+    # work and the compiled width ladder (_probe_widths)
     _TAIL_PROBE_MAX = 256
+
+    def _probe_widths(self) -> "list[int]":
+        """Every tail-slot width _sync_health dispatches health_probe
+        at: 0 (nothing to re-arm: lengths + overflow only), 16, and the
+        per-cycle cap on planes that can hold more than 16 rows."""
+        if self.num_docs <= 16:
+            return [0, 16]
+        return [0, 16, self._TAIL_PROBE_MAX]
 
     def _drain_ops(self, k: int):
         """Pop up to k ops from every BUSY queue (Python + native lane)
@@ -2197,6 +2222,13 @@ class MergePlane:
                 return False
         return True
 
+    def _read_row(self, slot: int):
+        """One arena row as host arrays (the state's namedtuple type),
+        sliced on the device (kernels.read_doc_row)."""
+        from .kernels import read_doc_row
+
+        return type(self.state)(*map(np.asarray, read_doc_row(self.state, slot)))
+
     def text(self, name: str) -> Optional[str]:
         """Decode a plain-text document's live text from device state.
 
@@ -2241,14 +2273,15 @@ class MergePlane:
                     return None
                 clients, clocks, ranks, entries = expanded
             else:
-                live = np.asarray(extract_live_mask(self.state))[slot]
+                row = self._read_row(slot)
+                live = (np.arange(self.capacity) < row.length) & ~row.deleted
                 occupied = np.nonzero(live)[0]
-                ranks_all = np.asarray(self.state.rank)[slot][occupied]
+                ranks_all = row.rank[occupied]
                 order = np.argsort(ranks_all)
                 sel = occupied[order]
                 ranks = ranks_all[order]
-                clients = np.asarray(self.state.id_client)[slot][sel]
-                clocks = np.asarray(self.state.id_clock)[slot][sel]
+                clients = row.id_client[sel]
+                clocks = row.id_clock[sel]
                 entries = [log[i] for i in sel]
         out: list[int] = []
         i = 0
@@ -2316,12 +2349,13 @@ class MergePlane:
         content in the log, or a divergence): text() returns None."""
         from bisect import bisect_right
 
-        num = int(np.asarray(self.state.num_runs)[slot])
-        rcl = np.asarray(self.state.run_client)[slot][:num]
-        rck = np.asarray(self.state.run_clock)[slot][:num]
-        rln = np.asarray(self.state.run_len)[slot][:num]
-        rrk = np.asarray(self.state.run_rank)[slot][:num]
-        rdl = np.asarray(self.state.run_deleted)[slot][:num]
+        row = self._read_row(slot)
+        num = int(row.num_runs)
+        rcl = row.run_client[:num]
+        rck = row.run_clock[:num]
+        rln = row.run_len[:num]
+        rrk = row.run_rank[:num]
+        rdl = row.run_deleted[:num]
         keep = (rln > 0) & ~rdl
         order = np.argsort(rrk[keep])
         index = self.unit_off_index(doc, slot)
@@ -2606,12 +2640,17 @@ class TpuMergeExtension(Extension):
             from .scheduler import CLASS_CANARY, LaneDeferred
 
             loop = asyncio.get_event_loop()
-            # one lock acquisition per shape: early client syncs and
-            # unloads interleave between compiles instead of stalling
-            # for the whole warmup
-            for shape in (
-                self.plane.warmup_shapes() + self.plane.warmup_aux_shapes()
-            ):
+            plane = self.plane
+            stats = plane.warm_stats
+            started = time.perf_counter()
+
+            async def warm_one(site: str, shape_key, fn) -> bool:
+                """One warm entry under its own lane admission and lock
+                acquisition: early client syncs and unloads interleave
+                between compiles instead of stalling for the whole
+                grid. A failure is recorded and the grid goes on — the
+                remaining shapes may well compile. False = lane parked
+                (the re-attach warm pass retries)."""
                 ticket = None
                 if self.lane is not None:
                     try:
@@ -2619,52 +2658,51 @@ class TpuMergeExtension(Extension):
                             CLASS_CANARY, site="warmup", weight=1
                         )
                     except LaneDeferred:
-                        return  # parked: the re-attach warm pass retries
+                        return False
                 try:
-                    async with self.plane.flush_lock:
-                        await loop.run_in_executor(
-                            None,
-                            lambda s=shape: self.plane.warmup_compiles(
-                                s, shared=True
-                            ),
-                        )
-                except Exception:
-                    from ..server import logger as _logger_mod
-
-                    _logger_mod.log_error("plane compile warmup failed (continuing)")
-                    return
+                    async with plane.flush_lock:
+                        dispatched = await loop.run_in_executor(None, fn)
+                    # only warmup_compiles can answer False: the shared
+                    # registry already covered this shape
+                    stats["covered" if dispatched is False else "compiled"] += 1
+                except Exception as error:
+                    plane.note_warm_failure(site, shape_key, error)
                 finally:
                     if ticket is not None:
                         ticket.release(preempted=ticket.should_yield())
-            # from here every flush shape is compiled: a later fresh
-            # compile is the recompile-storm signal
-            self.plane.compile_watch.mark_warmed()
-            if self.serving is not None:
-                # one lock acquisition per gather width (mirrors the
-                # shape loop above): a lane-demote rebuild or an early
-                # sync serve slots in between compiles
-                for width in self.serving._gather_widths():
-                    ticket = None
-                    if self.lane is not None:
-                        try:
-                            ticket = await self.lane.admit(
-                                CLASS_CANARY, site="warmup", weight=1
-                            )
-                        except LaneDeferred:
-                            return
-                    try:
-                        async with self.plane.flush_lock:
-                            await loop.run_in_executor(
-                                None,
-                                lambda w=width: self.serving.warmup_gathers(w),
-                            )
-                    except Exception:
-                        from ..server import logger as _logger_mod
+                    stats["seconds"] = round(time.perf_counter() - started, 3)
+                return True
 
-                        _logger_mod.log_error("gather warmup failed (continuing)")
-                    finally:
-                        if ticket is not None:
-                            ticket.release()
+            entries = [
+                (
+                    *plane._warm_site(shape),
+                    lambda s=shape: plane.warmup_compiles(s, shared=True),
+                )
+                for shape in plane.warmup_shapes() + plane.warmup_aux_shapes()
+            ]
+            if self.serving is not None:
+                pack_w = self.serving._pack_width()
+                entries += [
+                    (
+                        "catchup_pack",
+                        (width, pack_w),
+                        lambda w=width: self.serving.warmup_gathers(w),
+                    )
+                    for width in self.serving._gather_widths()
+                ]
+                entries += [
+                    ("sv_diff", (width,), lambda w=width: self.serving.warmup_triage(w))
+                    for width in self.serving._TRIAGE_WIDTHS
+                ]
+            stats.update(entries=len(entries), compiled=0, covered=0, done=False)
+            for site, shape_key, fn in entries:
+                if not await warm_one(site, shape_key, fn):
+                    return
+            # from here every shape a live dispatch can take was
+            # attempted: a later fresh compile is the recompile-storm
+            # signal
+            plane.compile_watch.mark_warmed()
+            stats["done"] = True
 
         self._spawn_tracked(warm())
         self._schedule_residency()

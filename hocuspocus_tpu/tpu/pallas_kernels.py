@@ -270,18 +270,9 @@ def _integrate_pallas(state: DocState, ops: OpBatch, interpret: bool):
     count = jnp.sum(ops.kind != KIND_NOOP)
     # tie the count to a kernel output so fetching it is a completion
     # barrier for the integrate step by DATA DEPENDENCE, not by runtime
-    # program-atomicity assumptions (see bench.py sync() on why buffer
-    # readiness cannot be trusted here)
+    # program-atomicity assumptions
     count, _ = jax.lax.optimization_barrier((count, new_state.length))
     return new_state, count
-
-
-# Shapes whose Pallas compile failed on this process's backend. r02's
-# bench died because a Mosaic VMEM OOM propagated out of the flush; a
-# kernel failure must cost one fallback, not the server. Keyed by the
-# full (D, N, K) problem shape since any of them can change the
-# compiled program.
-_pallas_broken_shapes: set[tuple[int, int, int]] = set()
 
 
 def integrate_op_slots_pallas(
@@ -289,28 +280,17 @@ def integrate_op_slots_pallas(
 ) -> tuple[DocState, jax.Array]:
     """Drop-in equivalent of kernels.integrate_op_slots via Pallas.
 
-    Ops fields have shape (K, D). Falls back to the XLA scan path when
-    the doc count has no valid block factor, or — permanently for that
-    shape — when Mosaic rejects the kernel (e.g. a VMEM regression),
-    so a compile failure degrades throughput instead of availability.
+    Ops fields have shape (K, D). Takes the XLA scan path when the doc
+    count has no valid block factor — a shape decision, made before any
+    compile. A Mosaic compile or launch failure RAISES: the caller's
+    flush-fault rail (TpuMergeExtension._degrade_all_served, counted in
+    cpu_fallbacks) keeps the server available and the failure visible.
     """
     from .kernels import integrate_op_slots
 
-    shape = (state.id_client.shape[0], state.id_client.shape[1], ops.kind.shape[0])
-    if _pick_block(shape[0], shape[1]) == 0 or shape in _pallas_broken_shapes:
+    if _pick_block(state.id_client.shape[0], state.id_client.shape[1]) == 0:
         return integrate_op_slots(state, ops)
-    try:
-        return _integrate_pallas(state, ops, interpret)
-    except Exception as error:  # Mosaic/XLA compile or launch failure
-        _pallas_broken_shapes.add(shape)
-        import logging
-
-        logging.getLogger("hocuspocus_tpu.tpu").warning(
-            "pallas integrate failed at shape %s; falling back to XLA scan: %s",
-            shape,
-            str(error)[:500],
-        )
-        return integrate_op_slots(state, ops)
+    return _integrate_pallas(state, ops, interpret)
 
 
 def integrate_op_slots_fast(state: DocState, ops: OpBatch) -> tuple[DocState, jax.Array]:
@@ -345,28 +325,13 @@ def integrate_op_slots_sparse_pallas(
 ) -> tuple[DocState, jax.Array]:
     """Sparse dispatch via Pallas; ops fields are (K, B), slots (B,).
 
-    Falls back to the sparse XLA scan when B has no valid doc-block
-    factor (B < 8) or — permanently per shape — when Mosaic rejects
-    the kernel."""
+    Takes the sparse XLA scan when B has no valid doc-block factor
+    (B < 8); a Mosaic failure raises (see integrate_op_slots_pallas)."""
     from .kernels import integrate_op_slots_sparse
 
-    b = int(slots.shape[0])
-    capacity = state.id_client.shape[1]
-    shape = (b, capacity, ops.kind.shape[0])
-    if _pick_block(b, capacity) == 0 or shape in _pallas_broken_shapes:
+    if _pick_block(int(slots.shape[0]), state.id_client.shape[1]) == 0:
         return integrate_op_slots_sparse(state, ops, slots)
-    try:
-        return _integrate_sparse_pallas(state, ops, slots, interpret)
-    except Exception as error:  # Mosaic/XLA compile or launch failure
-        _pallas_broken_shapes.add(shape)
-        import logging
-
-        logging.getLogger("hocuspocus_tpu.tpu").warning(
-            "pallas sparse integrate failed at shape %s; falling back to XLA scan: %s",
-            shape,
-            str(error)[:500],
-        )
-        return integrate_op_slots_sparse(state, ops, slots)
+    return _integrate_sparse_pallas(state, ops, slots, interpret)
 
 
 def integrate_op_slots_sparse_fast(

@@ -326,36 +326,17 @@ def _integrate_pallas_rle(state: RleState, ops: OpBatch, interpret: bool):
     return new_state, count
 
 
-_pallas_rle_broken_shapes: set[tuple[int, int, int]] = set()
-
-
 def integrate_op_slots_rle_pallas(
     state: RleState, ops: OpBatch, *, interpret: bool = False
 ):
     """Drop-in equivalent of kernels_rle.integrate_op_slots_rle via
-    Pallas; falls back to the XLA scan path when no block factor fits
-    or — permanently per shape — when Mosaic rejects the kernel."""
+    Pallas; takes the XLA scan path when no block factor fits. A Mosaic
+    failure raises (see pallas_kernels.integrate_op_slots_pallas)."""
     from .kernels_rle import integrate_op_slots_rle
 
-    shape = (
-        state.run_client.shape[0],
-        state.run_client.shape[1],
-        ops.kind.shape[0],
-    )
-    if _pick_block_rle(shape[0], shape[1]) == 0 or shape in _pallas_rle_broken_shapes:
+    if _pick_block_rle(state.run_client.shape[0], state.run_client.shape[1]) == 0:
         return integrate_op_slots_rle(state, ops)
-    try:
-        return _integrate_pallas_rle(state, ops, interpret)
-    except Exception as error:
-        _pallas_rle_broken_shapes.add(shape)
-        import logging
-
-        logging.getLogger("hocuspocus_tpu.tpu").warning(
-            "pallas RLE integrate failed at shape %s; falling back to XLA scan: %s",
-            shape,
-            str(error)[:500],
-        )
-        return integrate_op_slots_rle(state, ops)
+    return _integrate_pallas_rle(state, ops, interpret)
 
 
 def integrate_op_slots_rle_fast(state: RleState, ops: OpBatch):
@@ -387,27 +368,13 @@ def _integrate_sparse_pallas_rle(state: RleState, ops: OpBatch, slots, interpret
 def integrate_op_slots_rle_sparse_pallas(
     state: RleState, ops: OpBatch, slots, *, interpret: bool = False
 ):
-    """Sparse RLE dispatch via Pallas; falls back to the sparse XLA scan
-    when B has no valid block factor or Mosaic rejects the shape."""
+    """Sparse RLE dispatch via Pallas; takes the sparse XLA scan when B
+    has no valid block factor. A Mosaic failure raises."""
     from .kernels_rle import integrate_op_slots_rle_sparse
 
-    b = int(slots.shape[0])
-    entries = state.run_client.shape[1]
-    shape = (b, entries, ops.kind.shape[0])
-    if _pick_block_rle(b, entries) == 0 or shape in _pallas_rle_broken_shapes:
+    if _pick_block_rle(int(slots.shape[0]), state.run_client.shape[1]) == 0:
         return integrate_op_slots_rle_sparse(state, ops, slots)
-    try:
-        return _integrate_sparse_pallas_rle(state, ops, slots, interpret)
-    except Exception as error:
-        _pallas_rle_broken_shapes.add(shape)
-        import logging
-
-        logging.getLogger("hocuspocus_tpu.tpu").warning(
-            "pallas sparse RLE integrate failed at shape %s; falling back: %s",
-            shape,
-            str(error)[:500],
-        )
-        return integrate_op_slots_rle_sparse(state, ops, slots)
+    return _integrate_sparse_pallas_rle(state, ops, slots, interpret)
 
 
 def integrate_op_slots_rle_sparse_fast(state: RleState, ops: OpBatch, slots):

@@ -464,7 +464,9 @@ class PlaneServing:
         import jax.numpy as jnp
 
         state = self.plane.state
-        idx = jnp.asarray(slot_indices, jnp.int32)
+        # uploaded with the plane's placement rules: on a pinned cell an
+        # uncommitted index lands on the default device (chip 0) first
+        idx = self.plane._upload_slots(np.asarray(slot_indices, np.int32))
         if self.plane.arena == "rle":
             return np.asarray(
                 jnp.stack(
@@ -535,8 +537,6 @@ class PlaneServing:
         exactly those). Tombstones arrive in arena order; the host
         sorts and merges identically to the full-row path, so the
         DeleteSet bytes emitted downstream are byte-identical."""
-        import jax.numpy as jnp
-
         plane = self.plane
         width = next(w for w in self._gather_widths() if w >= len(chunk))
         pack_w = self._pack_width()
@@ -544,7 +544,7 @@ class PlaneServing:
         rle = plane.arena == "rle"
         with plane._step_lock:  # never gather donated buffers mid-flush
             t0 = time.perf_counter()
-            slots_dev = jnp.asarray(padded, jnp.int32)
+            slots_dev = plane._upload_slots(np.asarray(padded, np.int32))
             shape_key = (width, pack_w)
             with plane.compile_watch.track("catchup_pack", shape_key):
                 if rle:
@@ -632,8 +632,6 @@ class PlaneServing:
         listen-time warm task — which passes one `width` per call so
         interactive work (sync serves, lane-demote rebuilds) interleaves
         between compiles instead of waiting out the whole ladder."""
-        import jax.numpy as jnp
-
         plane = self.plane
         pack_w = self._pack_width()
         widths = self._gather_widths() if width is None else [width]
@@ -644,7 +642,7 @@ class PlaneServing:
                 with plane.compile_watch.track(
                     "catchup_pack", shape_key, warmup=True
                 ):
-                    slots_dev = jnp.asarray([0] * w, jnp.int32)
+                    slots_dev = plane._upload_slots(np.zeros(w, np.int32))
                     if plane.arena == "rle":
                         from .kernels_rle import catchup_pack_rle
 
@@ -1015,10 +1013,6 @@ class PlaneServing:
     async def _drain_catchup_locked(self, batch: list) -> None:
         import asyncio
 
-        import jax.numpy as jnp
-
-        from .kernels import state_vector_diff
-
         plane = self.plane
         try:
             if plane.pending_ops() > 0:
@@ -1041,8 +1035,7 @@ class PlaneServing:
                 self.refresh()
             # triage rows: healthy, covering docs only (the rest resolve
             # to None and fall back to the CPU path)
-            rows: list[tuple] = []  # (local_sv, target_sv, columns, future)
-            width = 1
+            rows: list[tuple] = []  # (doc, local_sv, target_sv, columns, future)
             for name, document, sv_bytes, future in batch:
                 doc = self.doc_healthy(name)
                 if doc is None or not self.covers(name, document):
@@ -1055,7 +1048,6 @@ class PlaneServing:
                     future.done() or future.set_result(None)
                     continue
                 columns = sorted(set(local_sv) | set(target_sv))
-                width = max(width, len(columns))
                 rows.append((doc, local_sv, target_sv, columns, future))
             if not rows:
                 return
@@ -1084,34 +1076,29 @@ class PlaneServing:
                     except Exception:
                         future.set_result(None)
                 return
-            # pad to a power-of-two (B, C) so storm-size jitter doesn't
-            # recompile the kernel per request count
-            b = 1
-            while b < len(rows):
-                b *= 2
-            c = 1
-            while c < width:
-                c *= 2
-            server = np.zeros((b, c), np.int64)
-            client = np.zeros((b, c), np.int64)
-            for i, (doc, local_sv, target_sv, columns, _) in enumerate(rows):
-                for j, cid in enumerate(columns):
-                    server[i, j] = local_sv.get(cid, 0)
-                    client[i, j] = target_sv.get(cid, 0)
-            missing_from, missing_len = state_vector_diff(
-                jnp.asarray(server, jnp.int32), jnp.asarray(client, jnp.int32)
-            )
-            plane._note_dispatch("sync")
-            missing_from = np.asarray(missing_from)
-            missing_len = np.asarray(missing_len)
-            for i, (doc, local_sv, target_sv, columns, future) in enumerate(rows):
+            # every (doc, client) pair of the storm, flat: the diff is
+            # elementwise, so one fixed-width program serves any mix of
+            # request count and state-vector width
+            pairs = sum(len(columns) for _, _, _, columns, _ in rows)
+            server = np.zeros(pairs, np.int32)
+            client = np.zeros(pairs, np.int32)
+            at = 0
+            for _, local_sv, target_sv, columns, _ in rows:
+                for cid in columns:
+                    server[at] = local_sv.get(cid, 0)
+                    client[at] = target_sv.get(cid, 0)
+                    at += 1
+            missing_from, missing_len = self._triage(server, client)
+            at = 0
+            for doc, local_sv, target_sv, columns, future in rows:
+                first, at = at, at + len(columns)
                 if future.done():
                     continue
                 try:
                     sm = {
-                        cid: int(missing_from[i, j])
+                        cid: int(missing_from[first + j])
                         for j, cid in enumerate(columns)
-                        if missing_len[i, j] > 0
+                        if missing_len[first + j] > 0
                     }
                     future.set_result(self._encode_from_sm(doc, sm))
                 except Exception:
@@ -1119,6 +1106,47 @@ class PlaneServing:
         except Exception:
             for *_rest, future in batch:
                 future.done() or future.set_result(None)
+
+    # (doc, client) pairs per triage dispatch: a fixed ladder, so the
+    # size of a storm never compiles a program in the serving path; a
+    # storm above the widest runs in chunks of it
+    _TRIAGE_WIDTHS = (256, 4096, 65536)
+
+    def _triage(
+        self, server: np.ndarray, client: np.ndarray, warmup: bool = False
+    ) -> "tuple[np.ndarray, np.ndarray]":
+        """state_vector_diff over flat int32 clock pairs, on the
+        plane's own chip (the triage has no arena operand to follow,
+        so uncommitted inputs would run every cell's diff on the
+        default device). Returns (missing_from, missing_len)."""
+        import jax
+
+        from .kernels import state_vector_diff
+
+        plane = self.plane
+        widest = self._TRIAGE_WIDTHS[-1]
+        missing_from = np.empty_like(server)
+        missing_len = np.empty_like(server)
+        for at in range(0, server.size, widest):
+            n = min(widest, server.size - at)
+            width = next(w for w in self._TRIAGE_WIDTHS if w >= n)
+            padded = np.zeros((2, width), np.int32)
+            padded[0, :n] = server[at : at + n]
+            padded[1, :n] = client[at : at + n]
+            with plane.compile_watch.track("sv_diff", (width,), warmup=warmup):
+                diff_from, diff_len = state_vector_diff(
+                    jax.device_put(padded[0], plane.device),
+                    jax.device_put(padded[1], plane.device),
+                )
+                missing_from[at : at + n] = np.asarray(diff_from)[:n]
+                missing_len[at : at + n] = np.asarray(diff_len)[:n]
+            plane._note_dispatch("sync")
+        return missing_from, missing_len
+
+    def warmup_triage(self, width: int) -> None:
+        """Compile the catch-up triage program at one ladder width."""
+        zeros = np.zeros(width, np.int32)
+        self._triage(zeros, zeros, warmup=True)
 
     def build_broadcast(self, name: str) -> Optional[bytes]:
         """Merged update for ops integrated since the last broadcast.

@@ -22,14 +22,22 @@ from .kernels import DocState, OpBatch, integrate_op_slots, make_empty_state
 def enumerate_devices(count: int = 0) -> list:
     """The device roster for the per-chip cell plane (tpu/cells.py).
 
-    count <= 0 means "every local device" (the MULTICHIP capture's 8
-    chips); an explicit count larger than the physical roster wraps
-    (cell i pins to device i % n) so CI hosts with one forced-host CPU
-    device can still exercise an 8-cell plane, and a count smaller than
-    the roster uses the first `count` chips."""
+    count <= 0 means "every local device"; a count smaller than the
+    roster uses the first `count` chips. A count LARGER than the roster
+    is an error on an accelerator — wrapping would stack several cells'
+    arenas on one chip while the deployment believes it has one each.
+    On the CPU platform it wraps (cell i pins to device i % n) so CI
+    hosts with one forced-host device can still exercise an 8-cell
+    plane."""
     devices = jax.local_devices()
     if count <= 0:
         return list(devices)
+    if count > len(devices) and devices[0].platform != "cpu":
+        raise ValueError(
+            f"{count} device cells requested but only {len(devices)} "
+            f"{devices[0].platform} device(s) are visible; one cell per "
+            "chip is the contract (--tpu-devices 0 uses every chip)"
+        )
     return [devices[i % len(devices)] for i in range(count)]
 
 

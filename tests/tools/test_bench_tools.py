@@ -1,10 +1,9 @@
-"""Bench tooling: gate trajectory handling, SLO-verdict gating, probe cache.
+"""Bench tooling: gate trajectory handling and SLO-verdict gating.
 
 Covers the observability-loop plumbing around the scenario harness:
-`tools/bench_gate.py` must exit cleanly on an empty/fresh trajectory,
-gate on the scenario-suite SLO verdict when present, and surface
-capture staleness; `bench.py` must pay each backend-probe timeout at
-most once per process.
+`tools/bench_gate.py` must exit cleanly on an empty/fresh trajectory
+and gate on the scenario-suite SLO verdict when present; `bench.py`
+and `tools/bench_capture.py` must not measure, or cite, without a chip.
 """
 
 import importlib.util
@@ -12,8 +11,6 @@ import json
 import os
 import subprocess
 import sys
-
-import pytest
 
 _REPO_DIR = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -28,7 +25,6 @@ def _load(name: str, relpath: str):
 
 
 bench_gate = _load("_test_bench_gate", "tools/bench_gate.py")
-bench = _load("_test_bench", "bench.py")
 
 
 def _write(path, payload, mtime=None):
@@ -38,7 +34,7 @@ def _write(path, payload, mtime=None):
         os.utime(path, (mtime, mtime))
 
 
-def _artifact(suite_verdict=None, stale=False, stages=None, breached=()):
+def _artifact(suite_verdict=None, stages=None, breached=()):
     extra = {"backend": "cpu"}
     if stages is not None:
         extra["update_e2e"] = {
@@ -52,9 +48,6 @@ def _artifact(suite_verdict=None, stale=False, stages=None, breached=()):
                 "smoke": {"verdict": suite_verdict, "breached": list(breached)}
             },
         }
-    if stale:
-        extra["stale_capture"] = True
-        extra["capture_artifact"] = "benchmarks/results/old.json"
     return {"metric": "m", "value": 1.0, "unit": "x", "extra": extra}
 
 
@@ -143,63 +136,6 @@ def test_gate_pairwise_regression_still_detected(tmp_path, capsys):
     )
     assert bench_gate.main(["--dir", str(tmp_path)]) == 1
     assert "REGRESSION" in capsys.readouterr().out
-
-
-# -- capture staleness ---------------------------------------------------------
-
-
-def test_gate_stale_capture_warns_by_default(tmp_path, capsys):
-    _write(tmp_path / "BENCH_r01.json", _artifact(suite_verdict="pass", stale=True))
-    assert bench_gate.main(["--dir", str(tmp_path)]) == 0
-    assert "STALE capture" in capsys.readouterr().out
-
-
-def test_gate_stale_capture_fails_under_fail_stale(tmp_path):
-    _write(tmp_path / "BENCH_r01.json", _artifact(suite_verdict="pass", stale=True))
-    assert bench_gate.main(["--dir", str(tmp_path), "--fail-stale"]) == 1
-
-
-# -- bench.py probe cache ------------------------------------------------------
-
-
-def test_probe_timeout_paid_once_per_label(monkeypatch):
-    """A hung probe costs PROBE_TIMEOUT exactly once per env label per
-    process; repeats answer from the cache."""
-    bench._probe_cache.clear()
-    calls = []
-
-    def hang(*args, **kwargs):
-        calls.append(kwargs.get("env", {}).get("JAX_PLATFORMS", "<inherit>"))
-        raise subprocess.TimeoutExpired(cmd="probe", timeout=1)
-
-    monkeypatch.setattr(bench.subprocess, "run", hang)
-    assert bench._probe(None) is None
-    assert bench._probe(None) is None  # cached, no new subprocess
-    assert bench._probe("") is None
-    assert bench._probe("") is None
-    assert len(calls) == 2
-    assert bench._probe_cached(None) and bench._probe_cached("")
-    bench._probe_cache.clear()
-
-
-def test_probe_cache_keeps_live_backend(monkeypatch):
-    bench._probe_cache.clear()
-    calls = []
-
-    class FakeProc:
-        returncode = 0
-        stdout = "PROBE tpu 8\n"
-        stderr = ""
-
-    def probe_ok(*args, **kwargs):
-        calls.append(1)
-        return FakeProc()
-
-    monkeypatch.setattr(bench.subprocess, "run", probe_ok)
-    assert bench._probe(None) == "tpu"
-    assert bench._probe(None) == "tpu"
-    assert len(calls) == 1
-    bench._probe_cache.clear()
 
 
 def test_gate_extracts_overload_storm_interactive_p99():
@@ -455,29 +391,28 @@ def test_gate_wire_saturation_headroom_band_note(capsys, tmp_path):
         "headroom_ratio": 1.125,
         "headroom_within_2x": True,
     }
-    failures, notes = bench_gate.current_round_checks(payload, fail_stale=False)
+    failures, notes = bench_gate.current_round_checks(payload)
     assert not failures
     assert any("within 2x" in note for note in notes)
 
     payload["extra"]["wire_saturation"]["headroom_ratio"] = 5.0
     payload["extra"]["wire_saturation"]["headroom_within_2x"] = False
-    failures, notes = bench_gate.current_round_checks(payload, fail_stale=False)
+    failures, notes = bench_gate.current_round_checks(payload)
     assert not failures
     assert any("OUTSIDE the 2x" in note for note in notes)
 
 
-def test_capture_stale_summary_is_one_loud_line(tmp_path, monkeypatch):
-    """bench_capture's stale-headline summary: one line naming every
-    stale_capture round in the trajectory; silent when none are."""
-    bench_capture = _load("_test_bench_capture", "tools/bench_capture.py")
-    monkeypatch.setattr(bench_capture, "_REPO_DIR", str(tmp_path))
-    assert bench_capture.summarize_stale_rounds() is None
-    _write(tmp_path / "BENCH_r01.json", _artifact())
-    _write(tmp_path / "BENCH_r02.json", _artifact(stale=True))
-    _write(tmp_path / "BENCH_r03.json", _artifact(stale=True))
-    line = bench_capture.summarize_stale_rounds()
-    assert line is not None and line.count("\n") == 0
-    assert line.startswith("!!! STALE HEADLINES")
-    assert "2 of 3" in line
-    assert "BENCH_r02.json" in line and "BENCH_r03.json" in line
-    assert "BENCH_r01.json" not in line
+# -- no chip: nothing measured, nothing cited ----------------------------------
+
+
+def test_bench_exits_nonzero_off_tpu():
+    proc = subprocess.run(
+        [sys.executable, os.path.join(_REPO_DIR, "bench.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True,
+        text=True,
+        timeout=120,
+    )
+    assert proc.returncode == 3
+    assert proc.stdout.strip() == ""  # no JSON line: no number to mistake
+    assert "no TPU" in proc.stderr and "nothing measured" in proc.stderr

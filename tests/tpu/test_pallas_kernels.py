@@ -1,10 +1,12 @@
 """Pallas integrate kernel vs the XLA-scan reference path.
 
 Runs in Pallas interpret mode on the virtual CPU backend (conftest);
-the identical kernel code compiles via Mosaic on real TPU (bench.py).
+the identical kernel code compiles via Mosaic on real TPU — AOT for a
+v5e in test_chip_preflight.py, run and checked by chip_smoke.py.
 """
 
 import numpy as np
+import pytest
 
 from hocuspocus_tpu.tpu.kernels import (
     NONE_CLIENT,
@@ -143,89 +145,55 @@ def test_pick_block_model_covers_r02_oom_shape():
     assert _LIVE_BUFFERS * db * 5632 * 4 <= _VMEM_LIMIT
 
 
-def test_pallas_compile_failure_falls_back_to_xla(monkeypatch):
-    """A Mosaic failure must degrade to the XLA scan, then stop retrying."""
-    import hocuspocus_tpu.tpu.pallas_kernels as pk
+@pytest.mark.parametrize(
+    "module, jitted, entry, sparse",
+    [
+        ("pallas_kernels", "_integrate_pallas", "integrate_op_slots_pallas", False),
+        ("pallas_kernels", "_integrate_sparse_pallas", "integrate_op_slots_sparse_pallas", True),
+        ("pallas_kernels_rle", "_integrate_pallas_rle", "integrate_op_slots_rle_pallas", False),
+        (
+            "pallas_kernels_rle",
+            "_integrate_sparse_pallas_rle",
+            "integrate_op_slots_rle_sparse_pallas",
+            True,
+        ),
+    ],
+)
+def test_mosaic_failure_propagates(monkeypatch, module, jitted, entry, sparse):
+    """A kernel that does not compile RAISES out of every Pallas entry
+    point: no per-shape rescue onto the XLA scan hides the device. The
+    flush-fault rail (cpu_fallbacks) is what keeps the server up."""
+    import importlib
 
-    calls = {"pallas": 0}
+    from hocuspocus_tpu.tpu.kernels_rle import make_empty_rle_state
 
-    def boom(state, ops, interpret):
-        calls["pallas"] += 1
+    mod = importlib.import_module(f"hocuspocus_tpu.tpu.{module}")
+
+    def boom(*args):
         raise RuntimeError("Mosaic says no (simulated VMEM OOM)")
 
-    monkeypatch.setattr(pk, "_integrate_pallas", boom)
-    monkeypatch.setattr(pk, "_pallas_broken_shapes", set())
-    num_docs, capacity = 64, 256
-    state = make_empty_state(num_docs, capacity)
+    monkeypatch.setattr(mod, jitted, boom)
+    num_docs = 64
+    state = (
+        make_empty_rle_state(num_docs, 64)
+        if module.endswith("rle")
+        else make_empty_state(num_docs, 256)
+    )
+    width = 16 if sparse else num_docs
     ops = OpBatch(
-        kind=np.ones((2, num_docs), np.int32),
-        client=np.full((2, num_docs), 7, np.uint32),
-        clock=np.asarray([[0] * num_docs, [4] * num_docs], np.int32),
-        run_len=np.full((2, num_docs), 4, np.int32),
-        left_client=np.asarray(
-            [[NONE_CLIENT] * num_docs, [7] * num_docs], np.uint32
-        ),
-        left_clock=np.zeros((2, num_docs), np.int32),
-        right_client=np.full((2, num_docs), NONE_CLIENT, np.uint32),
-        right_clock=np.zeros((2, num_docs), np.int32),
+        kind=np.ones((2, width), np.int32),
+        client=np.full((2, width), 7, np.uint32),
+        clock=np.asarray([[0] * width, [4] * width], np.int32),
+        run_len=np.full((2, width), 4, np.int32),
+        left_client=np.asarray([[NONE_CLIENT] * width, [7] * width], np.uint32),
+        left_clock=np.asarray([[0] * width, [3] * width], np.int32),
+        right_client=np.full((2, width), NONE_CLIENT, np.uint32),
+        right_clock=np.zeros((2, width), np.int32),
     )
-    state, count = pk.integrate_op_slots_pallas(state, ops)
-    assert int(count) == 2 * num_docs  # the XLA path served the flush
-    assert (np.asarray(state.length) == 8).all()
-    assert calls["pallas"] == 1
-    # second flush at the same shape skips the broken compile entirely
-    state, _ = pk.integrate_op_slots_pallas(state, ops)
-    assert calls["pallas"] == 1
-    assert (num_docs, capacity, 2) in pk._pallas_broken_shapes
-
-
-def test_pallas_compiles_at_production_shape_on_tpu():
-    """Mosaic-compiles (not interpret) the bench shape on a real TPU.
-
-    Gated: needs the real chip, and the suite conftest pins this process
-    to the virtual CPU mesh — so the compile runs in a clean subprocess.
-    Run with HOCUSPOCUS_TPU_COMPILE_TEST=1 on TPU hardware; bench.py
-    exercises the same shape every round either way.
-    """
-    import os
-    import subprocess
-    import sys
-
-    import pytest
-
-    if os.environ.get("HOCUSPOCUS_TPU_COMPILE_TEST") != "1":
-        pytest.skip("set HOCUSPOCUS_TPU_COMPILE_TEST=1 on TPU hardware")
-    snippet = (
-        "import jax, numpy as np, jax.numpy as jnp\n"
-        "assert jax.default_backend() == 'tpu', jax.default_backend()\n"
-        "from hocuspocus_tpu.tpu.kernels import make_empty_state, OpBatch, NONE_CLIENT\n"
-        "import hocuspocus_tpu.tpu.pallas_kernels as pk\n"
-        "D, N, K = 8192, 5632, 64\n"
-        "state = make_empty_state(D, N)\n"
-        "ops = OpBatch(kind=jnp.ones((K, D), jnp.int32),\n"
-        "    client=jnp.full((K, D), 7, jnp.uint32),\n"
-        "    clock=jnp.broadcast_to(jnp.arange(K, dtype=jnp.int32)[:, None] * 16, (K, D)),\n"
-        "    run_len=jnp.full((K, D), 16, jnp.int32),\n"
-        "    left_client=jnp.broadcast_to(jnp.where(jnp.arange(K)[:, None] == 0,\n"
-        "        jnp.uint32(NONE_CLIENT), jnp.uint32(7)), (K, D)),\n"
-        "    left_clock=jnp.broadcast_to(jnp.maximum(jnp.arange(K, dtype=jnp.int32)[:, None] * 16 - 1, 0), (K, D)),\n"
-        "    right_client=jnp.full((K, D), NONE_CLIENT, jnp.uint32),\n"
-        "    right_clock=jnp.zeros((K, D), jnp.int32))\n"
-        "state, count = pk.integrate_op_slots_pallas(state, ops)\n"
-        "assert not pk._pallas_broken_shapes, pk._pallas_broken_shapes\n"
-        "assert int(np.asarray(state.length).sum()) == D * K * 16\n"
-        "print('TPU-COMPILE-OK')\n"
-    )
-    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
-    proc = subprocess.run(
-        [sys.executable, "-c", snippet],
-        capture_output=True,
-        text=True,
-        timeout=600,
-        env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    )
-    assert "TPU-COMPILE-OK" in proc.stdout, proc.stderr[-2000:]
+    args = (state, ops, np.arange(width, dtype=np.int32)) if sparse else (state, ops)
+    for _ in range(2):  # and it keeps raising: no shape is remembered as broken
+        with pytest.raises(RuntimeError, match="Mosaic says no"):
+            getattr(mod, entry)(*args)
 
 
 def test_sharded_pallas_step_matches_xla():

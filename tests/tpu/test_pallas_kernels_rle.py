@@ -1,8 +1,8 @@
 """Pallas RLE integrate kernel vs the vmapped XLA reference path.
 
 Runs in Pallas interpret mode on the virtual CPU backend (conftest);
-the identical kernel code compiles via Mosaic on real TPU (bench.py
-RLE section). Exact array equality is required: both paths apply the
+the identical kernel code compiles via Mosaic on real TPU
+(test_chip_preflight.py AOT, chip_checks.py on the chip). Exact array equality is required: both paths apply the
 same op sequence with the same append discipline, so every entry lane
 must match, not just the expanded unit order.
 """
@@ -78,34 +78,3 @@ def test_pick_block_rle_respects_vmem():
         db = _pick_block_rle(docs, entries)
         if db:
             assert _LIVE_BUFFERS * db * entries * 4 <= _VMEM_BUDGET
-
-
-def test_pallas_rle_compile_failure_falls_back(monkeypatch):
-    import hocuspocus_tpu.tpu.pallas_kernels_rle as pkr
-
-    calls = {"pallas": 0}
-
-    def boom(state, ops, interpret):
-        calls["pallas"] += 1
-        raise RuntimeError("Mosaic says no (simulated)")
-
-    monkeypatch.setattr(pkr, "_integrate_pallas_rle", boom)
-    monkeypatch.setattr(pkr, "_pallas_rle_broken_shapes", set())
-    num_docs, entries = 64, 64
-    state = make_empty_rle_state(num_docs, entries)
-    ops = OpBatch(
-        kind=np.ones((2, num_docs), np.int32),
-        client=np.full((2, num_docs), 7, np.uint32),
-        clock=np.asarray([[0] * num_docs, [4] * num_docs], np.int32),
-        run_len=np.full((2, num_docs), 4, np.int32),
-        left_client=np.asarray([[NONE_CLIENT] * num_docs, [7] * num_docs], np.uint32),
-        left_clock=np.asarray([[0] * num_docs, [3] * num_docs], np.int32),
-        right_client=np.full((2, num_docs), NONE_CLIENT, np.uint32),
-        right_clock=np.zeros((2, num_docs), np.int32),
-    )
-    state, count = pkr.integrate_op_slots_rle_pallas(state, ops)
-    assert int(count) == 2 * num_docs
-    assert (np.asarray(state.total_units) == 8).all()
-    assert calls["pallas"] == 1
-    state, _ = pkr.integrate_op_slots_rle_pallas(state, ops)
-    assert calls["pallas"] == 1  # broken shape not retried

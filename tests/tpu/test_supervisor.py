@@ -1,10 +1,10 @@
 """Plane supervisor: fault-tolerant TPU runtime lifecycle (tpu/supervisor.py).
 
-The round-5 verdict found the defect these tests pin down: a server
-configured with the TPU merge plane hung at boot, serving nothing,
-whenever the TPU runtime was wedged — exactly the failure mode of a
-dead device tunnel. The supervisor inverts the ownership: the plane is
-an accelerator the server may acquire, never a boot dependency.
+The defect these tests pin down: a server configured with the TPU
+merge plane hung at boot, serving nothing, whenever the TPU runtime was
+wedged (device discovery that never returns). The supervisor inverts
+the ownership: the plane is an accelerator the server may acquire,
+never a boot dependency.
 
 Chaos scenarios covered, with the invariant "hardware absence degrades
 throughput, never availability" checked in each:
@@ -252,6 +252,76 @@ async def test_late_init_hot_attaches_live_documents():
     finally:
         a.destroy()
         b.destroy()
+        await server.destroy()
+
+
+async def test_load_in_flight_at_ready_is_onboarded():
+    """A document whose load straddles the READY transition: its
+    after_load hook passed the extension while it was still
+    INITIALIZING, and it is not in `instance.documents` yet when the
+    attach sweep runs. The sweep must wait for the load and onboard it
+    — missed, the document stays on the CPU path for life."""
+    from hocuspocus_tpu.tpu.merge_plane import TpuMergeExtension
+
+    init_gate = threading.Event()
+    load_gate = asyncio.Event()
+    passed_the_plane_hook = asyncio.Event()
+
+    def late_factory():
+        init_gate.wait()
+        return TpuMergeExtension(
+            serve=True, num_docs=8, capacity=512, flush_interval_ms=1
+        )
+
+    async def slow_after_load(data):
+        # inline hooks run after every extension's: the supervised
+        # extension's after_load_document has already returned
+        passed_the_plane_hook.set()
+        await load_gate.wait()
+
+    # no canary inside the test: a breaker trip and recovery would run
+    # a second sweep and onboard what the attach sweep missed
+    ext = SupervisedTpuMergeExtension(
+        runtime_factory=late_factory, init_timeout=60.0, watchdog_interval=60.0
+    )
+    server = await new_hocuspocus(
+        extensions=[ext], after_load_document=slow_after_load
+    )
+    a = new_provider(server, name="in-flight")
+    try:
+        await asyncio.wait_for(passed_the_plane_hook.wait(), timeout=10)
+        instance = server.hocuspocus
+        assert ext.supervisor.state == STATE_INITIALIZING
+        assert "in-flight" in instance.loading_documents
+        assert "in-flight" not in instance.documents
+        init_gate.set()  # the runtime comes up while the load is in flight
+        await retryable_assertion(
+            lambda: _assert(ext.supervisor.state == STATE_READY)
+        )
+        assert "in-flight" in instance.loading_documents
+        load_gate.set()
+        await wait_synced(a)
+        await retryable_assertion(
+            lambda: _assert(
+                ext.runtime.is_served("in-flight"), ext.supervisor.snapshot()
+            )
+        )
+        # ... and really rides the plane from here on
+        b = new_provider(server, name="in-flight")
+        try:
+            await wait_synced(b)
+            broadcasts_before = ext.plane.counters["plane_broadcasts"]
+            a.document.get_text("t").insert(0, "served")
+            await retryable_assertion(
+                lambda: _assert(b.document.get_text("t").to_string() == "served")
+            )
+            assert ext.plane.counters["plane_broadcasts"] > broadcasts_before
+        finally:
+            b.destroy()
+    finally:
+        init_gate.set()
+        load_gate.set()
+        a.destroy()
         await server.destroy()
 
 

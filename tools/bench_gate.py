@@ -32,7 +32,7 @@ rounds and deliberate local runs):
 
     python tools/bench_gate.py                 # newest two BENCH_*.json
     python tools/bench_gate.py --tolerance 0.5 # allow +50% per stage
-    python tools/bench_gate.py --current BENCH_r06.json --previous BENCH_r05.json
+    python tools/bench_gate.py --current BENCH_b.json --previous BENCH_a.json
 
 Safety rails (exit 0 with a SKIP note, never a false alarm):
 - an empty or single-round trajectory ("no prior round — gate skipped"),
@@ -54,15 +54,11 @@ and the headroom model's predicted
 below `previous * (1 - tolerance)`; the ms floor does not apply to
 frames/s.
 
-Two checks look at the CURRENT round alone (they don't need a prior
-round, so they run even on a fresh trajectory):
-- the scenario-suite SLO verdict (`extra.scenario_suite.verdict`, from
-  the loadgen burn-rate harness): a `fail`/`error` verdict fails the
-  gate — a breached SLO is a regression even when every raw p99 moved
-  inside tolerance;
-- capture staleness (`extra.stale_capture`): a stale headline is
-  reported loudly, and fails the gate under `--fail-stale` (the
-  bench_capture workflow's enforcement hook).
+One check looks at the CURRENT round alone (it doesn't need a prior
+round, so it runs even on a fresh trajectory): the scenario-suite SLO
+verdict (`extra.scenario_suite.verdict`, from the loadgen burn-rate
+harness). A `fail`/`error` verdict fails the gate — a breached SLO is a
+regression even when every raw p99 moved inside tolerance.
 """
 
 from __future__ import annotations
@@ -283,7 +279,7 @@ def backend_of(payload: dict) -> "str | None":
     return extra.get("backend")
 
 
-def current_round_checks(payload: dict, fail_stale: bool) -> "tuple[list[str], list[str]]":
+def current_round_checks(payload: dict) -> "tuple[list[str], list[str]]":
     """Checks on the newest round alone -> (failures, notes)."""
     failures: "list[str]" = []
     notes: "list[str]" = []
@@ -326,16 +322,6 @@ def current_round_checks(payload: dict, fail_stale: bool) -> "tuple[list[str], l
                 f"band (ratio {ratio}) — the cost ledger's loop-site "
                 "partition may have drifted from the real loop thread"
             )
-    if extra.get("stale_capture"):
-        note = (
-            "STALE capture: headline value is a re-cited on-chip run "
-            f"({extra.get('capture_artifact', '?')}, "
-            f"mtime {extra.get('capture_mtime_utc', '?')})"
-        )
-        if fail_stale:
-            failures.append(note)
-        else:
-            notes.append(f"WARN {note}")
     return failures, notes
 
 
@@ -413,11 +399,6 @@ def main(argv: "list[str] | None" = None) -> int:
     parser.add_argument(
         "--dir", default=_REPO_DIR, help="directory holding BENCH_*.json"
     )
-    parser.add_argument(
-        "--fail-stale",
-        action="store_true",
-        help="treat a stale_capture headline in the current round as a failure",
-    )
     args = parser.parse_args(argv)
 
     if bool(args.current) != bool(args.previous):
@@ -444,9 +425,9 @@ def main(argv: "list[str] | None" = None) -> int:
         return 0
 
     # current-round checks run regardless of trajectory depth: the
-    # scenario-suite SLO verdict and capture staleness are properties of
-    # THIS round, not a comparison
-    failures, cur_notes = current_round_checks(current, args.fail_stale)
+    # scenario-suite SLO verdict is a property of THIS round, not a
+    # comparison
+    failures, cur_notes = current_round_checks(current)
 
     if prev_path is None:
         print(
