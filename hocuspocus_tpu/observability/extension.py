@@ -926,8 +926,9 @@ class Metrics(Extension):
         """On-demand `jax.profiler` capture: `GET /debug/profile?secs=N`
         traces the device for N seconds and returns the artifact
         directory (open it with TensorBoard's profile plugin or convert
-        with xprof). Device spans (`Tracer.device_span`) annotate the
-        capture via jax.profiler.TraceAnnotation."""
+        with xprof). While it runs, every `Tracer.span` site of the
+        program annotates the capture (jax.profiler.TraceAnnotation),
+        with or without `--trace`."""
         query = getattr(getattr(request, "rel_url", None), "query", None)
         if query is None:
             query = getattr(request, "query", None) or {}

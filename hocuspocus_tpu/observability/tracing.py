@@ -9,12 +9,36 @@ chain spans, merge-plane device-step spans, and — via `UpdateTraceBook`
 — end-to-end lifecycle traces that follow one update from the capture
 seam through the flush pipeline to broadcast, each stage a span sharing
 one monotonically increasing trace id. Spans export as plain dicts or as
-Chrome/Perfetto trace-event JSON (`export_chrome_trace`), and device
-spans bridge into the JAX profiler when a capture is active.
+Chrome/Perfetto trace-event JSON (`export_chrome_trace`).
+
+The live rule. `Tracer.span(name, **attrs)` is the one way to write a
+span site, and it is live whenever someone is looking:
+
+- `tracer.enabled` (an operator passed `--trace`): the span lands in the
+  ring, on `perf_counter`;
+- a `jax.profiler` capture is running (the program asks
+  `TraceAnnotation.is_enabled()` itself, and never imports jax to do
+  so): the span enters a `TraceAnnotation(name)`, so it appears in the
+  capture's `.xplane.pb` on the profiler's clock, beside the device's
+  ops — no flag, no configuration;
+- neither: the site costs one predicate and gets the shared no-op span
+  back: no `Span`, no annotation.
+
+`add_span` (explicit boundaries) and `event` stay ring-only: a profiler
+annotation cannot be back-dated.
+
+The synchronous-section rule. A `span` wraps a synchronous section: no
+`await` that can suspend inside it. Spans of one thread then never
+interleave, and the sum of a name's spans is that thread's time — which
+is what the benchmark's `program_span` readers rely on. A section that
+awaits (`message.apply`, `hooks.<name>`, `serving.catchup_drain`) reads
+the clock before, calls `add_span` after, and so stays out of the
+profiler's trace, where it would overlap other tasks' spans on the
+loop's line.
 
 Design constraints:
-- Near-zero cost when disabled: one attribute read + truth test per
-  span site, no object allocation.
+- Near-zero cost when not live: one attribute read, one static call and
+  a truth test per span site, no object allocation.
 - No global locks on the hot path: spans complete on the event loop
   thread; the ring buffer is a `collections.deque(maxlen=...)` whose
   append is atomic under the GIL.
@@ -28,11 +52,11 @@ from __future__ import annotations
 import contextvars
 import logging
 import os
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
-from typing import Any, Callable, Iterator, Optional
+from typing import Any, Callable, Optional
 
 _slow_logger = logging.getLogger("hocuspocus_tpu.tracing")
 
@@ -98,6 +122,8 @@ class Span:
 
 
 class _NoopSpan:
+    """What a span site gets when nobody is looking."""
+
     __slots__ = ()
 
     def set(self, key: str, value: Any) -> None:
@@ -106,8 +132,67 @@ class _NoopSpan:
     def finish(self) -> "_NoopSpan":
         return self
 
+    def __enter__(self) -> "_NoopSpan":
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        return False
+
 
 _NOOP_SPAN = _NoopSpan()
+
+# What a span site enters while a jax.profiler capture runs: a subclass of
+# jax.profiler.TraceAnnotation, once resolved; False when it cannot be
+# resolved (no jax.profiler, or a jaxlib without is_enabled): "never
+# capturing". None until jax shows up in sys.modules: this module never
+# imports it.
+_annotation: Any = None
+
+
+def _resolve_annotation() -> Any:
+    global _annotation
+    if "jax" not in sys.modules:
+        return None
+    try:
+        from jax.profiler import TraceAnnotation
+
+        TraceAnnotation.is_enabled()
+
+        class CaptureSpan(TraceAnnotation):
+            """The annotation itself (level 1, the name alone, entered and
+            left in C), with a span's no-op `set`: what a site gets when
+            only a capture is looking."""
+
+            def set(self, key: str, value: Any) -> None:
+                pass
+
+        _annotation = CaptureSpan
+    except Exception:
+        _annotation = False
+    return _annotation
+
+
+class _RingSpan:
+    """A span under an enabled tracer: a `Span` for the ring, inside a
+    profiler annotation too when a capture is running."""
+
+    __slots__ = ("_tracer", "_span", "_annotation")
+
+    def __init__(self, tracer: "Tracer", name: str, attributes: dict, annotate: Any) -> None:
+        self._tracer = tracer
+        self._annotation = annotate(name) if annotate is not None else None
+        self._span = Span(name, attributes or None)
+
+    def __enter__(self) -> Span:
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return self._span
+
+    def __exit__(self, *exc: Any) -> bool:
+        self._tracer._record(self._span.finish())
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        return False
 
 
 class Tracer:
@@ -133,7 +218,6 @@ class Tracer:
     def __init__(self, enabled: bool = True, max_spans: int = 4096) -> None:
         self.enabled = enabled
         self._spans: deque[Span] = deque(maxlen=max_spans)
-        self._jax_annotation = None  # lazily resolved TraceAnnotation class
         # slow-span promotion: None disables the check entirely
         self.slow_ms: Optional[float] = None
         self.on_slow: list[Callable[[Span], Any]] = []
@@ -178,31 +262,16 @@ class Tracer:
 
     # -- recording ---------------------------------------------------------
 
-    @contextmanager
-    def span(self, name: str, **attributes: Any) -> Iterator[Any]:
-        if not self.enabled:
-            yield _NOOP_SPAN
-            return
-        sp = Span(name, attributes or None)
-        try:
-            yield sp
-        finally:
-            self._record(sp.finish())
-
-    @contextmanager
-    def device_span(self, name: str, **attributes: Any) -> Iterator[Any]:
-        """A span that also shows up in a `jax.profiler` trace when one is
-        being captured (merge-plane device steps)."""
-        if not self.enabled:
-            yield _NOOP_SPAN
-            return
-        annotation = self._resolve_jax_annotation()
+    def span(self, name: str, **attributes: Any) -> Any:
+        """A context manager around a synchronous section (module
+        docstring: the live rule, the synchronous-section rule)."""
+        annotation = _annotation
         if annotation is None:
-            with self.span(name, **attributes) as sp:
-                yield sp
-            return
-        with annotation(name), self.span(name, **attributes) as sp:
-            yield sp
+            annotation = _resolve_annotation()
+        capturing = annotation and annotation.is_enabled()
+        if self.enabled:
+            return _RingSpan(self, name, attributes, annotation if capturing else None)
+        return annotation(name) if capturing else _NOOP_SPAN
 
     def event(self, name: str, **attributes: Any) -> None:
         """Record an instantaneous event as a zero-duration span (state
@@ -225,7 +294,8 @@ class Tracer:
     ) -> Optional[Span]:
         """Record a span with explicit perf_counter boundaries (the
         update trace book reconstructs stage spans after the fact from
-        pipeline timestamps)."""
+        pipeline timestamps; sections that await read the clock before
+        and call this after). Ring-only."""
         if not self.enabled:
             return None
         sp = Span(name, attributes or None)
@@ -272,16 +342,6 @@ class Tracer:
             return True
         self._sample_counter += 1
         return self._sample_counter % self.sample == 1
-
-    def _resolve_jax_annotation(self):
-        if self._jax_annotation is None:
-            try:
-                from jax.profiler import TraceAnnotation
-
-                self._jax_annotation = TraceAnnotation
-            except Exception:
-                self._jax_annotation = False
-        return self._jax_annotation or None
 
     # -- reading -----------------------------------------------------------
 
@@ -767,7 +827,8 @@ def _fleet_node() -> str:
 
 
 # The default tracer every instrumentation site uses. Disabled by default:
-# span sites cost one attribute read + branch until somebody enables it.
+# until somebody enables it or starts a profiler capture, a span site
+# costs one predicate.
 _default = Tracer(enabled=False)
 
 

@@ -152,7 +152,10 @@ class Connection:
         )
         self.send(message.to_bytes())
 
-    async def handle_message(self, data: bytes) -> None:
+    def _admit_and_parse(self, data: bytes):
+        """The synchronous head of a message's dispatch: admission, header
+        parse, the IncomingMessage. (message, type, document name), or
+        None when the frame goes no further."""
         overload = get_overload_controller()
         if (
             overload.enabled
@@ -164,7 +167,7 @@ class Connection:
             # Later) so a runaway client stops feeding the event loop
             if overload.rung >= RED:
                 self.close(TRY_AGAIN_LATER)
-                return
+                return None
             # below RED the frame is dropped, but never SILENTLY: a
             # dropped Update would otherwise diverge forever (the
             # client believes itself synced and never retransmits).
@@ -183,19 +186,27 @@ class Connection:
                     self._quota_heal_handle = loop.call_later(
                         1.0, self._send_quota_heal
                     )
-            return
+            return None
         # native header parse: one C++ call replaces the two Python
         # varint/string reads (frames.parse_frame_header falls back to
         # the Python decoder without the toolchain); the pre-read type
         # is handed to MessageReceiver so it is never decoded twice
         document_name, message_type, payload_off = parse_frame_header(data)
         if document_name != self.document.name:
-            return
+            return None
         message = IncomingMessage(data)
         message.decoder.pos = payload_off
         message.write_var_string(document_name)
-        wire = get_wire_telemetry()
+        return message, message_type, document_name
+
+    async def handle_message(self, data: bytes) -> None:
         tracer = get_tracer()
+        with tracer.span("connection.dispatch"):
+            parsed = self._admit_and_parse(data)
+        if parsed is None:
+            return
+        message, message_type, document_name = parsed
+        wire = get_wire_telemetry()
         mark = None
         if tracer.enabled:
             # ingress mark: a lifecycle trace stamped during this

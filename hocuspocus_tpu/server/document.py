@@ -11,6 +11,7 @@ import asyncio
 from typing import Any, Callable, Iterable, Optional
 
 from ..crdt import Doc, apply_update, encode_state_as_update
+from ..observability.tracing import get_tracer
 from ..protocol.awareness import (
     Awareness,
     apply_awareness_update,
@@ -153,7 +154,8 @@ class Document(Doc):
         gate = None
         if sink is not None:
             try:
-                gate = sink(update, origin)
+                with get_tracer().span("wal.append"):
+                    gate = sink(update, origin)
             except Exception:
                 from . import logger as _logger_mod
 

@@ -62,6 +62,7 @@ from typing import Any, Callable, Iterable, Optional
 
 from ..crdt import encode_state_as_update
 from ..observability.costs import get_cost_ledger
+from ..observability.tracing import get_tracer
 from ..observability.wire import get_wire_telemetry
 from ..protocol.frames import build_update_frame, build_update_frames_batch
 from ..protocol.message import OutgoingMessage
@@ -336,6 +337,13 @@ class DocumentFanout:
     # -- the tick ----------------------------------------------------------
 
     def flush(self) -> None:
+        # one span per synchronous piece of a tick: this one (coalesce,
+        # frame build, and the delivery when no durability gate is
+        # open), and the gated delivery when it runs later
+        with get_tracer().span("fanout.tick"):
+            self._flush()
+
+    def _flush(self) -> None:
         self._scheduled = False
         self._delay_handle = None
         pending = self._pending_updates
@@ -507,7 +515,8 @@ class DocumentFanout:
                             pass  # commit errors are counted, never block
             finally:
                 self._gate_tasks.discard(asyncio.current_task())
-            deliver_tick()
+            with get_tracer().span("fanout.tick"):
+                deliver_tick()
 
         # strong ref: a GC'd waiter would swallow the tick's frames
         task = asyncio.ensure_future(waiter())

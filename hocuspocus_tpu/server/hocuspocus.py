@@ -111,11 +111,14 @@ class Hocuspocus:
         propagates. `callback` runs after each extension with its return
         value (used for context merging).
         """
+        # a chain awaits its handlers: ring-only, read by no metric
         tracer = get_tracer()
-        if tracer.enabled:
-            with tracer.span(f"hooks.{name}"):
-                return await self._run_hooks(name, payload, callback)
-        return await self._run_hooks(name, payload, callback)
+        started = time.perf_counter() if tracer.enabled else None
+        try:
+            return await self._run_hooks(name, payload, callback)
+        finally:
+            if started is not None:
+                tracer.add_span(f"hooks.{name}", started, time.perf_counter())
 
     async def _run_hooks(self, name: str, payload: Payload, callback: Optional[Callable]) -> Any:
         result: Any = None

@@ -43,30 +43,33 @@ class MessageReceiver:
         *,
         message_type: Optional[int] = None,
     ) -> None:
+        if message_type is None:
+            message_type = self.message.read_var_uint()
+        # wraps awaits (hooks, the durability gate): other tasks' work
+        # interleaves, so this is ring-only and read by no metric
         tracer = get_tracer()
-        if tracer.enabled:
-            with tracer.span(
-                "message.apply",
-                document=document.name,
-                bytes=len(self.message.decoder.buf),
-            ) as span:
-                await self._apply(document, connection, reply, span, message_type)
-        else:
-            await self._apply(document, connection, reply, None, message_type)
+        started = time.perf_counter() if tracer.enabled else None
+        try:
+            await self._apply(document, connection, reply, message_type)
+        finally:
+            if started is not None:
+                tracer.add_span(
+                    "message.apply",
+                    started,
+                    time.perf_counter(),
+                    document=document.name,
+                    bytes=len(self.message.decoder.buf),
+                    type=int(message_type),
+                )
 
     async def _apply(
         self,
         document: Document,
-        connection=None,
-        reply: Optional[Callable[[bytes], None]] = None,
-        span=None,
-        message_type: Optional[int] = None,
+        connection,
+        reply: Optional[Callable[[bytes], None]],
+        message_type: int,
     ) -> None:
         message = self.message
-        if message_type is None:
-            message_type = message.read_var_uint()
-        if span is not None:
-            span.set("type", int(message_type))
         wire = get_wire_telemetry()
         # ingress accounting covers the SOCKET edge only: redis-bus
         # replicated messages also flow through this receiver
@@ -266,11 +269,12 @@ class MessageReceiver:
                 return sync_type
             ledger = get_cost_ledger()
             t0 = time.perf_counter_ns() if ledger.enabled else 0
-            read_sync_step2(
-                message.decoder,
-                document,
-                connection if connection is not None else self.default_transaction_origin,
-            )
+            with get_tracer().span("message.update_apply", document=document.name):
+                read_sync_step2(
+                    message.decoder,
+                    document,
+                    connection if connection is not None else self.default_transaction_origin,
+                )
             if ledger.enabled:
                 ledger.record("apply_update", "Sync", time.perf_counter_ns() - t0)
             if connection is not None:
@@ -289,13 +293,11 @@ class MessageReceiver:
             tracer = get_tracer()
             ledger = get_cost_ledger()
             t0 = time.perf_counter_ns() if ledger.enabled else 0
-            if tracer.enabled:
-                # the CPU-side apply that precedes the capture seam: a
-                # lifecycle trace's host prologue is visible next to its
-                # update.* stage spans in /debug/trace
-                with tracer.span("message.update_apply", document=document.name):
-                    read_update(message.decoder, document, origin)
-            else:
+            # the CPU-side apply that precedes the capture seam (the
+            # document's observer runs the WAL append and the plane's
+            # capture inside it): a lifecycle trace's host prologue is
+            # visible next to its update.* stage spans in /debug/trace
+            with tracer.span("message.update_apply", document=document.name):
                 read_update(message.decoder, document, origin)
             if ledger.enabled:
                 ledger.record("apply_update", "Sync", time.perf_counter_ns() - t0)

@@ -44,6 +44,7 @@ import zlib
 from typing import Any, Iterable, Optional
 from urllib.parse import quote
 
+from ..observability.tracing import get_tracer
 from .faults import FaultInjector
 
 # record framing: crc32(length+type+payload), payload length, type
@@ -304,12 +305,13 @@ class DocumentWal:
         faults.check_fsync()
         if self._fh is not None:
             self._fh.flush()
-            os.fsync(self._fh.fileno())
+            with get_tracer().span("wal.fsync"):
+                os.fsync(self._fh.fileno())
         elif self.segments:
             # handle released (doc unloaded) with the tail segment
             # possibly page-cache-only: settle it before the journal
             # stops covering it
-            with open(self.segments[-1].path, "rb") as fh:
+            with open(self.segments[-1].path, "rb") as fh, get_tracer().span("wal.fsync"):
                 os.fsync(fh.fileno())
 
     # -- truncation --------------------------------------------------------
@@ -435,6 +437,7 @@ class WalManager:
         self._docs: "dict[str, DocumentWal]" = {}
         # name -> [(rec_type, payload, rotate_before, drop_older_after)]
         self._pending: "dict[str, list]" = {}
+        self._pending_since: Optional[float] = None  # the oldest pending append
         self._tick_future: Optional[asyncio.Future] = None
         self._flush_task: Optional[asyncio.Task] = None
         self._flush_lock = asyncio.Lock()
@@ -472,6 +475,12 @@ class WalManager:
             # starts taking hundreds of ms per tick is backpressure the
             # front door must see
             "commit_last_ms": 0.0,
+            # the same, summed over every commit (monotone), and per
+            # commit batch the time from its oldest pending append to
+            # its futures being resolved on the loop: the longest a
+            # fan-out tick of that batch can have been gated
+            "commit_ms_total": 0.0,
+            "durable_wait_ms_total": 0.0,
         }
 
     @property
@@ -504,7 +513,7 @@ class WalManager:
     ) -> "asyncio.Future":
         """Buffer one record into the current tick's group commit and
         return the tick's shared durability future."""
-        self._pending.setdefault(name, []).append((rec_type, payload, False, False))
+        self._buffer(name, (rec_type, payload, False, False))
         return self._schedule()
 
     def checkpoint(self, name: str, snapshot: bytes) -> "asyncio.Future":
@@ -513,8 +522,13 @@ class WalManager:
         subsumes them (an eviction/compaction checkpoint bounds the log
         without waiting for the next debounced store)."""
         self.stats["checkpoints"] += 1
-        self._pending.setdefault(name, []).append((REC_SNAPSHOT, snapshot, True, True))
+        self._buffer(name, (REC_SNAPSHOT, snapshot, True, True))
         return self._schedule()
+
+    def _buffer(self, name: str, entry: tuple) -> None:
+        if not self._pending:
+            self._pending_since = time.perf_counter()
+        self._pending.setdefault(name, []).append(entry)
 
     def _schedule(self) -> "asyncio.Future":
         # the loop lookup sits on the per-update capture path: cache it
@@ -527,8 +541,10 @@ class WalManager:
             except RuntimeError:
                 # no loop (unit/direct use): commit synchronously
                 future: "asyncio.Future" = _SyncFuture()
-                self._commit(self._take_pending())
+                pending, since = self._take_pending()
+                self._commit(pending)
                 future.set_result(None)
+                self._note_durable(since)
                 return future
             self._loop = loop
         if self._tick_future is None or self._tick_future.done():
@@ -537,9 +553,15 @@ class WalManager:
             self._flush_task = loop.create_task(self._flush_async())
         return self._tick_future
 
-    def _take_pending(self) -> "dict[str, list]":
+    def _take_pending(self) -> "tuple[dict[str, list], Optional[float]]":
+        """The buffered batch, and when its oldest append was buffered."""
         pending, self._pending = self._pending, {}
-        return pending
+        since, self._pending_since = self._pending_since, None
+        return pending, since
+
+    def _note_durable(self, since: Optional[float]) -> None:
+        if since is not None:
+            self.stats["durable_wait_ms_total"] += (time.perf_counter() - since) * 1000.0
 
     async def _flush_async(self) -> None:
         # serialize batches; appends landing mid-write join the NEXT
@@ -548,7 +570,7 @@ class WalManager:
         # always picked up and resolved)
         async with self._flush_lock:
             while True:
-                pending = self._take_pending()
+                pending, since = self._take_pending()
                 future, self._tick_future = self._tick_future, None
                 if pending:
                     try:
@@ -562,12 +584,17 @@ class WalManager:
                     # counted and the records stay recoverable from the
                     # store path
                     future.set_result(None)
+                self._note_durable(since)
                 if not self._pending or self._closed:
                     return
 
     def _commit(self, pending: "dict[str, list]") -> None:
         """Executor thread: write every dirty doc's batch, then make the
         whole tick durable with ONE journal fsync (tick mode)."""
+        with get_tracer().span("wal.commit"):
+            self._commit_batch(pending)
+
+    def _commit_batch(self, pending: "dict[str, list]") -> None:
         commit_started = time.perf_counter()
         batch_records = 0
         journal_entries: "list[bytes]" = []
@@ -663,6 +690,7 @@ class WalManager:
         self.stats["commit_batch_records_last"] = batch_records
         commit_s = time.perf_counter() - commit_started
         self.stats["commit_last_ms"] = round(commit_s * 1000, 3)
+        self.stats["commit_ms_total"] += commit_s * 1000
         from ..observability.costs import get_cost_ledger
 
         ledger = get_cost_ledger()
@@ -722,7 +750,8 @@ class WalManager:
             # fdatasync: data + the metadata needed to read it back
             # (file size) — skips timestamp flushes the recovery scan
             # never looks at
-            os.fdatasync(self._journal_fh.fileno())
+            with get_tracer().span("wal.fsync"):
+                os.fdatasync(self._journal_fh.fileno())
             self.stats["fsyncs"] += 1
             self._journal_size += len(blob)
             self.stats["journal_bytes"] += len(blob)
@@ -798,7 +827,7 @@ class WalManager:
             tail = max(e for e in os.listdir(directory) if e.endswith(".wal"))
         except (FileNotFoundError, ValueError):
             return  # nothing on disk: nothing to settle
-        with open(os.path.join(directory, tail), "rb") as fh:
+        with open(os.path.join(directory, tail), "rb") as fh, get_tracer().span("wal.fsync"):
             os.fsync(fh.fileno())
 
     def _journal_replay(self, name: str) -> "tuple[list[tuple[int, bytes]], int]":

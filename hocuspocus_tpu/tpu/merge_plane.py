@@ -416,6 +416,17 @@ class MergePlane:
             "flush_batches_fast": 0,
             "flush_fast_ops": 0,
             "flush_slow_ops": 0,
+            # per device batch: the rows that really carry ops (the
+            # fast set, the slow set, or every busy slot of a dense
+            # batch) and the bucket width B they were padded to
+            "flush_busy_rows": 0,
+            "flush_bucket_rows": 0,
+            # broadcast passes run, and the time from when each was
+            # scheduled (the first capture since the last pass) to when
+            # it ran: the coalescing window, the phase alignment and
+            # the loop's lag — what an update waits by design
+            "broadcast_passes": 0,
+            "broadcast_wait_ms_total": 0.0,
             # warm-grid programs that failed to compile or run (the
             # detail rows are in warm_failures)
             "warm_failures": 0,
@@ -1170,7 +1181,7 @@ class MergePlane:
         the serving flush loop uses 1 so broadcasts interleave with
         integration instead of waiting for a full drain; sync serves
         drain fully (covers() needs everything integrated)."""
-        with self._step_lock:
+        with self._step_lock, get_tracer().span("merge_plane.flush"):
             return self._flush_locked(max_batches)
 
     def warmup_compiles(self, shape=None, shared: bool = False) -> bool:
@@ -1430,7 +1441,16 @@ class MergePlane:
         k_last = b_last = busy_last = 0
         while max_batches is None or batches < max_batches:
             t0 = time.perf_counter()
-            drained = self._drain_ops(k_max)
+            # minimal-work run merge: split the drained columns into
+            # all-sequential (fast) and genuinely-concurrent (slow)
+            # sets. A column is entirely one or the other per batch —
+            # the two dispatches below touch disjoint rows, so their
+            # relative order is immaterial.
+            fast = None
+            with tracer.span("merge_plane.drain"):
+                slow = drained = self._drain_ops(k_max)
+                if drained is not None and self.run_merge_enabled:
+                    fast, slow = self._classify_fast(drained)
             if drained is None:
                 break
             cycle_traces = None
@@ -1442,15 +1462,6 @@ class MergePlane:
                 )
             built = drained[5]
             busy_total = int(drained[4].size)
-            # minimal-work run merge: split the drained columns into
-            # all-sequential (fast) and genuinely-concurrent (slow)
-            # sets. A column is entirely one or the other per batch —
-            # the two dispatches below touch disjoint rows, so their
-            # relative order is immaterial.
-            fast = None
-            slow = drained
-            if self.run_merge_enabled:
-                fast, slow = self._classify_fast(drained)
             if fast is not None:
                 (
                     run_row, run_col, f_client, f_clock, f_run,
@@ -1467,20 +1478,17 @@ class MergePlane:
                 slot_view_f[:nf] = f_slots
                 slot_view_f[nf:] = self.num_docs
                 t1 = time.perf_counter()
-                args_f = self._upload_append_batch(
-                    (cl_v, ck_v, rn_v), slot_view_f
-                )
+                with tracer.span("merge_plane.upload"):
+                    args_f = self._upload_append_batch(
+                        (cl_v, ck_v, rn_v), slot_view_f
+                    )
                 self._append_inflight[self._append_batches % 2] = args_f
                 self._append_batches += 1
                 t2 = time.perf_counter()
                 step_f = self._append_step_fn()
-                if tracer.enabled:
-                    with tracer.device_span(
-                        "merge_plane.append", slots=k_max, busy=bf
-                    ) as span:
-                        self.state, _count = step_f(self.state, *args_f)
-                        span.set("integrated", f_ops)
-                else:
+                with tracer.span(
+                    "merge_plane.append", slots=k_max, busy=bf, integrated=f_ops
+                ):
                     self.state, _count = step_f(self.state, *args_f)
                 t_dispatch = time.perf_counter()
                 self.compile_watch.observe(
@@ -1493,6 +1501,8 @@ class MergePlane:
                 self._tail_clock[f_slots] = f_tail_ck
                 self.counters["flush_batches_fast"] += 1
                 self.counters["flush_fast_ops"] += f_ops
+                self.counters["flush_busy_rows"] += nf
+                self.counters["flush_bucket_rows"] += bf
                 fast_total += f_ops
                 device_batches += 1
                 build_ms += (t1 - t0) * 1000.0
@@ -1522,12 +1532,13 @@ class MergePlane:
                 )
                 t1 = time.perf_counter()
                 if slot_view is None:
-                    step_args = (self._upload_batch(fields),)
+                    with tracer.span("merge_plane.upload"):
+                        step_args = (self._upload_batch(fields),)
                     step = self._step_fn()
                     self.counters["flush_batches_dense"] += 1
                 else:
-                    ops, slots_dev = self._upload_sparse_batch(fields, slot_view)
-                    step_args = (ops, slots_dev)
+                    with tracer.span("merge_plane.upload"):
+                        step_args = self._upload_sparse_batch(fields, slot_view)
                     step = self._sparse_step_fn()
                     self.counters["flush_batches_sparse"] += 1
                 # remember what this staging buffer fed the device:
@@ -1547,13 +1558,9 @@ class MergePlane:
                 # next loop iteration builds and uploads batch i+1 from
                 # the OTHER staging buffer — that alternation is the
                 # double-buffered pipeline.
-                if tracer.enabled:
-                    with tracer.device_span(
-                        "merge_plane.integrate", slots=k, busy=b
-                    ) as span:
-                        self.state, _count = step(self.state, *step_args)
-                        span.set("integrated", slow[5])
-                else:
+                with tracer.span(
+                    "merge_plane.integrate", slots=k, busy=b, integrated=slow[5]
+                ):
                     self.state, _count = step(self.state, *step_args)
                 t_dispatch = time.perf_counter()
                 # compile-event classification from the timestamps
@@ -1577,6 +1584,8 @@ class MergePlane:
                     if self.slot_live[col]:
                         self._tail_dirty.add(col)
                 self.counters["flush_slow_ops"] += slow[5]
+                self.counters["flush_busy_rows"] += b_actual
+                self.counters["flush_bucket_rows"] += b
                 slow_total += slow[5]
                 device_batches += 1
                 if cycle_traces:
@@ -1595,7 +1604,8 @@ class MergePlane:
         if batches:
             self._note_dispatch("flush", device_batches)
             t3 = time.perf_counter()
-            self._sync_health()
+            with tracer.span("merge_plane.readback"):
+                self._sync_health()
             t_sync = time.perf_counter()
             # readback-barrier stall: the host time this cycle spent
             # blocked on the device before results were visible
@@ -2867,6 +2877,10 @@ class TpuMergeExtension(Extension):
 
     def try_capture(self, document, update: bytes, origin) -> bool:
         """Claim an update for plane-batched broadcast. False = CPU fan-out."""
+        with get_tracer().span("plane.capture"):
+            return self._try_capture(document, update, origin)
+
+    def _try_capture(self, document, update: bytes, origin) -> bool:
         from ..server.hocuspocus import REDIS_ORIGIN
         from ..server.types import REPLICA_ORIGIN
 
@@ -2909,9 +2923,10 @@ class TpuMergeExtension(Extension):
         # replica-stream applies count as remote ops: the merged window's
         # cross_update must carry only locally-originated ops, or the
         # plane would echo the owner's ticks back over the replica lane
-        accepted = plane.enqueue_update(
-            name, update, remote=origin in (REDIS_ORIGIN, REPLICA_ORIGIN)
-        )
+        with get_tracer().span("plane.lower"):
+            accepted = plane.enqueue_update(
+                name, update, remote=origin in (REDIS_ORIGIN, REPLICA_ORIGIN)
+            )
         if trace_id is not None and not accepted:
             # nothing queued (deduplicated, or the doc degraded during
             # the enqueue — where retire already dropped the doc's book)
@@ -3185,6 +3200,10 @@ class TpuMergeExtension(Extension):
         converge by CRDT idempotence either way)."""
         if not self.serve:
             return
+        with get_tracer().span("plane.broadcast"):
+            self._broadcast_pass(cross_instance)
+
+    def _broadcast_pass(self, cross_instance: bool) -> None:
         plane = self.plane
         dirty = list(plane.dirty)
         plane.dirty.clear()
@@ -3365,13 +3384,11 @@ class TpuMergeExtension(Extension):
                         await asyncio.get_event_loop().run_in_executor(
                             None, lambda: self.plane.flush(max_batches)
                         )
-                        if self.serve:
-                            self.serving.refresh()
                     except Exception:
                         self._degrade_all_served()
                         return
-                    if self.serve:
-                        self._validate_served()
+                    if self.serve and not self._post_flush():
+                        return
                 if self.governor is not None:
                     self.governor.note_cycle(self.plane.flush_stats)
             finally:
@@ -3383,6 +3400,19 @@ class TpuMergeExtension(Extension):
                 self.governor.note_park()
         finally:
             self._flush_inflight = False
+
+    def _post_flush(self) -> bool:
+        """What a flush cycle runs back on the loop once the executor
+        returns: the serving refresh, then the desync sweep. False when
+        the refresh failed and every served doc was degraded."""
+        with get_tracer().span("plane.post_flush"):
+            try:
+                self.serving.refresh()
+            except Exception:
+                self._degrade_all_served()
+                return False
+            self._validate_served()
+            return True
 
     def _validate_served(self) -> None:
         """Post-flush desync sweep, vectorized over every slot.
@@ -3495,9 +3525,14 @@ class TpuMergeExtension(Extension):
             return
         loop = asyncio.get_event_loop()
 
+        scheduled_at = loop.time()
+
         def run() -> None:
             self._broadcast_handle = None
-            self._last_broadcast_at = loop.time()
+            now = self._last_broadcast_at = loop.time()
+            counters = self.plane.counters
+            counters["broadcast_passes"] += 1
+            counters["broadcast_wait_ms_total"] += (now - scheduled_at) * 1000.0
             self._broadcast_served()
 
         # coalescing window only under sustained traffic: a lone edit
@@ -3507,7 +3542,7 @@ class TpuMergeExtension(Extension):
         # windows quantize onto the shard's phase grid (sharded router)
         # so N shards' broadcast passes stop landing on the same tick.
         window = self.broadcast_interval_ms / 1000
-        idle = loop.time() - self._last_broadcast_at
+        idle = scheduled_at - self._last_broadcast_at
         delay = 0.0 if idle >= window else window
         if delay:
             delay = self._align_to_phase(delay, window)

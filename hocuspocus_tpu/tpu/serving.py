@@ -901,11 +901,8 @@ class PlaneServing:
         """
         if self.paused:
             return None  # supervisor drain: serve from the CPU document
-        tracer = get_tracer()
-        if tracer.enabled:
-            with tracer.span("serving.sync_serve", document=name):
-                return self._encode_state_as_update_inner(name, document, sv_bytes)
-        return self._encode_state_as_update_inner(name, document, sv_bytes)
+        with get_tracer().span("serving.sync_serve", document=name):
+            return self._encode_state_as_update_inner(name, document, sv_bytes)
 
     def _encode_state_as_update_inner(
         self, name: str, document, sv_bytes: Optional[bytes] = None
@@ -1000,12 +997,19 @@ class PlaneServing:
             # holds the flush lock: every step reads device state, and a
             # concurrent executor-side flush donates the buffers it reads
             async with plane.flush_lock:
+                # awaits the executor's flush: ring-only, read by no metric
                 tracer = get_tracer()
-                if tracer.enabled:
-                    with tracer.span("serving.catchup_drain", batch=len(batch)):
-                        await self._drain_catchup_locked(batch)
-                else:
+                started = time.perf_counter() if tracer.enabled else None
+                try:
                     await self._drain_catchup_locked(batch)
+                finally:
+                    if started is not None:
+                        tracer.add_span(
+                            "serving.catchup_drain",
+                            started,
+                            time.perf_counter(),
+                            batch=len(batch),
+                        )
         finally:
             if ticket is not None:
                 ticket.release()

@@ -48,11 +48,11 @@ def test_tracer_ring_buffer_bounded():
     assert [s["name"] for s in spans] == ["s6", "s7", "s8", "s9"]
 
 
-def test_device_span_works_without_profiler():
+def test_span_lands_in_the_ring_with_no_profiler_capture():
     tracer = Tracer(enabled=True)
-    with tracer.device_span("merge", slots=4) as span:
+    with tracer.span("merge", slots=4) as span:
         span.set("integrated", 128)
-    assert tracer.export()[0]["attributes"]["integrated"] == 128
+    assert tracer.export()[0]["attributes"] == {"slots": 4, "integrated": 128}
 
 
 def test_global_tracer_enable_disable():
