@@ -519,8 +519,11 @@ def build_server(args: argparse.Namespace):
     builds, not a hand-assembled lookalike."""
     from .extensions import Logger, SQLite, S3, Webhook
     from .server import Configuration, Server
+    from .server.heap import HeapStewardExtension
 
-    extensions: list = [Logger()]
+    # this process is the server's own, so its collector is too
+    # (server/heap.py); an embedder's Server gets no steward unasked
+    extensions: list = [Logger(), HeapStewardExtension()]
     if args.trace:
         from .observability import enable_tracing
 

@@ -125,6 +125,13 @@ class Metrics(Extension):
                 reg.register(metric)
             except ValueError:
                 pass  # already adopted (shared registry, repeat bind)
+        # heap steward (server/heap.py): the chosen collector passes, the
+        # thaws and the automatic full passes that should not happen; all
+        # zero in a process whose entry point did not install it
+        from ..server.heap import get_heap_steward
+
+        for metric in get_heap_steward().metrics():
+            reg.register(metric)
         # per-frame cost ledger + sampling CPU profiler (observability/
         # costs.py, observability/profiler.py): process-global collectors
         # adopted like the wire telemetry — the ledger's site counters,

@@ -56,11 +56,16 @@ def _fmt_value(value: float) -> str:
 
 
 class Counter:
-    """Monotonically increasing counter, optionally labelled."""
+    """Monotonically increasing counter, optionally labelled; like a
+    Gauge it can track a live callable (a total kept elsewhere, read at
+    scrape time)."""
 
-    def __init__(self, name: str, help: str) -> None:
+    def __init__(
+        self, name: str, help: str, fn: Optional[Callable[[], float]] = None
+    ) -> None:
         self.name = name
         self.help = help
+        self._fn = fn
         self._values: dict[tuple, float] = {}
 
     def inc(self, amount: float = 1.0, **labels: str) -> None:
@@ -68,11 +73,16 @@ class Counter:
         self._values[key] = self._values.get(key, 0.0) + amount
 
     def value(self, **labels: str) -> float:
+        if self._fn is not None and not labels:
+            return float(self._fn())
         return self._values.get(tuple(sorted(labels.items())), 0.0)
 
     def expose(self) -> Iterable[str]:
         yield f"# HELP {self.name} {self.help}"
         yield f"# TYPE {self.name} counter"
+        if self._fn is not None:
+            yield f"{self.name} {_fmt_value(float(self._fn()))}"
+            return
         if not self._values:
             yield f"{self.name} 0"
             return

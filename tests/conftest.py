@@ -48,3 +48,29 @@ def pytest_pyfunc_call(pyfuncitem):
         asyncio.run(asyncio.wait_for(fn(**kwargs), timeout=60))
         pyfuncitem.obj = lambda *a, **k: None
     yield
+
+
+@pytest.fixture
+def heap_steward():
+    """The process's heap steward (server/heap.py) for one test, with the
+    collector put back as found whatever the test did: the suite runs
+    `--dist loadfile`, and a leaked freeze or threshold would change the
+    tests of other files in the same worker. The baseline is thawed first:
+    CPython 3.12 itself starts with 375 frozen objects, which no process
+    can freeze again once `gc.unfreeze()` has run."""
+    import gc
+
+    from hocuspocus_tpu.server.heap import get_heap_steward
+
+    steward = get_heap_steward()
+    tuned = ("min_interval_s", "max_interval_s", "growth_share", "load_settle_s", "churn_share", "churn_floor")
+    threshold, tuning = gc.get_threshold(), {key: getattr(steward, key) for key in tuned}
+    gc.unfreeze()
+    try:
+        yield steward
+    finally:
+        while steward.installed:
+            steward.uninstall()
+        vars(steward).update(tuning)
+        gc.unfreeze()
+        gc.set_threshold(*threshold)
