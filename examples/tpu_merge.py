@@ -7,7 +7,7 @@ replies are served from device state with storm-batched state-vector
 triage, and fan-out rides one merged broadcast per flush. Device steps
 run off the event loop; flush shapes pre-compile at listen. Any
 degradation falls the affected doc back to the CPU path with no data
-loss (see docs/tpu/merge-plane.md and bench.py).
+loss (see docs/tpu/merge-plane.md).
 
 Run: python examples/tpu_merge.py
 Multi-chip: pass mesh=hocuspocus_tpu.tpu.sharding.make_mesh() to shard

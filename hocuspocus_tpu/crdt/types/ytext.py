@@ -43,7 +43,7 @@ def identical_attrs(a: Any, b: Any) -> bool:
     for objects/arrays. cleanupFormattingGap compares with `===`, so a
     marker restating an equal-but-distinct object attribute is KEPT by
     yjs peers — using deep equality there deletes markers a yjs peer
-    retains and diverges the tombstone layout (round-5 ADVICE)."""
+    retains and diverges the tombstone layout (round-5 review)."""
     if a is b:
         return True
     # JS has one number type but distinct booleans: True must not

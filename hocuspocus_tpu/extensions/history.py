@@ -340,7 +340,7 @@ def _classify_root(ytype, live=None) -> str:
     restored doc collapses deleted typed content to GC ranges), so the
     live root's concrete type is the only trustworthy signal there —
     defaulting to 'text' mistyped emptied array/map roots and made
-    restore raise mid-transaction (ADVICE.md)."""
+    restore raise mid-transaction."""
     kind = _concrete_kind(ytype)
     if kind is not None:
         return kind

@@ -287,7 +287,7 @@ class FleetControllerExtension(Extension):
         self._last_sample_t: Optional[float] = None
         self._rate_ewma: "dict[int, float]" = {}
         # roster timeline: every membership change, stamped relative to
-        # listen time — the bench artifact's scale story
+        # listen time (a scenario's `extra.autoscale` carries it)
         self.timeline: "deque[dict]" = deque(maxlen=256)
         self.actuation = {
             "activations": 0,

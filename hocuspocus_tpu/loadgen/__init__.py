@@ -3,7 +3,7 @@
 Two layers (docs/guides/load-testing.md):
 
 - :mod:`.harness` — ``ServedLoadHarness``, the socket-free real-server
-  topology bench.py measures the served 100k-doc regime with;
+  topology the scenario runner builds on;
 - the scenario engine — declarative, phase-tagged, seeded traffic
   programs (:mod:`.scenario`), a library of production mixes
   (:mod:`.scenarios`), and the SLO-judged executor (:mod:`.runner`)
@@ -12,6 +12,9 @@ Two layers (docs/guides/load-testing.md):
 Run one from the command line::
 
     python -m hocuspocus_tpu.loadgen --scenario smoke --seed 7
+
+This is a fixture for tests and rehearsals: its traffic is uniform,
+evenly spaced and append-only. Speed is measured by ``bench/`` only.
 
 Back-compat: ``from hocuspocus_tpu.loadgen import run_served_load``
 keeps working exactly as when this was a single module.

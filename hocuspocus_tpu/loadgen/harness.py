@@ -2,12 +2,11 @@
 
 The reference's scale story is doc-sharding across instances
 (`docs/guides/scalability.md:7-14`), but OS sockets cap any in-process
-measurement near 4k docs (fd limits). This harness drives a
-config4-shaped population — live served docs with writers, sampled
+population near 4k docs (fd limits). This harness drives a population
+shaped like BASELINE config 4 — live served docs with writers, sampled
 readers, steady background load, and optional cross-instance Redis
-fan-out — through REAL server objects over `InProcessProviderSocket`,
-so the 100k-doc regime is measurable in CI and on-chip (`bench.py`
-reuses it for the served p99 metric).
+fan-out — through REAL server objects over `InProcessProviderSocket`.
+The scenario runner (runner.py) builds its topologies on it.
 
 Everything on the path is production code: providers run the full
 auth/SyncStep1/2/awareness pipeline, the server runs the full hook
@@ -38,7 +37,7 @@ class ServedLoadHarness:
     - instances: server instances; >1 wires them through Redis
       (mini_redis unless REDIS_HOST targets a real one) and places the
       sampled readers on the SECOND instance so the timed path crosses
-      the fan-out, exactly like benchmarks/config4.
+      the fan-out.
     - sampled: docs that get a reader and are latency-timed.
     - shards / shard_rows / capacity / flush_interval_ms: plane layout
       per instance (rows must exceed num_docs/shards + hash skew).
@@ -104,8 +103,8 @@ class ServedLoadHarness:
         self.background_fraction = background_fraction
         # with_metrics: add a Metrics extension per instance (enables
         # the wire telemetry singleton and binds each plane's trace
-        # book to the e2e histogram) — the bench's wire_load pass reads
-        # ingress-stage quantiles off metrics[0] after the run
+        # book to the e2e histogram) — the scenario runner reads the
+        # servers' /metrics surfaces through it
         self.with_metrics = with_metrics
         self.metrics: list[Any] = []
         # overload: per-instance OverloadExtension options — the
@@ -385,7 +384,7 @@ class ServedLoadHarness:
         """Writer inserts `size` units into sampled doc `doc`; returns
         seconds until the reader's doc shows the grown text (None on
         timeout when not raising). Event-driven: woken by reader doc
-        updates. Shared by the bench edit loop and the scenario runner —
+        updates. Shared by the harness's edit loop and the scenario runner —
         the straggler-safe measurement logic must exist exactly once.
 
         The target is the WRITER's post-insert length: after a swallowed

@@ -502,8 +502,7 @@ class ScenarioRunner:
         static fleet size must stay <= max_ratio — a fleet that never
         scales back down fails the run even with every latency SLO
         green. Latched like any breach; the ratio lands in
-        ``extra.autoscale`` for the bench gate's
-        diurnal_autoscale.steady_footprint_ratio stage."""
+        ``extra.autoscale`` as ``steady_footprint_ratio``."""
         controllers = self.harness.fleet_controllers
         if not self._autoscale_config or not controllers:
             return
@@ -654,7 +653,7 @@ class ScenarioRunner:
         if self._autoscale_evidence is not None:
             # elastic-fleet evidence: roster timeline, scale decisions,
             # per-phase active-cell means and the steady-trough
-            # footprint ratio the bench gate reads
+            # footprint ratio
             evidence["autoscale"] = self._autoscale_evidence
         publish = {}
         for i, server in enumerate(self.harness.servers):

@@ -1,9 +1,10 @@
 """Declarative scenario engine: composable phase-tagged traffic programs.
 
-The north star ("heavy traffic from millions of users", "as many
-scenarios as you can imagine") cannot be evidenced by single-shape
-bench passes — it needs *named, replayable production mixes* judged by
-the SLO engine. This module is the declarative half of that harness:
+Whether a topology holds together under a storm, a drain or a
+partition is not shown by one shape of traffic — it needs *named,
+replayable mixes* judged by the SLO engine. This module is the
+declarative half of that harness (a fixture for tests and rehearsals;
+speed is the benchmark's, bench/):
 
 - a **Scenario** is a population (docs, instances, shards, an optional
   mega-doc) plus an ordered list of **PhaseSpec**s, each a traffic
@@ -42,8 +43,8 @@ Op kinds (the whole DSL — small on purpose):
 ==========  ============================================================
 
 Everything here is stdlib-only and import-light: compiling and hashing
-schedules must work in tools (bench_capture, tests) without touching
-jax or the server stack.
+schedules must work in tests and in the ``/debug/loadgen`` endpoint
+without touching jax or the server stack.
 """
 
 from __future__ import annotations

@@ -3,8 +3,7 @@
 Each factory returns a :class:`~.scenario.Scenario` sized by keyword
 arguments (defaults are CI-scale; pass bigger numbers for real storms).
 ``get_scenario(name, **overrides)`` resolves by registry name — the
-``python -m hocuspocus_tpu.loadgen`` CLI, bench.py's scenario-suite
-pass and ``tools/bench_capture.py`` all go through it.
+``python -m hocuspocus_tpu.loadgen`` CLI and the tests go through it.
 
 The mixes (ROADMAP item 5, Collabs arXiv:2212.02618 composed multi-user
 workloads, Eg-walker arXiv:2409.14252 realistic-concurrency merges):
@@ -559,12 +558,9 @@ def multi_device_storm(
     migrate docs OFF the hot cell mid-run (evict-snapshot→hydrate, zero
     acked-update loss — ``verify_convergence`` latches divergence into
     the verdict via the cross-instance check), and the small docs'
-    interactive p99 holds while the mega-doc churns — the
-    `multi_device_storm.interactive_p99` gate stage in
-    tools/bench_gate.py. Per-device doc counts, utilization spread,
-    placement hash and migration accounting land in
-    ``extra.multi_device`` so the next on-chip capture can verify the
-    226 ms → <50 ms trajectory chip by chip."""
+    interactive p99 holds while the mega-doc churns (the storm phase's
+    SLO). Per-device doc counts, utilization spread, placement hash and
+    migration accounting land in ``extra.multi_device``."""
     return Scenario(
         name="multi_device_storm",
         description="hot-doc skew forcing load-aware rebalancing across "
@@ -619,10 +615,9 @@ def edge_fanout(
     writers on edge 0, readers on edge 1, two merge cells behind the
     relay lane — every measured edit crosses edge→cell→edge, and a join
     storm lands THROUGH the edge tier mid-run (door auth + relay
-    session establishment under pressure). The fanout phase's p99 is
-    the `edge_fanout.interactive_p99` gate stage in
-    tools/bench_gate.py: the edge hop must stay a constant tax, not a
-    new tail."""
+    session establishment under pressure). The fanout phase's p99 has
+    its own SLO: the edge hop must stay a constant tax, not a new
+    tail."""
     return Scenario(
         name="edge_fanout",
         description="edge-terminated join storm + cross-edge fan-out "
@@ -666,8 +661,7 @@ def mega_audience(
     so the router grows an owner + follower placement, followers
     bootstrap off the owner's snapshot rail and the edge spreads the
     audience's channels across the whole route set. The fanout phase's
-    p99 is the `mega_audience.fanout_p99` gate stage in
-    tools/bench_gate.py: the measured write→observe path must stay FLAT
+    p99 has its own SLO: the measured write→observe path must stay FLAT
     as the audience (and the follower count) scales, because the owner
     only streams one coalesced tick per flush regardless of audience —
     reads are the followers' problem. ``verify_convergence`` latches a
@@ -738,11 +732,11 @@ def diurnal_autoscale(
     ramp-down shape over a multi-device cell plane, plus a long steady
     `night` trough where the autoscaler must have parked the fleet back
     down to warm spares. Two latched verdict inputs: the per-phase SLOs
-    (peak p99 is the `diurnal_autoscale.interactive_p99` gate stage —
+    (the peak phase's p99 among them —
     elasticity must not cost the peak), and the **steady-trough
     footprint ratio** — mean active cells during `night` over the
     static fleet size — which must stay ≤ `max_ratio`
-    (`diurnal_autoscale.steady_footprint_ratio` in tools/bench_gate.py).
+    (``extra.autoscale.steady_footprint_ratio``, latched into the verdict).
     Scale-downs migrate docs over the evict-snapshot→hydrate rail with
     zero acked loss; the runner attaches the roster timeline, scale
     decisions and migration counts as ``extra.autoscale``."""
@@ -862,10 +856,7 @@ def wire_saturation(
     ``extra.wire_saturation`` — per-rung offered vs. achieved frames/s
     (from the phase wire deltas), the headroom model's sustainable
     rate (``hocuspocus_profile_headroom_frames_per_s``) and the top-5
-    per-frame cost attribution. tools/bench_gate.py gates
-    ``wire_saturation.frames_per_s`` and
-    ``wire_saturation.headroom_frames_per_s`` as higher-is-better
-    stages. SLOs are deliberately generous — the verdict input here is
+    per-frame cost attribution. SLOs are deliberately generous — the verdict input here is
     throughput and attribution, not interactive latency."""
     return Scenario(
         name="wire_saturation",
@@ -935,8 +926,8 @@ SCENARIOS: "dict[str, Callable[..., Scenario]]" = {
     "wire_saturation": wire_saturation,
 }
 
-# the default suite bench.py / bench_capture run: fast enough for every
-# round, covers the single-instance, cross-instance, overload-shed,
+# the suite tier-1 pins its topology scenarios into: covers the
+# single-instance, cross-instance, overload-shed,
 # partition-heal, multi-device-rebalance and edge-tier (split front
 # door, cell-drain handoff, hot-doc follower fan-out) paths
 BENCH_SUITE = (

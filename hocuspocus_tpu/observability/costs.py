@@ -44,8 +44,8 @@ slices *inside* frame_decode; ``wal_append`` runs off-loop), each
 normalized per *ingress* frame so egress-side work (fan-out, encode)
 is charged back to the frame that caused it. The number rides on
 fleet digests (observability/fleet.py) so ``/debug/fleet`` shows
-per-node headroom, and the ``wire_saturation`` bench pass checks it
-against measured saturation (within 2x).
+per-node headroom, and the ``wire_saturation`` loadgen scenario
+reports it beside the frame rate its rate ladder reached.
 """
 
 from __future__ import annotations

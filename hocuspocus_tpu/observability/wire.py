@@ -453,10 +453,10 @@ class WireTelemetry:
             self.redis_frames_saved,
         )
 
-    # -- reading (bench / tests) ---------------------------------------------
+    # -- reading (loadgen / tests) -------------------------------------------
 
     def totals(self) -> dict:
-        """Aggregate snapshot for the bench's wire_load pass."""
+        """Aggregate snapshot; the loadgen runner takes per-phase deltas."""
         return {
             "messages_in": sum(self.messages_in._values.values()),
             "messages_out": sum(self.messages_out._values.values()),

@@ -117,9 +117,9 @@ class DevicePlacement:
 
     def placement_hash(self) -> str:
         """Content hash of the live placement map (cell count, healthy
-        set, overrides): two captures with equal hashes routed docs
-        identically — recorded in bench manifests so multichip rounds
-        are attributable."""
+        set, overrides): two runs with equal hashes routed docs
+        identically — recorded in a scenario's `extra.multi_device`
+        (loadgen/runner.py) and in `table()`."""
         payload = {
             "cells": self.cells,
             "salt": self.salt,
@@ -826,8 +826,8 @@ class MultiDeviceMergeExtension(Extension):
     @property
     def shards(self) -> "list[TpuMergeExtension]":
         """Shard-compatible view: the Metrics extension's summed plane
-        gauges, the loadgen harness and the bench suite all speak the
-        sharded router's `.shards` surface — cells are shards whose
+        gauges, the loadgen harness and the benchmark's counter reader
+        all speak the sharded router's `.shards` surface — cells are shards whose
         arenas happen to live on different chips."""
         return self.cells
 
@@ -901,7 +901,7 @@ class MultiDeviceMergeExtension(Extension):
         }
 
     def per_device_latency(self) -> "list[dict]":
-        """Per-device latency evidence for bench artifacts: each cell's
+        """Per-device latency evidence for a scenario's result: each cell's
         interactive lane-wait p99 and last flush cycle's device-side
         stage times, chip by chip."""
         out = []
@@ -930,7 +930,7 @@ class MultiDeviceMergeExtension(Extension):
         return out
 
     def utilization_spread(self) -> dict:
-        """Per-device doc/work spread for bench artifacts: max/mean doc
+        """Per-device doc/work spread for a scenario's result: max/mean doc
         and work ratios over the healthy cells (the multi_device_storm
         acceptance records these in extra)."""
         stats = [s for s in self.cell_stats() if s["healthy"]]

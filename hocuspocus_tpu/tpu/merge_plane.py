@@ -206,8 +206,8 @@ class MergePlane:
         self.max_slots_per_flush = max_slots_per_flush
         self.mesh = mesh
         # serializes flush + device readbacks when the extension runs
-        # flushes off the event loop (direct synchronous use — tests,
-        # benches — never contends)
+        # flushes off the event loop (direct synchronous use — tests —
+        # never contends)
         self.flush_lock = asyncio.Lock()
         # thread-level companion: flush() donates the old state buffers
         # to the kernel, so a reader interleaving with an executor-side
@@ -450,8 +450,8 @@ class MergePlane:
         }
         self.warm_failures: "list[dict]" = []
         # last completed flush cycle's stage breakdown (exported as
-        # gauges by observability/extension.py; reported by bench.py's
-        # sparse-load pass): host build / upload / device+readback ms,
+        # gauges by observability/extension.py): host build / upload /
+        # device+readback ms,
         # the (K, B) shape dispatched, busy width and fraction, bytes
         # shipped. Overwritten per cycle, never accumulated.
         self.flush_stats: dict[str, float] = {
@@ -801,7 +801,7 @@ class MergePlane:
 
     def note_trace(self, name: str) -> Optional[int]:
         """Capture-seam stamp: give one just-enqueued update a lifecycle
-        trace id (sampled). Called by try_capture and the benches."""
+        trace id (sampled). Called by try_capture."""
         return self.update_traces.stamp(name)
 
     def release(self, name: str) -> None:
@@ -1559,7 +1559,7 @@ class MergePlane:
                 # remote-attached TPUs); _sync_health below is the
                 # cycle's single completion barrier (content readback —
                 # buffer *readiness* of aliased Pallas outputs is not
-                # trustworthy, see bench.py sync()). The dispatch itself
+                # trustworthy). The dispatch itself
                 # is ASYNC: while the device integrates batch i, the
                 # next loop iteration builds and uploads batch i+1 from
                 # the OTHER staging buffer — that alternation is the
@@ -2469,7 +2469,7 @@ class TpuMergeExtension(Extension):
         lane — the device-lane arbiter this extension's device work
         admits through: a DeviceLane instance, None for the process-
         global one (all shards of one chip must share an arbiter), or
-        False to disable arbitration entirely (benches' off-leg).
+        False to disable arbitration entirely (a test seam).
         phase_offset_ms — deterministic timer phase (the sharded router
         assigns i/N spreads so N shards stop tick-aligning dispatches).
         drain_watermark — queue depth that collapses the tick to an

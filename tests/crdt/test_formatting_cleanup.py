@@ -123,7 +123,7 @@ def test_gap_cleanup_uses_identity_for_object_attrs():
     shape after a wire decode — every decode builds fresh objects) is
     therefore KEPT by yjs peers; deleting it with deep equality
     diverges our tombstone layout from yjs interop expectations
-    (round-5 ADVICE). Primitive values still dedup."""
+    (round-5 review). Primitive values still dedup."""
 
     def build(attr_value):
         a = Doc()

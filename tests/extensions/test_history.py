@@ -411,7 +411,7 @@ async def test_history_on_plane_served_docs():
 
 
 async def test_restore_with_all_tombstoned_array_root_is_not_half_rewritten():
-    """Regression (ADVICE.md): an array root EMPTIED before the
+    """Regression (round-5 review): an array root EMPTIED before the
     checkpoint carries only tombstones in the (gc-enabled) restored
     doc, and the old classifier defaulted it to 'text' — run() then
     called get_text() on the live YArray root and raised TypeError
@@ -469,7 +469,7 @@ async def test_restore_with_all_tombstoned_array_root_is_not_half_rewritten():
 
 
 async def test_store_minted_checkpoint_broadcasts_checkpointed():
-    """Regression (ADVICE.md): checkpoint_on_store minted versions
+    """Regression (round-5 review): checkpoint_on_store minted versions
     silently — clients only discovered them by polling history.list.
     The store path now broadcasts the same history.checkpointed event
     the stateless action does."""

@@ -240,7 +240,7 @@ async def test_restart_with_state_loss_reconverges():
 
 
 async def test_lost_reply_self_acquired_lock_is_recognized():
-    """Regression (ADVICE.md): execute() retries a SET NX once after a
+    """Regression (round-5 review): execute() retries a SET NX once after a
     transport failure; when the FIRST attempt executed server-side with
     its reply lost, the retry saw the key held and acquire_lock
     reported failure while this client's own token held the lock for a

@@ -113,7 +113,7 @@ async def test_awareness_cursor_helpers_roundtrip():
 
 
 async def test_history_client_rid_correlation_is_exact():
-    """Regression (ADVICE.md): errors were routed to the OLDEST pending
+    """Regression (round-5 review): errors were routed to the OLDEST pending
     future and broadcasts matched by kind alone, so another client's
     concurrent checkpoint/restore could resolve (or an error reject)
     the wrong awaitable. The rid echo makes correlation exact."""

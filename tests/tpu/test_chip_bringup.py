@@ -121,9 +121,9 @@ def _salt():
     return time.time_ns() % 2**30
 
 
-def _compile_once(cwd, salt, **env_changes):
+def _compile_once(cwd, salt, checkout, **env_changes):
     env = {k: v for k, v in os.environ.items() if not k.startswith("JAX_")}
-    env.update(PYTHONPATH=REPO, JAX_PLATFORMS="cpu", **env_changes)
+    env.update(PYTHONPATH=str(checkout), JAX_PLATFORMS="cpu", **env_changes)
     proc = subprocess.run(
         [sys.executable, "-c", _COMPILE_ONCE, str(salt)],
         env=env,
@@ -140,35 +140,46 @@ def _entries(directory):
     return set(os.listdir(directory)) if os.path.isdir(directory) else set()
 
 
+def _private_checkout(tmp_path):
+    """A checkout only this test's children import from: the package is
+    linked in, so `<checkout>/.jax_cache` is a directory no neighbour
+    test (six workers share the real one) can write into."""
+    checkout = tmp_path / "checkout"
+    checkout.mkdir()
+    os.symlink(os.path.join(REPO, "hocuspocus_tpu"), checkout / "hocuspocus_tpu")
+    return checkout
+
+
 def test_compile_cache_defaults_to_the_checkout(tmp_path):
     """Unset: <checkout>/.jax_cache whatever the cwd, a sub-second
     program is written there, and a second process reads it back."""
-    home = os.path.join(REPO, ".jax_cache")
+    checkout = _private_checkout(tmp_path)
+    home = str(checkout / ".jax_cache")
     salt = _salt()
-    before = _entries(home)
-    assert _compile_once(tmp_path, salt) == [home, 1, 0]
-    assert _entries(home) - before
+    assert _compile_once(tmp_path, salt, checkout) == [home, 1, 0]
+    assert _entries(home)
     (tmp_path / "elsewhere").mkdir()
-    assert _compile_once(tmp_path / "elsewhere", salt) == [home, 1, 1]
+    assert _compile_once(tmp_path / "elsewhere", salt, checkout) == [home, 1, 1]
 
 
 def test_compile_cache_env_var_is_left_to_jax(tmp_path):
+    checkout = _private_checkout(tmp_path)
     placed = str(tmp_path / "placed")
-    home = os.path.join(REPO, ".jax_cache")
-    before = _entries(home)
+    home = str(checkout / ".jax_cache")
     configured, requests, hits = _compile_once(
-        tmp_path, _salt(), JAX_COMPILATION_CACHE_DIR=placed
+        tmp_path, _salt(), checkout, JAX_COMPILATION_CACHE_DIR=placed
     )
     assert configured is None  # the code set nothing ...
     assert (requests, hits) == (1, 0)
     assert _entries(placed)  # ... JAX read the variable itself
-    assert _entries(home) == before
+    assert not os.path.exists(home)  # and the default home was never made
     # the environment's own compile-time floor is respected too: this
     # sub-second program is then not worth an entry
     floored = str(tmp_path / "floored")
     _compile_once(
         tmp_path,
         _salt(),
+        checkout,
         JAX_COMPILATION_CACHE_DIR=floored,
         JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="60",
     )

@@ -1,6 +1,6 @@
 """Served-load harness (hocuspocus_tpu.loadgen) at CI scale.
 
-The same harness bench.py uses for the at-scale served p99 — here with
+The harness the scenario runner builds on — here with
 small populations so CI proves the topology end-to-end: sockets-free
 providers, sharded serve planes, background load, cross-instance Redis
 fan-out (verdict item: "measure the SERVED 100k regime without

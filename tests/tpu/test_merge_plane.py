@@ -1,7 +1,8 @@
 """TPU merge plane correctness: device kernel vs CPU CRDT reference.
 
 Runs on the virtual CPU backend (conftest forces JAX_PLATFORMS=cpu with
-8 devices); the same code paths run on real TPU in bench.py.
+8 devices); the same code paths run on a real TPU in chip_smoke.py
+and the benchmark (bench/).
 """
 
 import random
