@@ -52,7 +52,7 @@ class Document(Doc):
         # durability capture seam (storage/extension.py): when attached,
         # every update is appended to the write-ahead log BEFORE any
         # broadcast, and the fan-out tick gates on the group-commit
-        # future the sink returns — no client sees an update before its
+        # gate the sink returns — no client sees an update before its
         # commit COMPLETES. A commit that completes with a disk error
         # still releases the gate (availability over durability: the
         # error is counted, /healthz degrades, and the store pipeline
@@ -163,7 +163,7 @@ class Document(Doc):
                     f"WAL append failed for {self.name!r}; broadcasting anyway"
                 )
             # plane windows broadcast later (queue_broadcast) — they
-            # gate on the newest append's commit future
+            # gate on the newest append's commit gate
             self._wal_gate = gate
         source = self.broadcast_source
         if source is not None:
@@ -207,7 +207,9 @@ class Document(Doc):
                 self._wal_gate = None
                 return
             try:
-                await gate
+                # shielded: the gate is shared with the broadcast ticks,
+                # and a joiner that goes away must not cancel it for them
+                await asyncio.shield(gate)
             except Exception:
                 return  # commit errors are counted elsewhere; serve
 

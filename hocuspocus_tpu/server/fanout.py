@@ -267,18 +267,19 @@ class DocumentFanout:
         # (REPLICA_PUSH). Tick-applied updates carry REPLICA_ORIGIN and
         # are non-replicable, so the seam never echoes.
         self.replica_sink: Optional[Callable[[list], Any]] = None
-        # durability gates (storage/extension.py): group-commit futures
-        # the tick must wait out before DELIVERING — an update is never
-        # shown to a client while the WAL write that covers it is still
-        # in flight (a commit that FAILS still releases the gate: the
-        # error is counted and health degrades; halting fan-out on a
-        # sick disk would trade availability for nothing, since the
-        # store pipeline still provides the durability floor).
-        # Coalescing and frame building stay synchronous (and overlap
-        # the commit on the executor); only the socket enqueue defers
-        # to the gate.
+        # durability gates (storage/wal.py `DurabilityGate`): the
+        # group-commit gates the tick must wait out before DELIVERING —
+        # an update is never shown to a client while the WAL write that
+        # covers it is still in flight (a commit that FAILS still
+        # releases the gate: the error is counted and health degrades;
+        # halting fan-out on a sick disk would trade availability for
+        # nothing, since the store pipeline still provides the
+        # durability floor). Coalescing and frame building stay
+        # synchronous (and overlap the commit on the log's lane thread);
+        # only the socket enqueue defers to the gate, and runs inside
+        # the gate's resolution: `_gated` holds what is registered there.
         self._gates: list = []
-        self._gate_tasks: set = set()
+        self._gated: "list[tuple[Any, Callable[[], None]]]" = []
 
     # -- enqueue -----------------------------------------------------------
 
@@ -498,29 +499,19 @@ class DocumentFanout:
         if not waiting:
             deliver_tick()
             return
-        self._spawn_gated_delivery(waiting, deliver_tick)
+        # gates resolve in append order, so the newest one still open is
+        # the last to resolve. Ticks stay ordered: a gate runs what
+        # registered on it in registration order
+        gate = waiting[-1]
 
-    def _spawn_gated_delivery(self, gates: list, deliver_tick: Callable) -> None:
-        """Run `deliver_tick` once every durability gate has resolved.
-        Ticks stay ordered: WAL commit futures resolve in append order,
-        and same-future waiters wake in task-creation order."""
-
-        async def waiter() -> None:
-            try:
-                for gate in gates:
-                    if not gate.done():
-                        try:
-                            await gate
-                        except Exception:
-                            pass  # commit errors are counted, never block
-            finally:
-                self._gate_tasks.discard(asyncio.current_task())
+        def released() -> None:
+            self._gated.remove(entry)
             with get_tracer().span("fanout.tick"):
                 deliver_tick()
 
-        # strong ref: a GC'd waiter would swallow the tick's frames
-        task = asyncio.ensure_future(waiter())
-        self._gate_tasks.add(task)
+        entry = (gate, released)
+        self._gated.append(entry)
+        gate.on_release(released)
 
     def deliver(self, audience, frame: bytes, tierable: bool = True) -> int:
         """Enqueue one shared frame to every connection; returns the
@@ -552,9 +543,9 @@ class DocumentFanout:
         self._pending_awareness = set()
         self._on_complete = []
         self._gates = []
-        for task in list(self._gate_tasks):
-            task.cancel()
-        self._gate_tasks.clear()
+        for gate, released in self._gated:
+            gate.discard(released)
+        self._gated = []
         self.replicate_updates = None
         self.replicate_awareness = None
         self.replica_sink = None

@@ -22,11 +22,12 @@ before every persistence extension at the default 100):
 - capture seam: after replay the document's `wal_sink` is attached —
   `Document._handle_update` appends every update (except WAL-origin
   replays) BEFORE broadcast and gates the fan-out tick on the group
-  commit future: no client is shown an update before its commit
-  completes. A commit completing WITH a disk error still releases the
-  gate — availability over durability; the error is counted,
-  `/healthz` degrades, and the store pipeline remains the durability
-  floor. `wal_checkpoint` lets the residency manager fold an eviction
+  commit's gate, which delivers the tick itself where the commit's
+  completion lands on the loop: no client is shown an update before
+  its commit completes. A commit completing WITH a disk error still
+  releases the gate — availability over durability; the error is
+  counted, `/healthz` degrades, and the store pipeline remains the
+  durability floor. `wal_checkpoint` lets the residency manager fold an eviction
   snapshot into the log (tpu/residency.py).
 """
 

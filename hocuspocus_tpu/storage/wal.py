@@ -28,9 +28,11 @@ Group commit: appends buffer in the manager and flush ONCE per event
 loop tick — one `write()` of the concatenated batch and one `fsync`
 per dirty document per tick, run OFF the loop in an executor (the same
 batch-amortization shape as the replication lane's one-flush-per-tick
-publish outbox, net/resp.py). Callers receive the tick's shared
-durability future; the broadcast fan-out gates on it so no client is
-ever shown an update the log could still lose.
+publish outbox, net/resp.py). Callers receive the batch's shared
+durability gate (`DurabilityGate`); the broadcast fan-out registers its
+delivery on it so no client is ever shown an update the log could still
+lose, and the delivery runs in the loop callback in which the commit's
+completion lands.
 """
 
 from __future__ import annotations
@@ -41,7 +43,8 @@ import struct
 import threading
 import time
 import zlib
-from typing import Any, Iterable, Optional
+from concurrent.futures import ThreadPoolExecutor
+from typing import Any, Callable, Optional
 from urllib.parse import quote
 
 from ..observability.tracing import get_tracer
@@ -400,9 +403,11 @@ FSYNC_MODES = ("tick", "always", "off")
 class WalManager:
     """Process-wide WAL: per-doc segment chains + the group-commit lane.
 
-    `append()` buffers and returns the current tick's shared durability
-    future; one flush per tick commits every dirty doc's batch off the
-    loop. `--wal-fsync` modes:
+    `append()` buffers and returns the current batch's shared
+    durability gate; one commit per tick writes every dirty doc's batch
+    off the loop, on the manager's own lane thread, and hands the gate
+    back to the loop with one `call_soon_threadsafe`. Commits are
+    serialised and in append order. `--wal-fsync` modes:
 
     - `tick` (default): per-doc segments are WRITTEN (page cache) but
       the tick's durability comes from the shared **commit journal** —
@@ -438,9 +443,14 @@ class WalManager:
         # name -> [(rec_type, payload, rotate_before, drop_older_after)]
         self._pending: "dict[str, list]" = {}
         self._pending_since: Optional[float] = None  # the oldest pending append
-        self._tick_future: Optional[asyncio.Future] = None
-        self._flush_task: Optional[asyncio.Task] = None
-        self._flush_lock = asyncio.Lock()
+        # the group-commit lane: `_gate` covers what is buffered,
+        # `_inflight` the batch the lane thread is writing (one at a
+        # time); `_start_handle` is the coalescing turn between the
+        # first append of a turn and its commit
+        self._gate: Optional[DurabilityGate] = None
+        self._inflight: Optional[DurabilityGate] = None
+        self._start_handle: Optional[asyncio.Handle] = None
+        self._lane: Optional[ThreadPoolExecutor] = None
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._closed = False
         # commit journal state (executor thread only, except the cache
@@ -477,10 +487,14 @@ class WalManager:
             "commit_last_ms": 0.0,
             # the same, summed over every commit (monotone), and per
             # commit batch the time from its oldest pending append to
-            # its futures being resolved on the loop: the longest a
-            # fan-out tick of that batch can have been gated
+            # the end of its commit-done step on the loop (gate resolved,
+            # its ticks delivered): the longest a fan-out tick of that
+            # batch can have been gated
             "commit_ms_total": 0.0,
             "durable_wait_ms_total": 0.0,
+            # fan-out ticks delivered from inside a gate's resolution (a
+            # tick that found its gate already done is not counted)
+            "ticks_released": 0,
         }
 
     @property
@@ -510,13 +524,13 @@ class WalManager:
 
     def append(
         self, name: str, payload: bytes, rec_type: int = REC_UPDATE
-    ) -> "asyncio.Future":
-        """Buffer one record into the current tick's group commit and
-        return the tick's shared durability future."""
+    ) -> "DurabilityGate":
+        """Buffer one record into the current group commit and return
+        the batch's shared durability gate."""
         self._buffer(name, (rec_type, payload, False, False))
         return self._schedule()
 
-    def checkpoint(self, name: str, snapshot: bytes) -> "asyncio.Future":
+    def checkpoint(self, name: str, snapshot: bytes) -> "DurabilityGate":
         """Append a full-state snapshot record into a FRESH segment and,
         once it is durable, drop every older segment — the snapshot
         subsumes them (an eviction/compaction checkpoint bounds the log
@@ -530,7 +544,7 @@ class WalManager:
             self._pending_since = time.perf_counter()
         self._pending.setdefault(name, []).append(entry)
 
-    def _schedule(self) -> "asyncio.Future":
+    def _schedule(self) -> "DurabilityGate":
         # the loop lookup sits on the per-update capture path: cache it
         # (one manager serves one loop; cross-loop reuse in tests goes
         # through the is_closed() check)
@@ -540,18 +554,23 @@ class WalManager:
                 loop = asyncio.get_running_loop()
             except RuntimeError:
                 # no loop (unit/direct use): commit synchronously
-                future: "asyncio.Future" = _SyncFuture()
+                gate = _SyncFuture()
                 pending, since = self._take_pending()
                 self._commit(pending)
-                future.set_result(None)
+                gate.set_result(None)
                 self._note_durable(since)
-                return future
+                return gate
+            # a lane left mid-commit by a loop that closed under it has
+            # nobody to hand back to: start over on this one
             self._loop = loop
-        if self._tick_future is None or self._tick_future.done():
-            self._tick_future = loop.create_future()
-        if self._flush_task is None or self._flush_task.done():
-            self._flush_task = loop.create_task(self._flush_async())
-        return self._tick_future
+            self._gate = self._inflight = self._start_handle = None
+        if self._gate is None:
+            self._gate = DurabilityGate(loop, self.stats)
+        if self._inflight is None and self._start_handle is None:
+            # one turn of the loop between the first append and the
+            # commit: every append of this turn joins the batch
+            self._start_handle = loop.call_soon(self._start_commit)
+        return self._gate
 
     def _take_pending(self) -> "tuple[dict[str, list], Optional[float]]":
         """The buffered batch, and when its oldest append was buffered."""
@@ -563,33 +582,49 @@ class WalManager:
         if since is not None:
             self.stats["durable_wait_ms_total"] += (time.perf_counter() - since) * 1000.0
 
-    async def _flush_async(self) -> None:
-        # serialize batches; appends landing mid-write join the NEXT
-        # iteration (the task loops until the buffer is empty, so a
-        # tick future created while a commit is on the executor is
-        # always picked up and resolved)
-        async with self._flush_lock:
-            while True:
-                pending, since = self._take_pending()
-                future, self._tick_future = self._tick_future, None
-                if pending:
-                    try:
-                        await asyncio.to_thread(self._commit, pending)
-                    except Exception:
-                        # never let a disk fault leak into the event loop
-                        pass
-                if future is not None and not future.done():
-                    # resolve even on failure: a broadcast gated on a
-                    # dead disk must not hang forever — the error is
-                    # counted and the records stay recoverable from the
-                    # store path
-                    future.set_result(None)
-                self._note_durable(since)
-                if not self._pending or self._closed:
-                    return
+    def _start_commit(self) -> None:
+        """Loop thread: hand everything buffered to the lane thread as
+        one batch. Appends that land while it is written join the NEXT
+        batch, which the commit-done step starts."""
+        self._start_handle = None
+        if self._inflight is not None or not self._pending:
+            return
+        pending, since = self._take_pending()
+        self._inflight, self._gate = self._gate, None
+        if self._lane is None:
+            self._lane = ThreadPoolExecutor(max_workers=1, thread_name_prefix="wal-commit")
+        self._lane.submit(self._run_commit, self._loop, pending, self._inflight, since)
+
+    def _run_commit(self, loop, pending: "dict[str, list]", gate, since) -> None:
+        """Lane thread: write the batch, then carry its gate back to the
+        loop with ONE threadsafe call — no wrapped future and no task
+        wake-up lie between the disk's return and the gate."""
+        try:
+            self._commit(pending)
+        except Exception:
+            # never let a disk fault leak into the event loop: the gate
+            # is released all the same (below), the error is counted and
+            # the records stay recoverable from the store path
+            pass
+        try:
+            loop.call_soon_threadsafe(self._commit_done, gate, since)
+        except RuntimeError:
+            pass  # the loop closed under the commit: nobody is gated
+
+    def _commit_done(self, gate: "DurabilityGate", since: Optional[float]) -> None:
+        """Loop thread, where the commit's completion lands: release the
+        batch's gate (its gated ticks are delivered here, in this turn
+        of the loop), then turn the lane round at once if records were
+        buffered meanwhile. Even a failed commit releases: a broadcast
+        gated on a dead disk must not hang forever."""
+        self._inflight = None
+        gate.release()
+        self._note_durable(since)
+        if self._pending and not self._closed:
+            self._start_commit()
 
     def _commit(self, pending: "dict[str, list]") -> None:
-        """Executor thread: write every dirty doc's batch, then make the
+        """Lane thread: write every dirty doc's batch, then make the
         whole tick durable with ONE journal fsync (tick mode)."""
         with get_tracer().span("wal.commit"):
             self._commit_batch(pending)
@@ -877,13 +912,11 @@ class WalManager:
     async def flush(self) -> None:
         """Force-commit everything buffered and wait for durability
         (the drain path's first step)."""
-        while self._pending or (
-            self._flush_task is not None and not self._flush_task.done()
-        ):
-            if self._pending:
-                await self._schedule()
-            else:
-                await self._flush_task
+        while self._pending or self._inflight is not None:
+            # shielded: the gate is shared, and a caller that gives up
+            # (a timeout, a cancelled drain) must not cancel it for the
+            # ticks and joiners that wait on it too
+            await asyncio.shield(self._schedule() if self._pending else self._inflight)
 
     # -- recovery / truncation ---------------------------------------------
 
@@ -934,6 +967,9 @@ class WalManager:
 
     def close(self) -> None:
         self._closed = True
+        if self._lane is not None:
+            self._lane.shutdown(wait=False)
+            self._lane = None
         for wal in self._docs.values():
             wal.close()
         self._docs.clear()
@@ -945,9 +981,53 @@ class WalManager:
             self._journal_fh = None
 
 
+class DurabilityGate(asyncio.Future):
+    """One commit batch's durability gate: done once the commit that
+    covers the batch has returned from the lane thread (with or without
+    a disk error). Awaitable from any task like the future it is; what
+    must not wait a further turn of the loop — a fan-out tick's delivery
+    — registers with `on_release` and runs INSIDE the resolution."""
+
+    def __init__(self, loop: asyncio.AbstractEventLoop, stats: dict) -> None:
+        super().__init__(loop=loop)
+        self._stats = stats
+        self._on_release: "list[Callable[[], Any]]" = []
+
+    def on_release(self, callback: "Callable[[], Any]") -> None:
+        """Run `callback()` synchronously inside this gate's resolution,
+        in registration order (at once if it has already resolved)."""
+        if self.done():
+            callback()
+        else:
+            self._on_release.append(callback)
+
+    def discard(self, callback: "Callable[[], Any]") -> None:
+        """Forget a registered callback (its document was destroyed)."""
+        try:
+            self._on_release.remove(callback)
+        except ValueError:
+            pass
+
+    def release(self) -> None:
+        """Resolve, then run what registered — each in its own `try`:
+        one tick's failure strands neither the others nor the lane."""
+        if not self.done():
+            self.set_result(None)
+        callbacks, self._on_release = self._on_release, []
+        for callback in callbacks:
+            self._stats["ticks_released"] += 1
+            try:
+                callback()
+            except Exception:
+                from ..server import logger as _logger_mod
+
+                _logger_mod.log_error("a gated delivery failed at its commit's completion")
+
+
 class _SyncFuture:
-    """Minimal already-done future for no-loop contexts (quacks enough
-    of the asyncio.Future surface for gate checks)."""
+    """Minimal already-done gate for no-loop contexts: the commit ran
+    before `append` returned, so nothing ever registers on it (quacks
+    enough of the future surface for gate checks)."""
 
     def __init__(self) -> None:
         self._result = None

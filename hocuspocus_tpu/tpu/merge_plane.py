@@ -3221,10 +3221,16 @@ class TpuMergeExtension(Extension):
         converge by CRDT idempotence either way)."""
         if not self.serve:
             return
+        queued: list = []
         with get_tracer().span("plane.broadcast"):
-            self._broadcast_pass(cross_instance)
+            self._broadcast_pass(cross_instance, queued)
+        # the window's ticks run now, not a turn of the loop later: each
+        # still waits for its durability gate, and the `call_soon` its
+        # enqueue scheduled finds nothing pending (server/fanout.py)
+        for document in queued:
+            document.fanout.flush()
 
-    def _broadcast_pass(self, cross_instance: bool) -> None:
+    def _broadcast_pass(self, cross_instance: bool, queued: list) -> None:
         plane = self.plane
         dirty = list(plane.dirty)
         plane.dirty.clear()
@@ -3298,6 +3304,7 @@ class TpuMergeExtension(Extension):
                         lambda t_last, _name=name: book.finish(_name, t_last)
                     ),
                 )
+                queued.append(document)
                 if (
                     cross_instance
                     and cross_update is not None

@@ -1,0 +1,57 @@
+"""`ticks_released_per_commit`: the reader on hand-made runs, its entry in the
+manifest, and the program's counter it reads. Runs on the CPU; loads no libtpu."""
+
+from __future__ import annotations
+
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, os.path.join(ROOT, "bench", "lib"))
+
+from manifest import Manifest  # noqa: E402
+
+METRIC = "ticks_released_per_commit"
+
+
+@pytest.fixture(scope="module")
+def manifest():
+    return Manifest()
+
+
+@pytest.mark.parametrize(
+    "wal_delta, expected",
+    [
+        ({"ticks_released": 30, "commit_batches": 12, "appended_records": 40}, 2.5),
+        ({"ticks_released": 0, "commit_batches": 12}, 0.0),  # commits, and no tick was ever gated
+        ({"commit_batches": 12, "durable_wait_ms_total": 66.0}, None),  # the parent commit: no such counter
+        ({"ticks_released": 0, "commit_batches": 0}, None),  # nothing committed in the window
+        ({}, None),  # a server without a log
+    ],
+)
+def test_the_reader_on_a_hand_made_run(manifest, wal_delta, expected):
+    found = manifest.reader(METRIC)({"wal_delta": wal_delta, "plane_delta": {}, "trace": None})
+    assert found == expected
+
+
+def test_every_cell_reports_it_under_the_log_layer(manifest):
+    entry = manifest.data["per_layer"][-1]
+    assert entry == {
+        "name": METRIC, "unit": "ticks", "better": "higher", "source": "program_counter",
+        "layer": "write-ahead log", "moves": "update_to_peer_p95_ms",
+    }
+    manifest.check_names()
+    for cell in manifest.cells:
+        assert METRIC in {m["name"] for m in manifest.metrics_of(cell, "per_layer")}
+
+
+def test_the_log_keeps_the_counter_the_reader_reads(tmp_path):
+    """`wal_delta` (bench/run.py) is the difference of `wal.stats` at the window's
+    edges, key by key: the counter has to be there from the start, as a number."""
+    sys.path.insert(1, ROOT)
+    from hocuspocus_tpu.storage import WalManager
+
+    stats = WalManager(str(tmp_path)).stats
+    assert stats["ticks_released"] == 0 and stats["commit_batches"] == 0

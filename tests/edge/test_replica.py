@@ -161,8 +161,11 @@ async def test_midstream_follower_join_converges():
         late_id = late.pop()
         writer.document.get_text("body").insert(0, "after-join ")
         owner_doc = _cell_doc(topo, owner_id, "viral")
+        # the owner first (the writer's edit is still on its way to it, and
+        # follower == owner holds before it lands, too), then the follower
         await wait_for(
-            lambda: _cell_doc(topo, late_id, "viral") is not None
+            lambda: "after-join" in str(owner_doc.get_text("body"))
+            and _cell_doc(topo, late_id, "viral") is not None
             and encode_state_as_update(_cell_doc(topo, late_id, "viral"))
             == encode_state_as_update(owner_doc),
             timeout=15,

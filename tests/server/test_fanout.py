@@ -415,3 +415,174 @@ async def test_plane_broadcast_rides_tick_and_closes_trace_at_last_enqueue():
     assert len(marks) == 1 and marks[0] >= t0
     await client.drained()
     assert client.doc.get_text("t").to_string() == "window"
+
+
+# -- durability gates: a commit delivers its own ticks -----------------------
+
+
+def _logged(wal, name: str, order: list, audience: int = 1):
+    """A document whose updates go to `wal` before any broadcast, as
+    storage/extension.py wires it, with clients that note every enqueue."""
+    document = Document(name)
+    document.wal_sink = lambda update, origin: wal.append(name, update)
+    clients = [FakeClient(document) for _ in range(audience)]
+    for client in clients:
+        send = client.connection.send
+
+        def noting(data, _send=send):
+            order.append(name)
+            _send(data)
+
+        client.connection.send = noting
+    return document, clients
+
+
+async def test_gated_ticks_are_enqueued_in_the_turn_their_commit_lands(tmp_path):
+    """No frame of a gated tick is enqueued before `_commit` has returned,
+    and every one of them is by the end of the loop callback in which the
+    commit's completion lands: the same turn, not two later."""
+    from hocuspocus_tpu.storage import WalManager
+    from tests.utils import HoldingFaults, TurnCounter
+
+    faults = HoldingFaults()
+    wal = WalManager(str(tmp_path), fsync="tick", faults=faults)
+    events: list = []
+    document, clients = _logged(wal, "gated", events, audience=3)
+    turns = TurnCounter()
+    real_commit, real_done = wal._commit, wal._commit_done
+    sent_at: list = []
+
+    def commit(pending):
+        real_commit(pending)
+        events.append("commit returned")
+
+    def commit_done(gate, since):
+        real_done(gate, since)
+        sent_at.append((turns.turn, list(events)))
+
+    wal._commit, wal._commit_done = commit, commit_done
+    try:
+        text = document.get_text("t")
+        text.insert(0, "durable ")
+        await faults.held()
+        text.insert(len(text), "first")  # a second tick behind the same held commit
+        await faults.held()
+        assert events == [], "a frame outran the commit that covers it"
+        assert wal.stats["ticks_released"] == 0
+        faults.release.set()
+        await asyncio.wait_for(wal.flush(), timeout=5)
+    finally:
+        turns.stop()
+    # the first commit released the first tick, the second the second one;
+    # each had every frame enqueued when its commit-done step returned
+    assert sent_at[0][1] == ["commit returned"] + ["gated"] * 3
+    assert sent_at[1][1] == sent_at[0][1] + ["commit returned"] + ["gated"] * 3
+    assert sent_at[1][0] > sent_at[0][0]
+    assert wal.stats["ticks_released"] == 2 and wal.stats["commit_batches"] == 2
+    for client in clients:
+        await client.drained()
+        assert client.doc.get_text("t").to_string() == "durable first"
+
+
+async def test_gated_ticks_of_two_commits_deliver_in_append_order(tmp_path):
+    from hocuspocus_tpu.storage import WalManager
+    from tests.utils import HoldingFaults
+
+    faults = HoldingFaults()
+    wal = WalManager(str(tmp_path), fsync="tick", faults=faults)
+    order: list = []
+    doc_a, (client_a,) = _logged(wal, "a", order)
+    doc_b, (client_b,) = _logged(wal, "b", order)
+    text_a, text_b = doc_a.get_text("t"), doc_b.get_text("t")
+    text_a.insert(0, "a1 ")  # first commit: one tick of each document
+    text_b.insert(0, "b1 ")
+    await faults.held()
+    first = doc_a._wal_gate
+    for text, step in ((text_a, "a2 "), (text_b, "b2 "), (text_a, "a3 ")):
+        text.insert(len(text), step)  # behind the held commit: the second one's
+        await asyncio.sleep(0)
+    assert doc_a._wal_gate is doc_b._wal_gate is not first
+    assert order == [] and len(doc_a.fanout._gated) == 3 and len(doc_b.fanout._gated) == 2
+    faults.release.set()
+    await asyncio.wait_for(wal.flush(), timeout=5)
+    assert order == ["a", "b", "a", "b", "a"]
+    assert doc_a.fanout._gated == [] and doc_b.fanout._gated == []
+    assert wal.stats["ticks_released"] == 5 and wal.stats["commit_batches"] == 2
+    await client_a.drained()
+    await client_b.drained()
+    assert client_a.doc.get_text("t").to_string() == "a1 a2 a3 "
+    assert client_b.doc.get_text("t").to_string() == "b1 b2 "
+    assert client_a.update_frames == 3 and client_b.update_frames == 2
+
+
+async def test_a_document_destroyed_while_gated_delivers_nothing(tmp_path):
+    from hocuspocus_tpu.storage import WalManager
+    from tests.utils import HoldingFaults
+
+    faults = HoldingFaults()
+    wal = WalManager(str(tmp_path), fsync="tick", faults=faults)
+    order: list = []
+    document, _clients = _logged(wal, "gone", order)
+    survivor, _ = _logged(wal, "stays", order)
+    document.get_text("t").insert(0, "never shown")
+    survivor.get_text("t").insert(0, "shown")
+    await faults.held()
+    gate = document._wal_gate
+    assert len(gate._on_release) == 2 and len(document.fanout._gated) == 1
+    document.destroy()
+    # nothing of the destroyed document stays registered on the gate
+    assert len(gate._on_release) == 1 and document.fanout._gated == []
+    faults.release.set()
+    await asyncio.wait_for(wal.flush(), timeout=5)
+    assert order == ["stays"]
+    assert wal.stats["ticks_released"] == 1
+
+
+async def test_ticks_released_counts_gated_ticks_only(tmp_path):
+    from hocuspocus_tpu.storage import WalManager
+
+    wal = WalManager(str(tmp_path), fsync="tick")
+    order: list = []
+    document, (client,) = _logged(wal, "counted", order)
+    unlogged = Document("no-log")
+    FakeClient(unlogged)
+    document.get_text("t").insert(0, "gated")
+    unlogged.get_text("t").insert(0, "no gate at all")
+    await asyncio.wait_for(wal.flush(), timeout=5)
+    assert wal.stats["ticks_released"] == 1 and order == ["counted"]
+    # a plane window queued after its commit has landed finds the gate
+    # done: delivered from the tick itself, and not counted
+    probe = Doc()
+    captured: list = []
+    probe.on("update", lambda update, *rest: captured.append(update))
+    probe.get_text("t").insert(0, "window")
+    assert document._wal_gate.done()
+    document.queue_broadcast(captured[0])
+    document.awareness.set_local_state({"cursor": 1})
+    await asyncio.sleep(0)
+    assert order == ["counted"] * 3  # the window, and the awareness tick
+    assert wal.stats["ticks_released"] == 1
+    await document.wait_wal_durable()  # nothing open: returns at once
+    await client.drained()
+
+
+async def test_a_joiner_that_goes_away_does_not_cancel_the_gate_of_the_ticks(tmp_path):
+    from hocuspocus_tpu.storage import WalManager
+    from tests.utils import HoldingFaults
+
+    faults = HoldingFaults()
+    wal = WalManager(str(tmp_path), fsync="tick", faults=faults)
+    order: list = []
+    document, (client,) = _logged(wal, "joined", order)
+    document.get_text("t").insert(0, "kept")
+    await faults.held()
+    joiner = asyncio.ensure_future(document.wait_wal_durable())
+    await asyncio.sleep(0)
+    joiner.cancel()
+    await asyncio.sleep(0)
+    assert joiner.cancelled() and not document._wal_gate.done() and order == []
+    faults.release.set()
+    await asyncio.wait_for(document.wait_wal_durable(), timeout=5)
+    assert order == ["joined"] and wal.stats["ticks_released"] == 1
+    await client.drained()
+    assert client.doc.get_text("t").to_string() == "kept"

@@ -10,6 +10,7 @@ import asyncio
 import os
 
 from tests.utils import (
+    HoldingFaults,
     new_hocuspocus,
     new_provider,
     retryable_assertion,
@@ -323,6 +324,50 @@ async def test_broadcast_waits_for_group_commit(tmp_path):
             "a broadcast frame outran its WAL group commit"
         )
     finally:
+        writer.destroy()
+        observer.destroy()
+        await server.destroy()
+
+
+async def test_a_plane_window_is_delivered_by_the_commit_that_covers_it(tmp_path):
+    """A plane-served document: the broadcast pass flushes the ticks it
+    queued before it returns, the window waits on the log's gate, and the
+    commit's completion delivers it — never before the commit returned."""
+    from hocuspocus_tpu.tpu import TpuMergeExtension
+
+    faults = HoldingFaults()
+    durability = Durability(wal_dir=str(tmp_path / "wal"), faults=faults)
+    ext = TpuMergeExtension(num_docs=8, capacity=1024, flush_interval_ms=1, serve=True)
+    server = await new_hocuspocus(extensions=[durability, ext], debounce=60000)
+    writer = new_provider(server, name="window")
+    observer = new_provider(server, name="window")
+    left_queued = []
+    broadcast_served = ext._broadcast_served
+
+    def served(*args, **kwargs):
+        broadcast_served(*args, **kwargs)
+        left_queued.extend(d.name for d in server.documents.values() if d.fanout._pending_updates)
+
+    ext._broadcast_served = served
+    try:
+        await wait_synced(writer, observer)
+        document = server.documents["window"]
+        assert "window" in ext._docs
+        writer.document.get_text("t").insert(0, "through the plane")
+        await faults.held()
+        await wait_for(lambda: document.fanout._gated)
+        assert observer.document.get_text("t").to_string() == ""
+        assert durability.wal.stats["ticks_released"] == 0
+        faults.release.set()
+        await retryable_assertion(
+            lambda: _assert(observer.document.get_text("t").to_string() == "through the plane")
+        )
+        assert durability.wal.stats["ticks_released"] >= 1
+        assert ext.plane.counters["plane_broadcasts"] >= 1
+        assert ext.plane.counters["cpu_fallbacks"] == 0
+        assert left_queued == [], "a pass returned with a tick it queued still pending"
+    finally:
+        faults.release.set()
         writer.destroy()
         observer.destroy()
         await server.destroy()

@@ -19,6 +19,7 @@ from hocuspocus_tpu.storage import (
     decode_records,
     encode_record,
 )
+from tests.utils import HoldingFaults, TurnCounter
 
 
 def _payloads(records):
@@ -395,3 +396,179 @@ async def test_failed_batch_burns_sequence_numbers(tmp_path):
     assert b"after-encode" in _payloads(records), (
         "post-encode record was truncated as store-covered"
     )
+
+
+# -- the commit lane and its gate ----------------------------------------------
+
+
+async def test_gate_resolves_in_the_turn_the_commit_lands(tmp_path):
+    """One threadsafe call carries the gate from the lane thread to the
+    loop: the commit-done step resolves the gate and runs what registered
+    on it in ONE turn of the loop, in registration order, and nothing runs
+    before `_commit` has returned."""
+    faults = HoldingFaults()
+    wal = WalManager(str(tmp_path), fsync="tick", faults=faults)
+    events = []
+    real_commit, real_done = wal._commit, wal._commit_done
+    turns = TurnCounter()
+
+    def commit(pending):
+        real_commit(pending)
+        events.append("commit returned")
+
+    def commit_done(gate, since):
+        events.append(("commit done", turns.turn))
+        real_done(gate, since)
+
+    wal._commit, wal._commit_done = commit, commit_done
+    try:
+        gate = wal.append("doc", b"x")
+        for tag in ("first", "second", "third"):
+            gate.on_release(lambda tag=tag: events.append((tag, turns.turn, gate.done())))
+        await faults.held()
+        assert events == [] and not gate.done()
+        faults.release.set()
+        await asyncio.wait_for(gate, timeout=5)
+    finally:
+        turns.stop()
+    landed = events[1][1]
+    assert events == [
+        "commit returned",
+        ("commit done", landed),
+        ("first", landed, True),
+        ("second", landed, True),
+        ("third", landed, True),
+    ]
+    assert wal.stats["ticks_released"] == 3
+    assert wal.stats["durable_wait_ms_total"] > 0
+    # registering on a resolved gate runs at once, and is not a release
+    gate.on_release(lambda: events.append("late"))
+    assert events[-1] == "late" and wal.stats["ticks_released"] == 3
+
+
+async def test_commit_done_turns_the_lane_round_at_once(tmp_path):
+    """Appends that land mid-commit join the next batch, and the
+    commit-done step starts that batch itself: commits stay serialised
+    and in append order, with no turn of the loop between them."""
+    faults = HoldingFaults()
+    wal = WalManager(str(tmp_path), fsync="off", faults=faults)
+    first = wal.append("doc", b"one")
+    await faults.held()
+    second = wal.append("doc", b"two")
+    third = wal.append("other", b"three")
+    assert second is third and second is not first
+    assert wal._inflight is first and wal._start_handle is None
+    started = []
+    first.on_release(lambda: started.append(wal._inflight))
+    second.on_release(lambda: started.append("second released"))
+    faults.release.set()
+    await asyncio.wait_for(second, timeout=5)
+    # inside the first gate's resolution the lane was still this batch's;
+    # by the end of that step the next batch was in flight
+    assert started == [None, "second released"]
+    assert wal.stats["commit_batches"] == 2
+    assert wal._inflight is None and wal._gate is None and not wal._pending
+    records, _ = await wal.replay("doc")
+    assert _payloads(records) == [b"one", b"two"]
+
+
+async def test_flush_waits_for_the_commit_in_flight_and_what_is_buffered(tmp_path):
+    faults = HoldingFaults()
+    wal = WalManager(str(tmp_path), fsync="tick", faults=faults)
+    wal.append("doc", b"one")
+    await faults.held()
+    wal.append("doc", b"two")  # buffered behind the held commit
+    flushed = asyncio.ensure_future(wal.flush())
+    await asyncio.sleep(0.01)
+    assert not flushed.done()
+    faults.release.set()
+    await asyncio.wait_for(flushed, timeout=5)
+    assert wal.stats["appended_records"] == 2 and wal.stats["commit_batches"] == 2
+    await asyncio.wait_for(wal.flush(), timeout=5)  # nothing left: returns at once
+
+
+def test_no_loop_append_is_durable_before_it_returns(tmp_path):
+    """Direct use with no running loop: the commit runs inside `append`,
+    and the gate it returns has nothing left to wait for."""
+    wal = WalManager(str(tmp_path), fsync="tick")
+    gate = wal.append("doc", b"sync")
+    assert gate.done() and gate.result() is None
+    assert wal.stats["appended_records"] == 1 and wal.stats["fsyncs"] == 1
+    assert wal.stats["ticks_released"] == 0
+    records, _ = asyncio.run(wal.replay("doc"))
+    assert b"sync" in _payloads(records)
+    assert wal.checkpoint("doc", b"snapshot").done()
+    records, _ = asyncio.run(wal.replay("doc"))
+    assert _payloads(records) == [b"snapshot"]  # the checkpoint subsumed the history
+
+
+async def test_a_release_callback_that_raises_strands_nothing(tmp_path):
+    wal = WalManager(str(tmp_path), fsync="off")
+    ran = []
+    gate = wal.append("doc", b"one")
+
+    def boom():
+        raise RuntimeError("a tick's delivery failed")
+
+    gate.on_release(lambda: ran.append("before"))
+    gate.on_release(boom)
+    gate.on_release(lambda: ran.append("after"))
+    gate.on_release(lambda: wal.append("doc", b"two"))  # buffered inside the resolution
+    await asyncio.wait_for(gate, timeout=5)
+    assert ran == ["before", "after"]
+    assert wal.stats["ticks_released"] == 4
+    await asyncio.wait_for(wal.flush(), timeout=5)
+    records, _ = await wal.replay("doc")
+    assert _payloads(records) == [b"one", b"two"]
+
+
+async def test_a_failed_commit_releases_what_registered(tmp_path):
+    faults = FaultInjector()
+    wal = WalManager(str(tmp_path), fsync="tick", faults=faults)
+    faults.fail_disk_full(1)
+    released = []
+    gate = wal.append("doc", b"x")
+    gate.on_release(lambda: released.append(gate.done()))
+    discarded = lambda: released.append("discarded")  # noqa: E731
+    gate.on_release(discarded)
+    gate.discard(discarded)
+    gate.discard(discarded)  # forgetting twice is harmless
+    await asyncio.wait_for(gate, timeout=5)
+    assert released == [True]
+    assert wal.stats["append_errors"] == 1 and wal.stats["ticks_released"] == 1
+
+
+async def test_a_commit_that_raises_still_releases_and_the_lane_goes_on(tmp_path):
+    wal = WalManager(str(tmp_path), fsync="off")
+    real_commit = wal._commit
+    calls = []
+
+    def commit(pending):
+        calls.append(sorted(pending))
+        if len(calls) == 1:
+            raise RuntimeError("not a disk error")
+        real_commit(pending)
+
+    wal._commit = commit
+    await asyncio.wait_for(wal.append("doc", b"lost"), timeout=5)
+    await asyncio.wait_for(wal.append("doc", b"kept"), timeout=5)
+    assert calls == [["doc"], ["doc"]]
+    records, _ = await wal.replay("doc")
+    assert _payloads(records) == [b"kept"]
+
+
+async def test_a_waiter_that_gives_up_does_not_cancel_the_shared_gate(tmp_path):
+    """`flush()` under a timeout (the shutdown path) awaits the batch's gate;
+    giving up must leave the gate to the ticks registered on it."""
+    faults = HoldingFaults()
+    wal = WalManager(str(tmp_path), fsync="tick", faults=faults)
+    gate = wal.append("doc", b"x")
+    released = []
+    gate.on_release(lambda: released.append(gate.cancelled()))
+    await faults.held()
+    with pytest.raises(asyncio.TimeoutError):
+        await asyncio.wait_for(wal.flush(), timeout=0.05)
+    assert not gate.done() and released == []
+    faults.release.set()
+    await asyncio.wait_for(wal.flush(), timeout=5)
+    assert released == [False] and wal.stats["appended_records"] == 1

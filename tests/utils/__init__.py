@@ -8,11 +8,13 @@ clients over real WebSockets, in one process.
 from __future__ import annotations
 
 import asyncio
+import threading
 import time
 from typing import Any, Optional
 
 from hocuspocus_tpu.provider import HocuspocusProvider, HocuspocusProviderWebsocket
 from hocuspocus_tpu.server import Configuration, Server
+from hocuspocus_tpu.storage import FaultInjector
 
 
 async def new_hocuspocus(**options: Any) -> Server:
@@ -124,3 +126,43 @@ class EventCollector:
             except asyncio.TimeoutError:
                 continue
         return self.events
+
+
+class HoldingFaults(FaultInjector):
+    """Holds the log's lane thread inside a commit (at the first disk
+    check of the batch) until `release` is set; later commits pass
+    straight through."""
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.entered = threading.Event()
+        self.release = threading.Event()
+
+    def check_disk_full(self) -> None:
+        self.entered.set()
+        assert self.release.wait(timeout=10), "the test never released the held commit"
+        super().check_disk_full()
+
+    async def held(self) -> None:
+        """Wait until a commit sits on the lane thread, then let the loop
+        turn a few times: whatever is going to wait for it waits by now."""
+        await wait_for(self.entered.is_set, interval=0.001)
+        for _ in range(5):
+            await asyncio.sleep(0)
+
+
+class TurnCounter:
+    """Counts the loop's turns: a `call_soon` handle that re-arms itself
+    runs once in every `_run_once`."""
+
+    def __init__(self) -> None:
+        self.turn = 0
+        self._loop = asyncio.get_running_loop()
+        self._handle = self._loop.call_soon(self._tick)
+
+    def _tick(self) -> None:
+        self.turn += 1
+        self._handle = self._loop.call_soon(self._tick)
+
+    def stop(self) -> None:
+        self._handle.cancel()
