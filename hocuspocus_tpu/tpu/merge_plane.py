@@ -421,6 +421,19 @@ class MergePlane:
             # batch) and the bucket width B they were padded to
             "flush_busy_rows": 0,
             "flush_bucket_rows": 0,
+            # why the classifier sent an op to the full-row integrate
+            # (they sum to flush_slow_ops while run_merge_enabled): an
+            # insert that names a right origin, so not at its row's
+            # tail; a delete; an append that does not chain off the
+            # tracked tail (a second author got there first, the tail
+            # is unknown) or that shares its column with one of those
+            "slow_ops_mid_row": 0,
+            "slow_ops_delete": 0,
+            "slow_ops_concurrent": 0,
+            # arena units the integrate batches swept: each batch's
+            # bucket rows, padding included, x the row's capacity (the
+            # kernels sweep whole rows, not the document's length)
+            "integrate_row_units": 0,
             # broadcast passes run, and the time from when each was
             # scheduled (the first capture since the last pass) to when
             # it ran: the coalescing window, the phase alignment and
@@ -1565,7 +1578,11 @@ class MergePlane:
                 # the OTHER staging buffer — that alternation is the
                 # double-buffered pipeline.
                 with tracer.span(
-                    "merge_plane.integrate", slots=k, busy=b, integrated=slow[5]
+                    "merge_plane.integrate",
+                    slots=k,
+                    busy=b,
+                    integrated=slow[5],
+                    row_units=self.capacity,
                 ):
                     self.state, _count = step(self.state, *step_args)
                 t_dispatch = time.perf_counter()
@@ -1594,6 +1611,7 @@ class MergePlane:
                     self.counters[self.cell_ops_key] += slow[5]
                 self.counters["flush_busy_rows"] += b_actual
                 self.counters["flush_bucket_rows"] += b
+                self.counters["integrate_row_units"] += b * self.capacity
                 slow_total += slow[5]
                 device_batches += 1
                 if cycle_traces:
@@ -1919,6 +1937,7 @@ class MergePlane:
         col_starts = np.flatnonzero(first)
         col_ok = np.logical_and.reduceat(ok, col_starts)
         if not col_ok.any():
+            self._count_slow_reasons(kind_s, rc_s)
             return None, drained
         counts = np.diff(np.append(col_starts, n))
         member = np.repeat(col_ok, counts)
@@ -1973,7 +1992,22 @@ class MergePlane:
             int(n - m),
             int(row_s[keep].max()) + 1,
         )
+        self._count_slow_reasons(slow[2][0], slow[2][6])
         return fast, slow
+
+    def _count_slow_reasons(self, kinds: np.ndarray, right_clients: np.ndarray) -> None:
+        """Why each op of this cycle's slow columns takes the full-row
+        integrate, by the op's own shape: a delete; an insert that names
+        a right origin (not at its row's tail); anything else is an
+        append the classifier could not chain off the tracked tail, or
+        one that rides in a column with a slow op."""
+        deletes = int(np.count_nonzero(kinds == KIND_DELETE))
+        mid_row = int(
+            np.count_nonzero((kinds == KIND_INSERT) & (right_clients != NONE_CLIENT))
+        )
+        self.counters["slow_ops_delete"] += deletes
+        self.counters["slow_ops_mid_row"] += mid_row
+        self.counters["slow_ops_concurrent"] += int(kinds.size) - deletes - mid_row
 
     def _append_staging_for(self, batch_index: int, k: int) -> _AppendStaging:
         """The append fast path's staging buffer for this batch — same

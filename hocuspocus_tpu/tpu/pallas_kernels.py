@@ -189,6 +189,13 @@ _VMEM_BUDGET = 96 * 1024 * 1024
 # "scoped allocation 19.68M" at db=32, N=5632 => 19.68e6/(32*5632*4)
 # ~ 27.3 buffers. 28 gives margin; tests/tpu/test_pallas_kernels.py
 # asserts the model against that shape so a regression fails in CI.
+# Shapes the model's choice has been checked at on a v5e since: db=16
+# and 64 at N=5632 (every run of the benchmark's 10 KB cells), and
+# db=8 at N=106,496, 95.4 MB of the budget and 19 times the measured
+# shape: Mosaic compiles it and the warm grid runs it on the chip at
+# B=16, 64, 256 and the dense 448 rows (PERF.md, PR 34; pinned in
+# tests/tpu/test_long_rows.py). Past ~112k units a row no block fits
+# and every width takes the scan.
 _LIVE_BUFFERS = 28
 
 
@@ -325,8 +332,11 @@ def integrate_op_slots_sparse_pallas(
 ) -> tuple[DocState, jax.Array]:
     """Sparse dispatch via Pallas; ops fields are (K, B), slots (B,).
 
-    Takes the sparse XLA scan when B has no valid doc-block factor
-    (B < 8); a Mosaic failure raises (see integrate_op_slots_pallas)."""
+    Takes the sparse XLA scan when no doc-block both divides B and
+    fits VMEM at this row length: B < 8 at any length, every B once a
+    row is too long for a block of 8 (past ~112k units; a 106,496-unit
+    row still runs the kernel at db=8). A Mosaic failure raises (see
+    integrate_op_slots_pallas)."""
     from .kernels import integrate_op_slots_sparse
 
     if _pick_block(int(slots.shape[0]), state.id_client.shape[1]) == 0:
