@@ -11,7 +11,8 @@ process-global collector (same singleton pattern as `get_tracer` /
 - sync-step latency by step (step1/step2/update) and auth
   (Auth-frame → hook chain complete) latency,
 - per-connection send-queue depth (summed live gauge), the high-water
-  mark, and backpressure-watermark crossings
+  mark, backpressure-watermark crossings, and socket writes by who
+  made them: `send()` itself or the writer task
   (`CallbackWebSocketTransport`),
 - socket churn: sockets opened/closed and close-code counters
   (`ClientConnection` / the websocket host),
@@ -138,6 +139,21 @@ class WireTelemetry:
         self.send_queue_overflows = Counter(
             "hocuspocus_wire_send_queue_overflow_total",
             "Transports closed because their send queue hit the bound",
+        )
+        # socket writes by who made them (server/transports.py): plain
+        # integers, always on, so the share written through is countable
+        # on any server without enabling the rest
+        self.frames_written_inline = 0
+        self.frames_written_queued = 0
+        self.frames_inline = Counter(
+            "hocuspocus_wire_frames_written_inline_total",
+            "Frames send() wrote to an idle socket itself, in the caller's turn",
+            fn=lambda: self.frames_written_inline,
+        )
+        self.frames_queued = Counter(
+            "hocuspocus_wire_frames_written_queued_total",
+            "Frames a connection's writer task shipped from its send queue",
+            fn=lambda: self.frames_written_queued,
         )
         self.pubsub_publishes = Counter(
             "hocuspocus_wire_pubsub_publishes_total",
@@ -440,6 +456,8 @@ class WireTelemetry:
             self.catchup_tier_transitions,
             self.sync_cache_events,
             self.send_queue_overflows,
+            self.frames_inline,
+            self.frames_queued,
             self.pubsub_publishes,
             self.pubsub_deliveries,
             self.pubsub_dropped,
@@ -472,6 +490,8 @@ class WireTelemetry:
             "sync_cache_hits": self._sync_cache_total("hit"),
             "sync_cache_misses": self._sync_cache_total("miss"),
             "queue_overflows": sum(self.send_queue_overflows._values.values()),
+            "frames_written_inline": self.frames_written_inline,
+            "frames_written_queued": self.frames_written_queued,
             "pubsub_publishes": sum(self.pubsub_publishes._values.values()),
             "pubsub_deliveries": sum(self.pubsub_deliveries._values.values()),
             "pubsub_dropped": sum(self.pubsub_dropped._values.values()),

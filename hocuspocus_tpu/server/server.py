@@ -30,17 +30,46 @@ from .types import Configuration, Payload
 class AiohttpWebSocketTransport(CallbackWebSocketTransport):
     """The generic queue-backed transport bound to an aiohttp
     WebSocketResponse (one concurrency machinery, two hosts — see
-    transports.py)."""
+    transports.py). `ws.send_bytes` reaches the socket without
+    suspending unless aiohttp's writer has to drain a paused protocol,
+    so the binding declares write-through."""
 
     def __init__(self, ws: web.WebSocketResponse) -> None:
         self.ws = ws
+        # built by the handler task that reads this socket: what that
+        # task sends this socket is a reply to a frame it is dispatching
+        self._reader_task = asyncio.current_task()
         super().__init__(
             send_async=ws.send_bytes,
             close_async=lambda code, reason: ws.close(
                 code=code, message=reason.encode()
             ),
             is_closed_check=lambda: ws.closed,
+            writable=self._socket_writable,
         )
+
+    def _socket_writable(self) -> bool:
+        """The socket is connected, asyncio has not paused its protocol
+        (the write buffer is under its high-water mark), and the caller
+        is not this socket's own reader task. What the reader replies to
+        its own socket from inside a dispatch (an ack, a SyncStep2)
+        queues, as it always did: the writer task ships it with whatever
+        the same dispatch sends that socket next. Frames from anyone
+        else (a tick's delivery, another connection's dispatch) write
+        through. Measured, not derived: with the replies written through
+        too, the closed-loop cell lost 6 % of its throughput; with them
+        queued it lost none (PERF.md section 6, PR 35). An aiohttp
+        that keeps these fields elsewhere reads as held back: every
+        frame then takes the queue."""
+        try:
+            protocol = self.ws._writer.protocol
+            return (
+                protocol.transport is not None
+                and not protocol.writing_paused
+                and asyncio.current_task() is not self._reader_task
+            )
+        except AttributeError:
+            return False
 
 
 class Server:

@@ -21,6 +21,24 @@ bench harness) or by awaiting `send_async` per frame without returning
 to the scheduler in between. Under fan-out storms this turns one task
 wakeup per frame into one per burst.
 
+Write-through: a binding whose socket can take a frame without
+suspending declares it with `writable` (the socket's own pause state).
+`send()` then writes the frame itself, in the caller's turn of the loop,
+when nothing could be ahead of it: the queue is empty, the writer task
+is parked in `queue.get()`, no other write is in progress and
+`writable()` says the socket is not held back. In every other case the
+frame is queued as ever, and once anything is queued everything after it
+queues behind it until the writer has drained, so a connection's frames
+leave in the order `send` was called. A write is the coroutine of
+`send_async(data)` driven to its first suspension; one that does suspend
+(a flow-control drain, a large frame compressed off the loop) is handed
+to the writer task, which finishes it before any later frame. The choice
+is read from the connection's state alone. A declared binding's writer
+task ships its queued frames the same way, one `send_async` a frame
+(`send_batch_async` is for bindings that declare nothing). A transport
+without `writable` (embedders, tests) never writes from `send()`, and
+its writer awaits `send_async` / `send_batch_async` as it always did.
+
 Overflow policy: the queue is bounded by `max_queue` (frames). A
 connection that falls `max_queue` frames behind is not coming back —
 the broadcast fan-out engine (server/fanout.py) already switched it to
@@ -35,8 +53,10 @@ telemetry (`hocuspocus_wire_send_queue_overflow_total`).
 from __future__ import annotations
 
 import asyncio
+import types
 from typing import Awaitable, Callable, List, Optional
 
+from ..observability.tracing import get_tracer
 from ..observability.wire import get_wire_telemetry
 
 # frames a single connection may have queued before the overflow policy
@@ -45,6 +65,25 @@ DEFAULT_MAX_QUEUE = 4096
 
 # websocket close code for the overflow policy: "try again later"
 _OVERFLOW_CLOSE_CODE = 1013
+
+
+@types.coroutine
+def _rest_of(steps, waiting_on):
+    """The rest of an awaitable whose iterator `steps` was driven outside
+    any task until it yielded `waiting_on`: hands each thing it waits on
+    to the task that awaits this, and each answer (or the cancellation)
+    back to it, as `await` itself would have."""
+    while True:
+        try:
+            answer = yield waiting_on
+        except BaseException as error:
+            resume, value = steps.throw, error
+        else:
+            resume, value = steps.send, answer
+        try:
+            waiting_on = resume(value)
+        except StopIteration:
+            return
 
 
 class CallbackWebSocketTransport:
@@ -61,6 +100,10 @@ class CallbackWebSocketTransport:
       drained batch to the framework in ONE call.
     - max_queue: bound on queued data frames (0 disables); crossing it
       triggers the overflow policy (close 1013, counted).
+    - writable() -> bool: declared by a binding whose `send_async`
+      completes without suspending while the socket is not held back
+      (not paused, its buffer under the limit), and says whether that
+      holds now. Turns on write-through (module docstring).
     """
 
     def __init__(
@@ -70,12 +113,20 @@ class CallbackWebSocketTransport:
         is_closed_check: Optional[Callable[[], bool]] = None,
         send_batch_async: Optional[Callable[[List[bytes]], Awaitable[None]]] = None,
         max_queue: int = DEFAULT_MAX_QUEUE,
+        writable: Optional[Callable[[], bool]] = None,
     ) -> None:
         self._send_async = send_async
         self._close_async = close_async
         self._is_closed_check = is_closed_check
         self._send_batch_async = send_batch_async
         self.max_queue = max_queue
+        self._writable = writable
+        # True only while the writer task is parked in queue.get() and no
+        # write is in progress: the one state in which send() may write
+        self._idle = False
+        # a write send() began and could not finish (it suspended): the
+        # writer task awaits it before it ships anything else
+        self._unfinished: Optional[Awaitable[None]] = None
         # bounded by the qsize policy in send(), not Queue(maxsize=...):
         # the close marker must ALWAYS fit, even into a full queue
         self.queue: asyncio.Queue = asyncio.Queue()
@@ -99,6 +150,14 @@ class CallbackWebSocketTransport:
     def send(self, data: bytes) -> None:
         if self.is_closed:
             return
+        if (
+            self._idle
+            and self._writable is not None
+            and self.queue.empty()
+            and self._writable()
+        ):
+            self._write_through(data)
+            return
         if self.max_queue and self.queue.qsize() >= self.max_queue:
             # overflow policy (module docstring): close rather than
             # balloon memory; the close marker rides the same queue so
@@ -110,6 +169,38 @@ class CallbackWebSocketTransport:
         wire = get_wire_telemetry()
         if wire.enabled:
             wire.note_send_queued(self)
+
+    def _write_through(self, data: bytes) -> None:
+        self._idle = False  # a send() from inside this write queues behind it
+        try:
+            rest = self._begin("transport.write_inline", data)
+        except Exception:
+            self.abort()  # as the writer's except: the socket is gone
+            return
+        self._idle = True
+        get_wire_telemetry().frames_written_inline += 1
+        if rest is not None:
+            self._unfinished = rest
+            self.queue.put_nowait(("wake", None))
+        elif self._drain_listeners and self.queue.empty():
+            self._notify_drained()
+
+    def _begin(self, span: str, data: bytes) -> Optional[Awaitable[None]]:
+        """The synchronous head of one socket write of a declared
+        binding, under its span: `send_async(data)` driven to its first
+        suspension. None when the write completed there, else the rest
+        of it, to be awaited."""
+        with get_tracer().span(span):
+            awaitable = self._send_async(data)
+            try:
+                steps = awaitable.__await__()
+            except AttributeError:
+                steps = awaitable  # a generator-based coroutine is its own iterator
+            try:
+                waiting_on = steps.send(None)
+            except StopIteration:
+                return None
+        return _rest_of(steps, waiting_on)
 
     def close(self, code: int = 1000, reason: str = "") -> None:
         if not self._closed:
@@ -135,7 +226,14 @@ class CallbackWebSocketTransport:
     async def _writer(self) -> None:
         try:
             while True:
-                batch = [await self.queue.get()]
+                self._idle = True
+                try:
+                    batch = [await self.queue.get()]
+                finally:
+                    self._idle = False
+                if self._unfinished is not None:
+                    rest, self._unfinished = self._unfinished, None
+                    await rest
                 # drain the whole queue per wake: one task wakeup (and
                 # one framework call on the batch path) per burst
                 while True:
@@ -148,15 +246,23 @@ class CallbackWebSocketTransport:
                 for kind, payload in batch:
                     if kind == "data":
                         frames.append(payload)
-                    else:
+                    elif kind == "close":
                         close_args = payload
                         break  # frames queued after a close are moot
                 if frames:
-                    if self._send_batch_async is not None:
+                    if self._writable is not None:
+                        # a declared binding: the same synchronous head
+                        # as send()'s own write, under the writer's span
+                        for data in frames:
+                            rest = self._begin("transport.write_queued", data)
+                            if rest is not None:
+                                await rest
+                    elif self._send_batch_async is not None:
                         await self._send_batch_async(frames)
                     else:
                         for data in frames:
                             await self._send_async(data)
+                    get_wire_telemetry().frames_written_queued += len(frames)
                 if close_args is not None:
                     code, reason = close_args
                     await self._close_async(code, reason)
