@@ -43,8 +43,18 @@ writers = load_generator("writers")
 def most_units_added(mix: dict, all_docs: int, seconds: float) -> int:
     """The most units one document can grow by in a run: one unit an update,
     had every operation of the hottest document been an insert."""
-    rate = max(writers.doc_rates(mix, all_docs, 0))
-    return round(rate * (seconds + float(mix["warmup_seconds"])))
+    return writers.most_updates(mix, all_docs, seconds)
+
+
+def most_entries_added(mix: dict, all_docs: int, seconds: float) -> int:
+    """The most entries a run-length row (`--tpu-arena rle`) of one document
+    can grow by in a run: two an update. Every update is one operation of the
+    device on one unit, and an operation appends at most two entries
+    (`tpu/kernels_rle.py`): an insert inside a run its own entry and the
+    run's tail, a delete of a unit inside a run the unit and the tail behind
+    it. A letter typed where the last one ended costs one, which the bound
+    does not count on."""
+    return 2 * most_units_added(mix, all_docs, seconds)
 
 
 class Cursor:

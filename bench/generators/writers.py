@@ -92,10 +92,27 @@ def open_schedule(mix: dict, all_docs: int, mine: "list[int]", seconds: float, s
     return events
 
 
+def most_updates(mix: dict, all_docs: int, seconds: float) -> int:
+    """The most updates one document of an open loop is sent in a run,
+    warm-up included: every seed deals out the same set of rates."""
+    return round(max(doc_rates(mix, all_docs, 0)) * (seconds + float(mix["warmup_seconds"])))
+
+
 def most_units_added(mix: dict, all_docs: int, seconds: float) -> int:
     """The most units one document of an open loop can grow by in a run."""
-    rate = max(doc_rates(mix, all_docs, 0))
-    return round(rate * (seconds + float(mix["warmup_seconds"]))) * int(mix["run_units"][1])
+    return most_updates(mix, all_docs, seconds) * int(mix["run_units"][1])
+
+
+def most_entries_added(mix: dict, all_docs: int, seconds: float) -> int:
+    """The most entries a run-length row (`--tpu-arena rle`) of one document
+    of an open loop can grow by in a run. An operation of the device appends
+    at most two entries (`tpu/kernels_rle.py`): an insert its own run and the
+    tail of the run it splits, a delete the tails behind its two ends. An
+    update is one insert, and where the mix types over a selection one delete
+    before it for every range of the delete set: the units cut can each be
+    another author's, so `delete_units[1]` ranges at the most."""
+    operations = 1 + (int(mix["delete_units"][1]) if mix["replace_share"] else 0)
+    return most_updates(mix, all_docs, seconds) * 2 * operations
 
 
 class Generator:

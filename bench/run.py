@@ -83,10 +83,6 @@ def settings(args, manifest: Manifest) -> "tuple[dict, dict, dict]":
     return cell, config, mix
 
 
-def flag(config: dict, name: str) -> int:
-    return int(config["flags"][config["flags"].index(name) + 1])
-
-
 class Clients:
     """The client processes of a run (`lib/clients.py`), each with a share
     of the driven documents, and the lines exchanged with them."""
@@ -340,6 +336,9 @@ async def drive(args, config: dict, mix: dict, seconds: float, compiles) -> dict
                 "wal_delta": {k: v - seen["counters"]["wal"].get(k, 0) for k, v in after["wal"].items()},
                 "dispatch": (seen["counters"]["dispatch"], after["dispatch"]),
                 "doc_units": units,
+                "arena": served.planes[0].arena,
+                "row_capacity": served.planes[0].capacity,
+                "planes": len(served.planes),
             },
             "traced": traced,
             "trace_dir": trace_dir,
@@ -369,15 +368,12 @@ def main(argv=None) -> int:
         return EXIT_NO_PROGRAM
     sys.path.insert(1, ROOT)
     seconds = args.seconds if args.seconds is not None else float(manifest.data["run_seconds"])
-    room = flag(config, "--tpu-capacity") - int(config["doc_units"])
-    if mix["loop"] == "open":
-        from clients import load_generator
+    import room
 
-        docs = int(mix["docs_per_plane"]) * flag(config, "--tpu-shards")
-        grows = load_generator(mix["generator"]).most_units_added(mix, docs, seconds)
-        if grows > room:
-            print(f"bench: a document could grow by {grows} units and its row has room for {room}", file=sys.stderr)
-            return 2
+    refused = room.refusal(config, mix, seconds)
+    if refused:
+        print(f"bench: {refused}", file=sys.stderr)
+        return 2
     with contextlib.suppress(ImportError, ValueError, OSError):
         import resource
 

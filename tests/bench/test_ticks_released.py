@@ -37,7 +37,7 @@ def test_the_reader_on_a_hand_made_run(manifest, wal_delta, expected):
 
 
 def test_every_cell_reports_it_under_the_log_layer(manifest):
-    entry = manifest.data["per_layer"][-1]
+    (entry,) = [m for m in manifest.data["per_layer"] if m["name"] == METRIC]  # by name: later PRs append
     assert entry == {
         "name": METRIC, "unit": "ticks", "better": "higher", "source": "program_counter",
         "layer": "write-ahead log", "moves": "update_to_peer_p95_ms",
