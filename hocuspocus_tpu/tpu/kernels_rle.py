@@ -561,9 +561,11 @@ def _tail_probe_one_rle(state: RleState) -> tuple:
 
 @jax.jit
 def health_probe_rle(state: RleState, slots) -> jax.Array:
-    """(2D + 2B,) uint32 [lengths..., overflows..., tail clients...,
-    tail clocks...]: the flush cycle's health readback for the RLE
-    arena (same contract as kernels.health_probe)."""
+    """(3D + 2B,) uint32 [lengths..., overflows..., tail clients...,
+    tail clocks..., occupied entries...]: the flush cycle's health
+    readback for the RLE arena (same contract as kernels.health_probe,
+    and last every row's num_runs: what the plane differences row by
+    row, from cycle to cycle, into `rle_entries_appended`)."""
     from .kernels import gather_doc_rows
 
     sub = gather_doc_rows(state, slots)
@@ -574,6 +576,7 @@ def health_probe_rle(state: RleState, slots) -> jax.Array:
             state.overflow.astype(jnp.uint32),
             clients,
             clocks,
+            state.num_runs.astype(jnp.uint32),
         ]
     )
 

@@ -200,6 +200,15 @@ class MergePlane:
         if device is not None and mesh is not None:
             raise ValueError("pass device= or mesh=, not both")
         self.arena = arena
+        # the integrate span's attribute for a row's length, in what
+        # `capacity` counts on this arena
+        self._span_row = {"row_entries": capacity} if arena == "rle" else {"row_units": capacity}
+        # run-length arena: each row's occupied entries (num_runs) as
+        # the last health readback saw them. A cleared row's is set to
+        # 0 and a defragmented row's to what the compaction left, when
+        # that happens, so what a row has grown by at the next readback
+        # is what its ops took
+        self._rle_row_entries = np.zeros(num_docs, np.int64) if arena == "rle" else None
         self.device = device
         self.num_docs = num_docs
         self.capacity = capacity
@@ -432,8 +441,17 @@ class MergePlane:
             "slow_ops_concurrent": 0,
             # arena units the integrate batches swept: each batch's
             # bucket rows, padding included, x the row's capacity (the
-            # kernels sweep whole rows, not the document's length)
+            # kernels sweep whole rows, not the document's length).
+            # The unit arena's; a run-length row is counted in entries
             "integrate_row_units": 0,
+            "integrate_row_entries": 0,
+            # run-length arena: entries the device took for the ops it
+            # integrated, a hydration's snapshot ops among them (splits
+            # and new runs; every row's num_runs rides the cycle's
+            # health readback and is differenced row by row). Over the
+            # ops flushed it is what an op costs a row of `capacity`
+            # entries, where the host projects one (enqueue_update)
+            "rle_entries_appended": 0,
             # broadcast passes run, and the time from when each was
             # scheduled (the first capture since the last pass) to when
             # it ran: the coalescing window, the phase alignment and
@@ -639,10 +657,11 @@ class MergePlane:
     def _health_probe_fn(self):
         """The flush cycle's health readback kernel for this arena:
         (state, (W,) slots) -> (2D + 2W,) uint32 [lengths...,
-        overflows..., tail clients..., tail clocks...]. _sync_health
-        reads everything it validates through this ONE program; the W
-        slots are the rows whose rank tail the full-integrate path or
-        a compaction invalidated (W = 0: none)."""
+        overflows..., tail clients..., tail clocks...], on the
+        run-length arena D more, every row's occupied entries.
+        _sync_health reads everything it validates through this ONE
+        program; the W slots are the rows whose rank tail the
+        full-integrate path or a compaction invalidated (W = 0: none)."""
         if self.arena == "rle":
             from .kernels_rle import health_probe_rle
 
@@ -714,7 +733,9 @@ class MergePlane:
             doc.seqs[("root", root)] = slot
         # RLE cost counts device-bound QUEUE entries, not serve-log
         # records: host-only GC records never consume arena entries
-        # (mirrors the Python path routing GC to map_out)
+        # (mirrors the Python path routing GC to map_out). One entry
+        # an op, as the Python path projects it: enqueue_update says
+        # why one is right where the device may take two
         cost = queued_ops if self.arena == "rle" else queued_units
         projected = self.projected_len[slot] + cost
         if projected > self.capacity:
@@ -977,6 +998,8 @@ class MergePlane:
             )
         for slot in slots:
             self._set_tail_empty(slot)
+        if self._rle_row_entries is not None:
+            self._rle_row_entries[list(slots)] = 0
         self.flush_epoch += 1
 
     def _set_tail_empty(self, slot: int) -> None:
@@ -1053,10 +1076,13 @@ class MergePlane:
             # leaking. Unit arena: exact (capacity = units, cost =
             # run_len per insert). RLE arena: neutral 1/op estimate —
             # run-aligned churn deletes cost 0 device entries and
-            # mid-run splits cost up to 2, so the host bound only stops
-            # unbounded queueing on a doomed doc; the DEVICE overflow
-            # flag is the real authority (caught one flush later, and
-            # routed through the same recycle seam as capacity).
+            # mid-run splits cost up to 2 (measured: 0.78 an op where
+            # one author types and deletes inside a long text, the
+            # counter `rle_entries_appended`), so the host bound only
+            # stops unbounded queueing on a doomed doc; the DEVICE
+            # overflow flag is the real authority (caught one flush
+            # later, and routed through the same recycle seam as
+            # capacity).
             if self.arena == "rle":
                 projected = self.projected_len[slot] + len(ops)
             else:
@@ -1582,7 +1608,8 @@ class MergePlane:
                     slots=k,
                     busy=b,
                     integrated=slow[5],
-                    row_units=self.capacity,
+                    arena=self.arena,
+                    **self._span_row,
                 ):
                     self.state, _count = step(self.state, *step_args)
                 t_dispatch = time.perf_counter()
@@ -1611,7 +1638,10 @@ class MergePlane:
                     self.counters[self.cell_ops_key] += slow[5]
                 self.counters["flush_busy_rows"] += b_actual
                 self.counters["flush_bucket_rows"] += b
-                self.counters["integrate_row_units"] += b * self.capacity
+                if self.arena == "rle":
+                    self.counters["integrate_row_entries"] += b * self.capacity
+                else:
+                    self.counters["integrate_row_units"] += b * self.capacity
                 slow_total += slow[5]
                 device_batches += 1
                 if cycle_traces:
@@ -1731,11 +1761,18 @@ class MergePlane:
         padded = np.zeros(probe_width, np.int32)
         padded[: probe_slots.size] = probe_slots  # pad: re-read slot 0
         with self.compile_watch.track("health_probe", (probe_width,)):
-            combined = np.asarray(
-                self._health_probe_fn()(self.state, self._upload_slots(padded))
-            )
+            probed = self._health_probe_fn()(self.state, self._upload_slots(padded))
+            # everything above holds the interpreter lock (staging, the
+            # upload, an asynchronous dispatch); this is the cycle's one
+            # wait for the device, made with the lock released
+            with get_tracer().span("merge_plane.device_wait"):
+                combined = np.asarray(probed)
         if probe_slots.size:
             self._note_dispatch("tail_probe")
+        if self.arena == "rle":
+            entries = combined[-self.num_docs :].astype(np.int64)
+            self.counters["rle_entries_appended"] += int((entries - self._rle_row_entries).sum())
+            self._rle_row_entries = entries
         lengths = combined[: self.num_docs].astype(np.int64)
         self.last_lengths = lengths
         self.last_overflows = combined[self.num_docs : 2 * self.num_docs].astype(

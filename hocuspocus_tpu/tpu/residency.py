@@ -935,7 +935,12 @@ class ResidencyManager:
         # these rows are stale — the run-merge classifier must not
         # fast-path against them until the next flush readback re-arms
         plane.invalidate_tails(slots)
-        return np.asarray(sizes)[: len(slots)]
+        sizes = np.asarray(sizes)[: len(slots)]
+        if plane.arena == "rle":
+            # the entries a defragmented row is left with: the base the
+            # next readback counts `rle_entries_appended` from
+            plane._rle_row_entries[slots] = sizes
+        return sizes
 
     def _writable_health_caches(self) -> None:
         """The plane's last_lengths/last_overflows are read-only views

@@ -74,3 +74,19 @@ def heap_steward():
         vars(steward).update(tuning)
         gc.unfreeze()
         gc.set_threshold(*threshold)
+
+
+@pytest.fixture(autouse=True)
+def tracer_ring_as_found():
+    """The process tracer's ring at the size the test found it: a test that
+    shrinks it (`enable_tracing(max_spans=4)`) would leave the tests of other
+    files in the same worker a ring that drops the span they look for."""
+    from collections import deque
+
+    from hocuspocus_tpu.observability.tracing import get_tracer
+
+    tracer = get_tracer()
+    size = tracer._spans.maxlen
+    yield
+    if tracer._spans.maxlen != size:
+        tracer._spans = deque(tracer._spans, maxlen=size)
