@@ -407,7 +407,11 @@ class WalManager:
     durability gate; one commit per tick writes every dirty doc's batch
     off the loop, on the manager's own lane thread, and hands the gate
     back to the loop with one `call_soon_threadsafe`. Commits are
-    serialised and in append order. `--wal-fsync` modes:
+    serialised and in append order. Where that call lands, the lane is
+    turned round before the gate is released: what was buffered during
+    the commit starts on the lane thread while the loop delivers the
+    finished batch's ticks, and each gate still releases only after its
+    own commit has returned, in batch order. `--wal-fsync` modes:
 
     - `tick` (default): per-doc segments are WRITTEN (page cache) but
       the tick's durability comes from the shared **commit journal** —
@@ -495,6 +499,9 @@ class WalManager:
             # fan-out ticks delivered from inside a gate's resolution (a
             # tick that found its gate already done is not counted)
             "ticks_released": 0,
+            # commit-done steps that found records buffered and handed
+            # them to the lane before releasing their own gate
+            "commits_turned_early": 0,
         }
 
     @property
@@ -585,7 +592,8 @@ class WalManager:
     def _start_commit(self) -> None:
         """Loop thread: hand everything buffered to the lane thread as
         one batch. Appends that land while it is written join the NEXT
-        batch, which the commit-done step starts."""
+        batch, which the commit-done step starts before it releases this
+        batch's gate."""
         self._start_handle = None
         if self._inflight is not None or not self._pending:
             return
@@ -612,16 +620,20 @@ class WalManager:
             pass  # the loop closed under the commit: nobody is gated
 
     def _commit_done(self, gate: "DurabilityGate", since: Optional[float]) -> None:
-        """Loop thread, where the commit's completion lands: release the
-        batch's gate (its gated ticks are delivered here, in this turn
-        of the loop), then turn the lane round at once if records were
-        buffered meanwhile. Even a failed commit releases: a broadcast
-        gated on a dead disk must not hang forever."""
+        """Loop thread, where the commit's completion lands: turn the lane
+        round first if records were buffered meanwhile, so the next batch
+        is written while this one's gated ticks are delivered; then release
+        the batch's gate (its ticks run here, in this turn of the loop,
+        their frames written to every idle socket). The next gate cannot
+        release inside this one's resolution: its own commit-done step is
+        another threadsafe call, a later turn. Even a failed commit
+        releases: a broadcast gated on a dead disk must not hang forever."""
         self._inflight = None
+        if self._pending and not self._closed:
+            self.stats["commits_turned_early"] += 1
+            self._start_commit()
         gate.release()
         self._note_durable(since)
-        if self._pending and not self._closed:
-            self._start_commit()
 
     def _commit(self, pending: "dict[str, list]") -> None:
         """Lane thread: write every dirty doc's batch, then make the

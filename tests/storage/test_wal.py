@@ -8,6 +8,7 @@ append is still recoverable.
 
 import asyncio
 import os
+import time
 
 import pytest
 
@@ -448,8 +449,9 @@ async def test_gate_resolves_in_the_turn_the_commit_lands(tmp_path):
 
 async def test_commit_done_turns_the_lane_round_at_once(tmp_path):
     """Appends that land mid-commit join the next batch, and the
-    commit-done step starts that batch itself: commits stay serialised
-    and in append order, with no turn of the loop between them."""
+    commit-done step starts that batch itself, BEFORE it releases the
+    finished batch's gate: the next write runs on the lane thread while
+    the loop delivers. Commits stay serialised and in append order."""
     faults = HoldingFaults()
     wal = WalManager(str(tmp_path), fsync="off", faults=faults)
     first = wal.append("doc", b"one")
@@ -459,17 +461,112 @@ async def test_commit_done_turns_the_lane_round_at_once(tmp_path):
     assert second is third and second is not first
     assert wal._inflight is first and wal._start_handle is None
     started = []
-    first.on_release(lambda: started.append(wal._inflight))
+    first.on_release(lambda: started.append((wal._inflight, dict(wal._pending))))
     second.on_release(lambda: started.append("second released"))
     faults.release.set()
     await asyncio.wait_for(second, timeout=5)
-    # inside the first gate's resolution the lane was still this batch's;
-    # by the end of that step the next batch was in flight
-    assert started == [None, "second released"]
-    assert wal.stats["commit_batches"] == 2
+    # inside the first gate's resolution the lane already holds the
+    # second batch, and nothing is left buffered
+    assert started == [(second, {}), "second released"]
+    assert wal.stats["commit_batches"] == 2 and wal.stats["commits_turned_early"] == 1
     assert wal._inflight is None and wal._gate is None and not wal._pending
     records, _ = await wal.replay("doc")
     assert _payloads(records) == [b"one", b"two"]
+
+
+@pytest.mark.parametrize("mode", ["tick", "always", "off"])
+async def test_a_commit_that_returns_inside_the_last_resolution_waits_its_turn(tmp_path, mode):
+    """The next batch's commit can return while the loop still delivers the
+    last one: its gate is released by its own commit-done step, a later
+    turn, never inside the resolution of the gate before it; gates release
+    in batch order and each only after its own commit has returned."""
+    faults = HoldingFaults()
+    wal = WalManager(str(tmp_path), fsync=mode, faults=faults)
+    returned = set()  # the docs whose commit is back from the lane thread
+    real_commit = wal._commit
+
+    def commit(pending):
+        real_commit(pending)
+        returned.update(pending)
+
+    wal._commit = commit
+    first = wal.append("a", b"one")
+    await faults.held()
+    second = wal.append("b", b"two")
+    order = []
+
+    def deliver_first():
+        # hold the loop here until the second commit is back from the lane
+        # thread: its completion is queued behind this resolution
+        assert wal._inflight is second and "a" in returned
+        deadline = time.monotonic() + 5
+        while "b" not in returned and time.monotonic() < deadline:
+            time.sleep(0.001)
+        order.append(("first", "b" in returned, second.done()))
+
+    first.on_release(deliver_first)
+    second.on_release(lambda: order.append(("second", "b" in returned, second.done())))
+    faults.release.set()
+    await asyncio.wait_for(second, timeout=5)
+    assert order == [("first", True, False), ("second", True, True)]
+    assert wal.stats["commit_batches"] == 2 and wal.stats["commits_turned_early"] == 1
+    for name, payload in (("a", b"one"), ("b", b"two")):
+        records, _ = await wal.replay(name)
+        assert _payloads(records) == [payload]
+
+
+async def test_a_failed_commit_releases_its_gate_and_turns_the_lane(tmp_path):
+    faults = HoldingFaults()
+    faults.fail_disk_full(1)  # the held commit fails once it is let go
+    wal = WalManager(str(tmp_path), fsync="tick", faults=faults)
+    first = wal.append("doc", b"lost")
+    await faults.held()
+    second = wal.append("doc", b"kept")
+    seen = []
+    first.on_release(lambda: seen.append((first.done(), wal._inflight is second)))
+    faults.release.set()
+    await asyncio.wait_for(second, timeout=5)
+    assert seen == [(True, True)]
+    assert wal.stats["append_errors"] == 1 and wal.stats["commits_turned_early"] == 1
+    records, _ = await wal.replay("doc")
+    assert _payloads(records) == [b"kept"]
+
+
+async def test_a_closed_manager_starts_nothing(tmp_path):
+    faults = HoldingFaults()
+    wal = WalManager(str(tmp_path), fsync="off", faults=faults)
+    first = wal.append("doc", b"one")
+    await faults.held()
+    second = wal.append("doc", b"two")
+    lane = wal._lane
+    wal.close()
+    faults.release.set()
+    await asyncio.wait_for(first, timeout=5)  # the commit in flight still releases
+    await asyncio.sleep(0.01)
+    assert wal._inflight is None and wal._lane is None and not second.done()
+    assert list(wal._pending) == ["doc"] and wal.stats["commits_turned_early"] == 0
+    lane.shutdown(wait=True)
+
+
+async def test_commits_turned_early_counts_only_steps_that_found_records(tmp_path):
+    faults = HoldingFaults()
+    wal = WalManager(str(tmp_path), fsync="tick", faults=faults)
+    first = wal.append("doc", b"one")  # alone: nothing buffered behind it
+    await faults.held()
+    faults.release.set()
+    await asyncio.wait_for(first, timeout=5)
+    assert wal.stats["commit_batches"] == 1 and wal.stats["commits_turned_early"] == 0
+    faults.entered.clear()
+    faults.release.clear()
+    second = wal.append("doc", b"two")
+    await faults.held()
+    third = wal.append("doc", b"three")  # buffered behind the held commit
+    faults.release.set()
+    await asyncio.wait_for(third, timeout=5)
+    assert second.done()
+    assert wal.stats["commit_batches"] == 3 and wal.stats["commits_turned_early"] == 1
+    await asyncio.wait_for(wal.append("doc", b"four"), timeout=5)
+    assert wal.stats["commit_batches"] == 4 and wal.stats["commits_turned_early"] == 1
 
 
 async def test_flush_waits_for_the_commit_in_flight_and_what_is_buffered(tmp_path):
