@@ -31,10 +31,12 @@ The synchronous-section rule. A `span` wraps a synchronous section: no
 `await` that can suspend inside it. Spans of one thread then never
 interleave, and the sum of a name's spans is that thread's time — which
 is what the benchmark's `program_span` readers rely on. A section that
-awaits (`message.apply`, `hooks.<name>`, `serving.catchup_drain`) reads
-the clock before, calls `add_span` after, and so stays out of the
-profiler's trace, where it would overlap other tasks' spans on the
-loop's line.
+awaits either gets one span per synchronous piece (`fanout.tick`;
+`Tracer.in_pieces` drives an awaitable so, as for `connection.receive`
+and `plane.flush_turn`), or reads the clock before and calls `add_span`
+after (`message.apply`, `hooks.<name>`, `serving.catchup_drain`), and so
+stays out of the profiler's trace, where it would overlap other tasks'
+spans on the loop's line.
 
 Design constraints:
 - Near-zero cost when not live: one attribute read, one static call and
@@ -55,6 +57,7 @@ import os
 import sys
 import threading
 import time
+import types
 from collections import deque
 from typing import Any, Callable, Optional
 
@@ -151,7 +154,11 @@ _annotation: Any = None
 
 def _resolve_annotation() -> Any:
     global _annotation
-    if "jax" not in sys.modules:
+    jax = sys.modules.get("jax")
+    # a jax still being imported (by a plane's init thread, say) is not
+    # there yet: importing its profiler from here would race that import
+    # and can leave the other thread with a half-initialised module
+    if jax is None or getattr(getattr(jax, "__spec__", None), "_initializing", False):
         return None
     try:
         from jax.profiler import TraceAnnotation
@@ -272,6 +279,42 @@ class Tracer:
         if self.enabled:
             return _RingSpan(self, name, attributes, annotation if capturing else None)
         return annotation(name) if capturing else _NOOP_SPAN
+
+    def live(self) -> bool:
+        """Whether a span site would record now (the live rule): the one
+        predicate a site that chooses between two paths pays."""
+        if self.enabled:
+            return True
+        annotation = _annotation
+        if annotation is None:
+            annotation = _resolve_annotation()
+        return bool(annotation) and annotation.is_enabled()
+
+    @types.coroutine
+    def in_pieces(self, name: str, awaitable: Any) -> Any:
+        """Await `awaitable` with each synchronous piece of it under a span
+        `name`: the piece up to its first suspension, and each piece from a
+        resumption to the next suspension. What it waits on is handed to
+        the awaiting task, and each answer (or the cancellation) back, as
+        `await` itself would. The synchronous-section rule for a section
+        that awaits: the pieces never interleave with another task's."""
+        steps = awaitable.__await__()
+        answer: Any = None
+        error: Optional[BaseException] = None
+        while True:
+            with self.span(name):
+                try:
+                    waiting_on = steps.send(answer) if error is None else steps.throw(error)
+                except StopIteration as done:
+                    return done.value
+            answer, error = None, None
+            try:
+                answer = yield waiting_on
+            except GeneratorExit:
+                steps.close()
+                raise
+            except BaseException as raised:
+                error = raised
 
     def event(self, name: str, **attributes: Any) -> None:
         """Record an instantaneous event as a zero-duration span (state

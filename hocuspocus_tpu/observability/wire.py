@@ -140,11 +140,13 @@ class WireTelemetry:
             "hocuspocus_wire_send_queue_overflow_total",
             "Transports closed because their send queue hit the bound",
         )
-        # socket writes by who made them (server/transports.py): plain
-        # integers, always on, so the share written through is countable
-        # on any server without enabling the rest
+        # socket writes by who made them (server/transports.py), and the
+        # frames the aiohttp host's readers took in (server/server.py):
+        # plain integers, always on, so the share written through is
+        # countable on any server without enabling the rest
         self.frames_written_inline = 0
         self.frames_written_queued = 0
+        self.frames_read = 0
         self.frames_inline = Counter(
             "hocuspocus_wire_frames_written_inline_total",
             "Frames send() wrote to an idle socket itself, in the caller's turn",
@@ -154,6 +156,11 @@ class WireTelemetry:
             "hocuspocus_wire_frames_written_queued_total",
             "Frames a connection's writer task shipped from its send queue",
             fn=lambda: self.frames_written_queued,
+        )
+        self.frames_read_total = Counter(
+            "hocuspocus_wire_frames_read_total",
+            "Binary frames the aiohttp host's connection readers took in",
+            fn=lambda: self.frames_read,
         )
         self.pubsub_publishes = Counter(
             "hocuspocus_wire_pubsub_publishes_total",
@@ -458,6 +465,7 @@ class WireTelemetry:
             self.send_queue_overflows,
             self.frames_inline,
             self.frames_queued,
+            self.frames_read_total,
             self.pubsub_publishes,
             self.pubsub_deliveries,
             self.pubsub_dropped,
@@ -492,6 +500,7 @@ class WireTelemetry:
             "queue_overflows": sum(self.send_queue_overflows._values.values()),
             "frames_written_inline": self.frames_written_inline,
             "frames_written_queued": self.frames_written_queued,
+            "frames_read": self.frames_read,
             "pubsub_publishes": sum(self.pubsub_publishes._values.values()),
             "pubsub_deliveries": sum(self.pubsub_deliveries._values.values()),
             "pubsub_dropped": sum(self.pubsub_dropped._values.values()),

@@ -16,6 +16,8 @@ import aiohttp
 from aiohttp import WSMsgType, web
 
 from . import logger
+from ..observability.tracing import get_tracer
+from ..observability.wire import get_wire_telemetry
 from ..protocol.close_events import MESSAGE_TOO_BIG, SERVICE_RESTART
 from .hocuspocus import Hocuspocus, RequestInfo
 from .overload import (
@@ -25,6 +27,24 @@ from .overload import (
 )
 from .transports import CallbackWebSocketTransport
 from .types import Configuration, Payload
+
+
+def _span_reads(transport: Any) -> None:
+    """Put a connection's read-ready callback under `transport.read`: the
+    socket's `recv`, aiohttp's frame parse and the hand-off to the reader
+    task's queue. asyncio's selector transport calls `_read_ready_cb` from
+    the handle the loop registered for the socket; a transport without it
+    (another loop implementation) reads with no span."""
+    read = getattr(transport, "_read_ready_cb", None)
+    if read is None:
+        return
+    tracer = get_tracer()
+
+    def read_ready() -> None:
+        with tracer.span("transport.read"):
+            read()
+
+    transport._read_ready_cb = read_ready
 
 
 class AiohttpWebSocketTransport(CallbackWebSocketTransport):
@@ -279,15 +299,26 @@ class Server:
             max_msg_size=self.configuration.stateless_payload_limit,
         )
         await ws.prepare(request)
+        _span_reads(request.transport)
         transport = AiohttpWebSocketTransport(ws)
         self._transports.add(transport)
         client_connection = self._create_session(transport, request_info, context)
         close_code = 1000
         close_reason = ""
+        tracer = get_tracer()
+        wire = get_wire_telemetry()
         try:
             async for msg in ws:
                 if msg.type == WSMsgType.BINARY:
-                    await client_connection.handle_message(msg.data)
+                    wire.frames_read += 1
+                    if tracer.live():
+                        # the reader's own turns, one span a synchronous
+                        # piece: dispatch and apply open inside them
+                        await tracer.in_pieces(
+                            "connection.receive", client_connection.handle_message(msg.data)
+                        )
+                    else:
+                        await client_connection.handle_message(msg.data)
                 elif msg.type == WSMsgType.ERROR:
                     exc = ws.exception()
                     if (

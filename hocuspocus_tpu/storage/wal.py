@@ -627,13 +627,18 @@ class WalManager:
         their frames written to every idle socket). The next gate cannot
         release inside this one's resolution: its own commit-done step is
         another threadsafe call, a later turn. Even a failed commit
-        releases: a broadcast gated on a dead disk must not hang forever."""
-        self._inflight = None
-        if self._pending and not self._closed:
-            self.stats["commits_turned_early"] += 1
-            self._start_commit()
+        releases: a broadcast gated on a dead disk must not hang forever.
+        Two spans `wal.commit_done`, the release between them: the ticks
+        keep their own `fanout.tick`."""
+        tracer = get_tracer()
+        with tracer.span("wal.commit_done"):
+            self._inflight = None
+            if self._pending and not self._closed:
+                self.stats["commits_turned_early"] += 1
+                self._start_commit()
         gate.release()
-        self._note_durable(since)
+        with tracer.span("wal.commit_done"):
+            self._note_durable(since)
 
     def _commit(self, pending: "dict[str, list]") -> None:
         """Lane thread: write every dirty doc's batch, then make the

@@ -3549,8 +3549,16 @@ class TpuMergeExtension(Extension):
             return
 
         def run() -> None:
-            self._flush_handle = None
-            self._spawn_tracked(self._flush_now())
+            tracer = get_tracer()
+            with tracer.span("plane.flush_turn"):
+                self._flush_handle = None
+                cycle = self._flush_now()
+                if tracer.live():
+                    # the cycle's own turns on the loop, one span each:
+                    # the head (lane, governor, the executor submit) and
+                    # the tail once the executor returns (post_flush)
+                    cycle = tracer.in_pieces("plane.flush_turn", cycle)
+                self._spawn_tracked(cycle)
 
         if delay_override is not None:
             delay = delay_override

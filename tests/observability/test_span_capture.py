@@ -40,6 +40,10 @@ LOOP_SPANS = [
     "plane.broadcast",
     "fanout.tick",
     "plane.post_flush",
+    "transport.read",
+    "connection.receive",
+    "plane.flush_turn",
+    "wal.commit_done",
 ]
 EXECUTOR_SPANS = [
     "merge_plane.flush",
@@ -160,6 +164,12 @@ def test_a_parent_span_holds_its_children(captured):
     assert seconds["merge_plane.flush"] >= sum(
         seconds["merge_plane." + stage] for stage in ("drain", "upload", "append", "integrate", "readback")
     )
+
+
+def test_a_loop_stage_holds_the_spans_that_open_inside_it(captured):
+    seconds = captured["span_seconds"]
+    assert seconds["connection.receive"] >= seconds["connection.dispatch"] + seconds["message.update_apply"]
+    assert seconds["plane.flush_turn"] >= seconds["plane.post_flush"]
 
 
 @pytest.mark.parametrize("counter", MONOTONE)
