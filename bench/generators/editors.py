@@ -124,7 +124,8 @@ class Generator(writers.Generator):
             if client == 0:
                 return  # the writer's own document: nobody else writes
             if records is None:
-                records, writer_id, body = self.records[doc][0], self.client_ids[doc][0], document.get_text("body")
+                records, writer_id = self.records[doc][0], self.client_ids[doc][0]
+                body = self.document_kind.edited(document)
             if pointer == len(records):
                 return
             at = writers.now()
@@ -147,7 +148,7 @@ class Generator(writers.Generator):
         """One operation of the mix from one writer, at its cursor."""
         rng = self.rngs[doc]
         document = self.providers[doc][writer].document
-        body = document.get_text("body")
+        body = self.document_kind.edited(document)
         length = len(body)
         cursor = self.cursors.get(doc)
         if cursor is None:

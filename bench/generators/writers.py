@@ -21,17 +21,22 @@ A traffic mix (`bench/traffic/<name>.json`) that names this generator sets:
                     position (typing over a selection), delete_units long
   rate_updates_per_s        open loop: updates per second over all documents
   doc_rate_pareto_alpha     open loop: per-document rates are the quantiles
-                    of this Pareto law, dealt to the documents by the seed
+                    of this Pareto law, dealt to the documents by the seed,
+                    each plane alike
   doc_rate_cap_over_mean    ... cut off at this multiple of the mean rate
 
 The configuration adds `clients_per_doc`, `writers_per_doc` and `doc_units`
 (the text a document starts with: the server recovers it from its log, the
-clients sync it and check its length).
+clients sync it and check its length). The spec names the kind of document
+(`lib/kinds.py`): a client edits the shared type the kind gives it and
+reports what it holds as the kind reads it.
 
 Every document draws from a generator of its own, seeded by the run's seed
 and the document's number, so how documents are dealt to client processes
 changes nothing. Every seed gets the same set of document rates, in another
-order, and the same number of updates in an open loop.
+order, and in an open loop the same due times, dealt to the documents in
+another order: what a run's seed changes is which document does what, not
+how much work arrives when.
 """
 
 from __future__ import annotations
@@ -41,7 +46,10 @@ import contextlib
 import random
 import time
 
+import kinds  # bench/lib, on the path of every process that loads a generator
+
 ALPHABET = "etaoinshrdlucmfwypvbgkqjxz"
+DUE_TIMES_SEED = 0x5EED  # an open loop's due times: the same for every run's seed
 now = time.monotonic  # CLOCK_MONOTONIC: one clock for every process of the host
 
 
@@ -62,15 +70,36 @@ class Update:
 
 def doc_rates(mix: dict, docs: int, seed: int) -> "list[float]":
     """Updates per second of each document: fixed quantiles of a Pareto
-    law, capped, scaled to the mix's total, dealt out by the seed."""
+    law, capped, scaled to the mix's total, dealt out by the seed. Where the
+    documents come grouped by plane (`docs_per_plane` of them in a row, as
+    `run.py` lists them), the rates are dealt a round at a time, as many as
+    there are planes, the largest left to the plane that holds least so far:
+    every seed gives the planes the same loads, only to other planes."""
     alpha = mix["doc_rate_pareto_alpha"]
     weights = [(1.0 - (i + 0.5) / docs) ** (-1.0 / alpha) for i in range(docs)]
     for _ in range(8):  # the cap moves the mean, so settle it
         cap = mix["doc_rate_cap_over_mean"] * sum(weights) / docs
         weights = [min(w, cap) for w in weights]
     scale = mix["rate_updates_per_s"] / sum(weights)
-    rates = [w * scale for w in weights]
-    random.Random(seed).shuffle(rates)
+    quantiles = [w * scale for w in weights]
+    per_plane = int(mix.get("docs_per_plane") or docs)
+    if docs % per_plane:
+        per_plane = docs
+    planes = docs // per_plane
+    rng = random.Random(seed)
+    tie_break = rng.sample(range(planes), planes)
+    loads = [0.0] * planes
+    dealt: "list[list[float]]" = [[] for _ in range(planes)]
+    ranked = sorted(quantiles, reverse=True)
+    for at in range(0, docs, planes):  # the largest rates left go to the lightest planes
+        lightest = sorted(range(planes), key=lambda plane: (loads[plane], tie_break[plane]))
+        for plane, rate in zip(lightest, ranked[at : at + planes]):
+            dealt[plane].append(rate)
+            loads[plane] += rate
+    rates = []
+    for plane_rates in dealt:
+        rng.shuffle(plane_rates)
+        rates.extend(plane_rates)
     return rates
 
 
@@ -80,16 +109,17 @@ def doc_rng(seed: int, doc: int) -> random.Random:
 
 def open_schedule(mix: dict, all_docs: int, mine: "list[int]", seconds: float, seed: int) -> "list[tuple]":
     """(due, document) of every update of the documents `mine`, from
-    `-warmup_seconds` to `seconds`, sorted by time."""
+    `-warmup_seconds` to `seconds`, sorted by time. The due times are drawn
+    once, from no seed, so that every seed offers the same arrivals; the seed
+    deals them out to the documents, to each as many as its rate asks."""
     start = -float(mix["warmup_seconds"])
-    rates = doc_rates(mix, all_docs, seed)
-    events = []
-    for doc in mine:
-        rng = random.Random(seed * 1_000_003 + doc + 500_009)
-        for _ in range(round(rates[doc] * (seconds - start))):
-            events.append((start + rng.random() * (seconds - start), doc))
-    events.sort()
-    return events
+    span = seconds - start
+    owners = [doc for doc, rate in enumerate(doc_rates(mix, all_docs, seed)) for _ in range(round(rate * span))]
+    times = random.Random(DUE_TIMES_SEED)
+    dues = sorted(start + times.random() * span for _ in owners)
+    random.Random(seed * 1_000_003 + 500_009).shuffle(owners)
+    mine = set(mine)
+    return [(due, doc) for due, doc in zip(dues, owners) if doc in mine]
 
 
 def most_updates(mix: dict, all_docs: int, seconds: float) -> int:
@@ -120,6 +150,7 @@ class Generator:
         self.spec = spec
         self.mix = mix = spec["mix"]
         self.url = spec["url"]
+        self.document_kind = kinds.load(spec["document"])
         self.seed = int(spec["seed"])
         self.seconds = float(spec["seconds"])
         self.docs = [(int(d["index"]), d["name"]) for d in spec["docs"]]
@@ -181,7 +212,7 @@ class Generator:
             name
             for doc, name in self.docs
             for provider in self.providers[doc]
-            if len(provider.document.get_text("body")) != int(self.mix["doc_units"])
+            if len(self.document_kind.edited(provider.document)) != int(self.mix["doc_units"])
         ]
         if short:
             raise RuntimeError(f"clients synced without the document's first text: {short[:5]}")
@@ -227,7 +258,7 @@ class Generator:
         position, after a deleted range where the mix says so."""
         rng, mix = self.rngs[doc], self.mix
         document = self.providers[doc][writer].document
-        body = document.get_text("body")
+        body = self.document_kind.edited(document)
         length = len(body)
         kind = rng.choices(self._kinds, self._kind_weights)[0]
         at = length if kind == "end" else length // 2 if kind == "hot" else rng.randrange(length + 1)
@@ -324,7 +355,7 @@ class Generator:
             "log": self.log,
             "clients": {
                 doc: [
-                    (p.document.get_text("body").to_string(), dict(p.document.store.get_state_vector()))
+                    (self.document_kind.view(p.document), dict(p.document.store.get_state_vector()))
                     for p in row
                 ]
                 for doc, row in self.providers.items()

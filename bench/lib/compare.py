@@ -1,10 +1,11 @@
 """What decides `correct`: every driven document, as each client, the
-server, the device arena and the write-ahead log hold it once the window
-has closed and the queues have drained, against the plain reference merged
-from the document's first text (made from the seed) and the updates the
-clients put on the wire; each of those updates against what its client
-meant to type (drawn from the seed); and a seeded sample of the resident
-documents against their first texts.
+server, the device and the write-ahead log hold it once the window has
+closed and the queues have drained, against the plain reference of its kind
+(`lib/kinds.py`) merged from the document's first state (made from the seed)
+and the updates the clients put on the wire; the kind's own checks (for
+`text`: each update against what its client meant to type, drawn from the
+seed); and a seeded sample of the resident documents against their first
+states.
 
 Each number compared is a count with the limit 0 (an exact comparison).
 Health facts are not here: `serve.Served.health`.
@@ -23,21 +24,30 @@ of the configuration broken, and have to come out as not correct:
 
 from __future__ import annotations
 
-from reference import ReferenceText, decode_update, signed32_before, unsigned_before
-from seeded import text_update
+import kinds
+from reference import signed32_before, unsigned_before
 
-LIMITS = {
-    "updates_undelivered": 0,
-    "updates_not_as_meant": 0,
-    "texts_not_as_typed": 0,
-    "client_texts_differing": 0,
-    "server_texts_differing": 0,
-    "device_texts_differing": 0,
-    "state_vectors_differing": 0,
-    "wal_texts_differing": 0,
-    "resident_texts_differing": 0,
-}
 CONTROLS = ("drop-last-update", "signed-client-order", "wal-drop-last-record")
+
+
+def default_kind():
+    """The kind of a configuration that names none (`lib/kinds.py`)."""
+    return kinds.load(kinds.DEFAULT)
+
+
+def differing(kind, place: str) -> str:
+    """The number of views read at `place` that differ from the reference's."""
+    return f"{place}_{kind.VIEWS}_differing"
+
+
+def limits(kind=None) -> dict:
+    """{number compared: its limit}, in the order they are printed."""
+    kind = kind or default_kind()
+    client, server, device, wal, resident = (
+        differing(kind, place) for place in ("client", "server", "device", "wal", "resident")
+    )
+    names = ["updates_undelivered", *kind.CHECKS, client, server, device, "state_vectors_differing", wal, resident]
+    return dict.fromkeys(names, 0)
 
 
 def by_doc(log: "list[tuple]", docs: int) -> "list[list[tuple]]":
@@ -48,106 +58,76 @@ def by_doc(log: "list[tuple]", docs: int) -> "list[list[tuple]]":
     return per_doc
 
 
-def merged(first: "list[tuple[int, str]]", log: "list[tuple]", control: "str | None" = None) -> "list[ReferenceText]":
-    """The reference text of each document: its first text, typed by
-    `first[doc][0]`, then the updates in the order the clients made them."""
+def merged(first: list, log: "list[tuple]", control: "str | None" = None, *, kind=None) -> list:
+    """The reference of each document of `kind`: its first state, as the
+    kind's `first_states` gave it, then the updates in the order the clients
+    made them."""
     if control is not None and control not in CONTROLS:
         raise ValueError(f"no control {control!r} (has {CONTROLS})")
+    kind = kind or default_kind()
     before = signed32_before if control == "signed-client-order" else unsigned_before
-    texts = []
-    for (client, text), entries in zip(first, by_doc(log, len(first))):
-        updates = [text_update(client, text)] + [entry[1] for entry in entries]
+    references = []
+    for state, entries in zip(first, by_doc(log, len(first))):
+        updates = [update for _client, update in kind.first_writes(state)] + [entry[1] for entry in entries]
         if control == "drop-last-update":
             updates = updates[:-1]
-        reference = ReferenceText(before)
+        reference = kind.Reference(before)
         reference.apply_updates(updates)
-        texts.append(reference)
-    return texts
+        references.append(reference)
+    return references
 
 
-def not_as_meant(log: "list[tuple]") -> int:
-    """Updates on the wire that do not say what their client meant: one run
-    of text from that client, after that many units deleted."""
-    wrong = 0
-    for _doc, update, client, run, cut in log:
-        try:
-            inserts, deletes = decode_update(update)
-        except (ValueError, IndexError, TypeError):
-            wrong += 1
-            continue
-        said = "".join(text for author, _clock, _left, _right, text in inserts if author == client)
-        wrong += (
-            said != run
-            or any(author != client for author, *_rest in inserts)
-            or sum(length for _client, _clock, length in deletes) != cut
-        )
-    return wrong
-
-
-def not_as_typed(first: "list[tuple[int, str]]", log: "list[tuple]", reference: "list[ReferenceText]") -> int:
-    """Documents with one writer that only appends have one possible text,
-    known from the seed alone: the first text and then every run in order."""
-    wrong = 0
-    for (_client, text), entries, want in zip(first, by_doc(log, len(first)), reference):
-        if len({entry[2] for entry in entries}) <= 1 and not any(entry[4] for entry in entries):
-            wrong += want.text() != text + "".join(entry[3] for entry in entries)
-    return wrong
-
-
-def replayed(updates: "list[bytes]") -> "tuple[str, dict] | None":
+def replayed(kind, updates: "list[bytes]") -> "tuple[object, dict] | None":
     try:
-        reference = ReferenceText()
+        reference = kind.Reference(unsigned_before)
         reference.apply_updates(updates)
     except (ValueError, IndexError):
         return None
-    return reference.text(), reference.state_vector()
+    return kind.reference_view(reference), reference.state_vector()
 
 
-def compare(reference: "list[ReferenceText]", observed: dict, first=None, log=None, only_appends: bool = False) -> dict:
+def compare(references: list, observed: dict, first=None, log=None, only_appends: bool = False, *, kind=None) -> dict:
     """{number compared: [value, limit]}. `observed` has "undelivered" (a
     count), "docs" (per driven document, in order: "clients", a list of
-    (text, state vector) per client; "server" and "device", a text or None;
-    "wal", the log's payloads) and "resident" (a list of (text wanted,
+    (view, state vector) per client; "server" and "device", a view or None;
+    "wal", the log's payloads) and "resident" (a list of (view wanted,
     server's, device's)). `first` and `log` as `merged` takes them."""
-    numbers = dict.fromkeys(LIMITS, 0)
+    kind = kind or default_kind()
+    limit = limits(kind)
+    numbers = dict.fromkeys(limit, 0)
     numbers["updates_undelivered"] = observed["undelivered"]
     if log is not None:
-        numbers["updates_not_as_meant"] = not_as_meant(log)
-        if only_appends:
-            numbers["texts_not_as_typed"] = not_as_typed(first, log, reference)
-    for want, got in zip(reference, observed["docs"]):
-        text, vector = want.text(), want.state_vector()
-        for got_text, got_vector in got["clients"]:
-            numbers["client_texts_differing"] += got_text != text
+        numbers.update(kind.checks(first, log, references, only_appends))
+    for want, got in zip(references, observed["docs"]):
+        view, vector = kind.reference_view(want), want.state_vector()
+        for got_view, got_vector in got["clients"]:
+            numbers[differing(kind, "client")] += got_view != view
             numbers["state_vectors_differing"] += got_vector != vector
-        numbers["server_texts_differing"] += got["server"] != text
-        numbers["device_texts_differing"] += got["device"] != text
-        numbers["wal_texts_differing"] += replayed(got["wal"]) != (text, vector)
+        numbers[differing(kind, "server")] += got["server"] != view
+        numbers[differing(kind, "device")] += got["device"] != view
+        numbers[differing(kind, "wal")] += replayed(kind, got["wal"]) != (view, vector)
     for wanted, server, device in observed["resident"]:
-        numbers["resident_texts_differing"] += (server != wanted) + (device != wanted)
-    return {name: [int(value), LIMITS[name]] for name, value in numbers.items()}
+        numbers[differing(kind, "resident")] += (server != wanted) + (device != wanted)
+    return {name: [int(value), limit[name]] for name, value in numbers.items()}
 
 
 def correct(compared: dict) -> bool:
     return all(value <= limit for value, limit in compared.values())
 
 
-def as_observed(reference: "list[ReferenceText]", logs: "list[list[bytes]]", control: "str | None" = None) -> dict:
-    """A set of reference texts put in the program's place, beside the log
-    as the program left it (`logs`, per document)."""
+def as_observed(references: list, logs: "list[list[bytes]]", control: "str | None" = None, *, kind=None) -> dict:
+    """A set of references put in the program's place, beside the log as
+    the program left it (`logs`, per document)."""
+    kind = kind or default_kind()
     if control == "wal-drop-last-record":
         # the journal may hold a record a second time: it is lost there too
         logs = [[p for p in payloads if p != payloads[-1]] for payloads in logs]
+    views = [kind.reference_view(r) for r in references]
     return {
         "undelivered": 0,
         "docs": [
-            {
-                "clients": [(r.text(), r.state_vector())],
-                "server": r.text(),
-                "device": r.text(),
-                "wal": payloads,
-            }
-            for r, payloads in zip(reference, logs)
+            {"clients": [(view, r.state_vector())], "server": view, "device": view, "wal": payloads}
+            for view, r, payloads in zip(views, references, logs)
         ],
         "resident": [],
     }

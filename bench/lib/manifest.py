@@ -1,6 +1,7 @@
 """BENCHMARK.json and the files it names: everything the harness knows about
-a cell, a configuration, a traffic mix or a per-layer metric it finds here,
-by name. A later PR adds files and entries; it edits nothing."""
+a cell, a configuration, its kind of document, a traffic mix or a per-layer
+metric it finds here, by name. A later PR adds files and entries; it edits
+nothing."""
 
 from __future__ import annotations
 
@@ -8,6 +9,8 @@ import importlib.util
 import json
 import os
 import re
+
+import kinds
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -49,6 +52,10 @@ class Manifest:
         bad += [m["name"] for m in metrics if m["better"] not in ("lower", "higher")]
         if bad:
             raise ManifestError(f"names, units or sources outside the contract: {bad}")
+        for name in self.configs:
+            kind = kinds.name_of(self.config(name))
+            if not NAME.match(kind) or not os.path.isfile(kinds.path_of(kind, self.bench_dir)):
+                raise ManifestError(f"configuration {name!r} names the document kind {kind!r}, which has no file")
 
     def cell(self, name: str) -> dict:
         if name not in self.cells:
@@ -61,16 +68,34 @@ class Manifest:
             raise ManifestError(f"no configuration {name!r} in BENCHMARK.json")
         return _read_json(os.path.join(self.root, self.configs[name]["file"]))
 
+    def kind(self, config: dict):
+        """The module of the document kind `config` names (`lib/kinds.py`)."""
+        return kinds.load(kinds.name_of(config), self.bench_dir)
+
     def traffic(self, name: str) -> dict:
         return _read_json(os.path.join(self.bench_dir, "traffic", name + ".json"))
 
     def metrics_of(self, cell: str, group: str) -> "list[dict]":
-        """The metrics of `end_to_end` or `per_layer` that this cell reports."""
+        """The metrics of `end_to_end` or `per_layer` that this cell reports:
+        one with a `workloads` list in the cells it lists; an end-to-end
+        metric without one in every cell, and a per-layer metric without one
+        in every cell that reports the end-to-end metric it moves."""
+        if group == "end_to_end":
+            return [m for m in self.data[group] if "workloads" not in m or cell in m["workloads"]]
+        reported = {m["name"] for m in self.metrics_of(cell, "end_to_end")}
         return [
             metric
             for metric in self.data[group]
-            if "workloads" not in metric or cell in metric["workloads"]
+            if cell in metric.get("workloads", ()) or ("workloads" not in metric and metric["moves"] in reported)
         ]
+
+    def reported_as(self, cell: str, quantity: str) -> dict:
+        """The one entry under which this cell reports a per-layer quantity:
+        the entry of that name, or its twin split by what it moves
+        (`server_loop_busy_share.typing`, where the cell reports another
+        end-to-end metric than the entry of that name moves)."""
+        (entry,) = [m for m in self.metrics_of(cell, "per_layer") if m["name"].split(".")[0] == quantity]
+        return entry
 
     def reader(self, metric: str):
         """The `read(run)` of bench/metrics/<metric>.py. A metric split by

@@ -5,18 +5,19 @@ The flags are read as the program reads them (`hocuspocus_tpu.cli`'s own
 parser: a flag left out takes the CLI's default, one given twice is read as
 `argparse` reads it), and a row's room is counted in the arena's own unit:
 
-  --tpu-arena unit   `--tpu-capacity` counts UTF-16 units. A document starts
-                     with `doc_units` of them and grows by what its
+  --tpu-arena unit   `--tpu-capacity` counts UTF-16 units. A document
+                     starts with what its kind's `first_in_row(config,
+                     "unit")` says (a text: `doc_units`) and grows by what its
                      generator's `most_units_added(mix, all_docs, seconds)` says.
   --tpu-arena rle    `--tpu-capacity` counts entries, one a run. A document
-                     starts with the one entry its first text takes
-                     (`lib/seeded.text_update` writes it as one string item)
-                     and grows by the generator's `most_entries_added(mix,
-                     all_docs, seconds)`. An operation flags a row's overflow
-                     unless two entries are free before it
-                     (`tpu/kernels_rle.py`: `num_runs + 2 <= r`), whatever it
-                     goes on to use: a row that holds the first text and that
-                     bound never comes to that.
+                     starts with its kind's `first_in_row(config, "rle")` (a
+                     text: the one entry of its one string item) and grows by
+                     the generator's `most_entries_added(mix, all_docs,
+                     seconds)`. An operation flags a row's overflow unless two
+                     entries are free before it (`tpu/kernels_rle.py`:
+                     `num_runs + 2 <= r`), whatever it goes on to use: a row
+                     that holds the first state and that bound never comes to
+                     that.
 
 This is a pre-flight and no more. The device's overflow flag stays the
 authority: a document that outgrows its row is retired from the plane, and
@@ -28,10 +29,10 @@ section 7).
 
 from __future__ import annotations
 
-from clients import load_generator  # bench/lib
+import kinds  # bench/lib
+from clients import load_generator
 
 COUNTED = {"unit": ("units", "most_units_added"), "rle": ("entries", "most_entries_added")}
-FIRST_TEXT_ENTRIES = 1
 
 
 def layout(flags: "list[str]") -> "tuple[int, str, int]":
@@ -58,7 +59,7 @@ def refusal(config: dict, mix: dict, seconds: float) -> "str | None":
             f"--tpu-arena {arena} counts a row in {counted} and the generator "
             f"{mix['generator']!r} has no {bound}(mix, all_docs, seconds) to bound a document's growth in them"
         )
-    room = capacity - (int(config["doc_units"]) if arena == "unit" else FIRST_TEXT_ENTRIES)
+    room = capacity - kinds.load(kinds.name_of(config)).first_in_row(config, arena)
     grows = most_added(mix, int(config["driven_docs_per_plane"]) * planes, seconds)
     if grows > room:
         return f"a document could grow by {grows} {counted} and its row has room for {room}"

@@ -1,7 +1,8 @@
 """What a run starts from, made from the seed alone and by the benchmark's
-own code: each document's first text, the Yjs update that holds it, and the
-write-ahead log files that the server recovers it from. Also the reader of
-those files, for the comparison at the end of a run.
+own code: each document's first text and the Yjs update that holds it (the
+kind `text`, `bench/documents/text.py`), and the write-ahead log files that
+the server recovers a first state from. Also the reader of those files, for
+the comparison at the end of a run.
 
 The log's layout is the program's (`<wal_dir>/<quoted name>/<index>.wal`
 and the commit journal beside them, records `[u32 crc32][u32 length][u8
@@ -84,17 +85,17 @@ def doc_dir(wal_dir: str, name: str) -> str:
     return os.path.join(wal_dir, quote(name, safe=""))
 
 
-def write_wal(wal_dir: str, names: "list[str]", updates: "list[bytes]") -> int:
-    """One log segment per document, holding its first update. Returns the
-    bytes written."""
+def write_wal(wal_dir: str, names: "list[str]", updates: "list[list[bytes]]") -> int:
+    """One log segment per document, holding its first updates, a record
+    each. Returns the bytes written."""
     written = 0
-    for name, update in zip(names, updates):
+    for name, payloads in zip(names, updates):
         directory = doc_dir(wal_dir, name)
         os.mkdir(directory)
-        record = wal_record(update)
+        records = b"".join(wal_record(payload) for payload in payloads)
         with open(os.path.join(directory, "00000000.wal"), "wb") as fh:
-            fh.write(record)
-        written += len(record)
+            fh.write(records)
+        written += len(records)
     return written
 
 

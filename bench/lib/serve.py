@@ -169,12 +169,12 @@ class Served:
                 bucket.append(name)
         return [name for bucket in buckets for name in bucket]
 
-    async def write_log(self, names: "list[str]", texts: "list[str]", seed: int) -> int:
+    async def write_log(self, names: "list[str]", firsts: list, kind) -> int:
         """The write-ahead log as a crashed server would have left it: one
-        segment per document, holding the update that typed its first text.
-        Written from a few threads (the directory may be on a slow mount).
-        Returns the bytes written."""
-        updates = [seeded.text_update(seeded.first_client(seed, nth), text) for nth, text in enumerate(texts)]
+        segment per document, holding the updates that make its first state
+        (`kind.first_writes`). Written from a few threads (the directory may
+        be on a slow mount). Returns the bytes written."""
+        updates = [[update for _client, update in kind.first_writes(first)] for first in firsts]
         loop = asyncio.get_running_loop()
         shares = [(names[at::8], updates[at::8]) for at in range(8)]
         written = await asyncio.gather(
@@ -230,19 +230,35 @@ class Served:
             timeout=120,
         )
 
-    async def texts(self, names: "list[str]") -> "tuple[dict, dict]":
-        """({name: server document text}, {name: text read back from the
-        device arena}), read as the server's own serving paths read them:
-        off the loop and under the flush lock."""
-        loop = asyncio.get_running_loop()
-        server_texts, device_texts = {}, {}
+    async def views(self, names: "list[str]", kind) -> "tuple[dict, dict]":
+        """({name: the server document's view}, {name: the device's view}),
+        each as the document's kind reads it (`lib/kinds.py`)."""
+        server_views, device_views = {}, {}
         for name in names:
             document = self.server.hocuspocus.documents.get(name)
-            server_texts[name] = None if document is None else document.get_text("body").to_string()
-            plane = self.planes[self.plane_index_of(name)]
-            async with plane.flush_lock:
-                device_texts[name] = await loop.run_in_executor(None, plane.text, name)
-        return server_texts, device_texts
+            server_views[name] = None if document is None else kind.view(document)
+            device_views[name] = await kind.device_view(self, name)
+        return server_views, device_views
+
+    async def on_plane(self, name: str, read):
+        """`read(plane)` of the plane that serves `name`, as the server's own
+        serving paths read it: off the loop and under the flush lock."""
+        plane = self.planes[self.plane_index_of(name)]
+        async with plane.flush_lock:
+            return await asyncio.get_running_loop().run_in_executor(None, read, plane)
+
+    async def device_update(self, name: str) -> "bytes | None":
+        """The update the plane itself serves a joiner of `name` that brings
+        an empty state vector (`PlaneServing.encode_state_as_update`), or
+        None where the plane would leave the joiner to the CPU document. The
+        view of a document the plane cannot materialise (a tree) is read
+        from it by the kind's own reference."""
+        document = self.server.hocuspocus.documents.get(name)
+        plane = self.planes[self.plane_index_of(name)]
+        serving = next((serving for serving in self.runtime.servings() if serving.plane is plane), None)
+        if document is None or serving is None:
+            return None
+        return await self.on_plane(name, lambda _plane: serving.encode_state_as_update(name, document, None))
 
     @staticmethod
     def plane_delta(before: dict, after: dict) -> dict:

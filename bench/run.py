@@ -87,7 +87,9 @@ class Clients:
     """The client processes of a run (`lib/clients.py`), each with a share
     of the driven documents, and the lines exchanged with them."""
 
-    def __init__(self, run_dir: str, url: str, names: "list[str]", mix: dict, seed: int, seconds: float) -> None:
+    def __init__(
+        self, run_dir: str, url: str, names: "list[str]", mix: dict, document: str, seed: int, seconds: float
+    ) -> None:
         self.specs = []
         count = max(1, min(int(mix.get("client_processes", 1)), len(names)))
         for nth in range(count):
@@ -96,6 +98,7 @@ class Clients:
                 "generator": mix["generator"],
                 "url": url,
                 "mix": mix,
+                "document": document,
                 "seed": seed,
                 "seconds": seconds,
                 "grace_seconds": GRACE_SECONDS,
@@ -154,12 +157,12 @@ class Clients:
                 await process.wait()
 
 
-async def drive(args, config: dict, mix: dict, seconds: float, compiles) -> dict:
+async def drive(args, config: dict, kind, mix: dict, seconds: float, compiles) -> dict:
     """Set-up, the window, and everything read from the live system; the
     server and the client processes are gone when this returns."""
     import jax
 
-    import seeded
+    import kinds
     from serve import LoopClock, RunFailed, Served
 
     clock = time.monotonic  # CLOCK_MONOTONIC: the client processes read the same one
@@ -182,15 +185,11 @@ async def drive(args, config: dict, mix: dict, seconds: float, compiles) -> dict
         units = int(config["doc_units"])
         driven = served.pick_names(int(mix["docs_per_plane"]), args.seed, "bench")
         resident = served.pick_names(int(config["resident_docs_per_plane"]) - int(mix["docs_per_plane"]), args.seed, "rest")
-        texts = seeded.first_texts(args.seed, len(resident) + len(driven), units)
-        first = [
-            (seeded.first_client(args.seed, len(resident) + nth), text)
-            for nth, text in enumerate(texts[len(resident) :])
-        ]
+        firsts = kind.first_states(args.seed, len(resident) + len(driven), config)
         started = clock()
-        written = await served.write_log(resident + driven, texts, args.seed)
+        written = await served.write_log(resident + driven, firsts, kind)
         wrote_s = clock() - started
-        clients = Clients(run_dir, served.url, driven, mix, args.seed, seconds)
+        clients = Clients(run_dir, served.url, driven, mix, kinds.name_of(config), args.seed, seconds)
         await clients.start()  # they import while the server recovers its documents
         started = clock()
         await served.recover(resident + driven)
@@ -272,10 +271,10 @@ async def drive(args, config: dict, mix: dict, seconds: float, compiles) -> dict
             with open(line.split(None, 1)[1], "rb") as fh:
                 results.append(pickle.load(fh))
         await served.quiesce()
-        server_texts, device_texts = await served.texts(driven)
+        server_views, device_views = await served.views(driven, kind)
         logs = await served.logged(driven)
         sample = random.Random(args.seed ^ 0xA7E57).sample(range(len(resident)), min(RESIDENT_SAMPLE, len(resident)))
-        rest_server, rest_device = await served.texts([resident[i] for i in sample])
+        rest_server, rest_device = await served.views([resident[i] for i in sample], kind)
         by_doc: dict = {}
         for result in results:
             by_doc.update(result["clients"])
@@ -284,13 +283,15 @@ async def drive(args, config: dict, mix: dict, seconds: float, compiles) -> dict
             "docs": [
                 {
                     "clients": by_doc[doc],
-                    "server": server_texts[name],
-                    "device": device_texts[name],
+                    "server": server_views[name],
+                    "device": device_views[name],
                     "wal": logs[name],
                 }
                 for doc, name in enumerate(driven)
             ],
-            "resident": [(texts[i], rest_server[resident[i]], rest_device[resident[i]]) for i in sample],
+            "resident": [
+                (kind.first_view(firsts[i]), rest_server[resident[i]], rest_device[resident[i]]) for i in sample
+            ],
         }
         health = served.health(resident + driven, seen["counters"], after, compiled)
         rungs = served.rungs_between(seen["counters"], after)
@@ -313,7 +314,7 @@ async def drive(args, config: dict, mix: dict, seconds: float, compiles) -> dict
             "health": health,
             "compiled_in_window": compiled,
             "observed": observed,
-            "first": first,
+            "first": firsts[len(resident) :],
             "log": [entry for result in results for entry in result["log"]],
             "logs": [logs[name] for name in driven],
             "setup_s": opens_at - _STARTED,
@@ -328,6 +329,7 @@ async def drive(args, config: dict, mix: dict, seconds: float, compiles) -> dict
                 "open_loop": results[0]["open_loop"],
                 "offered": len(latency),
                 "late_s": late,
+                "latency_s": latency,
                 "loop_lag_s": lags,
                 "loop_asleep_s": asleep_s,
                 "gc_pause_s": pauses,
@@ -374,6 +376,7 @@ def main(argv=None) -> int:
     if refused:
         print(f"bench: {refused}", file=sys.stderr)
         return 2
+    kind = manifest.kind(config)
     with contextlib.suppress(ImportError, ValueError, OSError):
         import resource
 
@@ -413,7 +416,7 @@ def main(argv=None) -> int:
         # to stdout, which carries the result alone: its lines are formatted
         # as in a deployment and go nowhere, so that no pipe's reader paces the run.
         with open(os.devnull, "w") as nowhere, contextlib.redirect_stdout(nowhere):
-            run = asyncio.run(drive(args, config, mix, seconds, compiles))
+            run = asyncio.run(drive(args, config, kind, mix, seconds, compiles))
     except (RunFailed, TimeoutError) as error:
         print(f"bench: the run failed: {error}", file=sys.stderr)
         return EXIT_RUN_FAILED
@@ -438,14 +441,12 @@ def main(argv=None) -> int:
 
     checking = time.perf_counter()
     only_appends = set(mix["position_mix"]) == {"end"} and not mix["replace_share"]
-    reference = compare.merged(run["first"], run["log"])
-    compared = compare.compare(reference, run["observed"], run["first"], run["log"], only_appends)
-    controls = {
-        name: compare.compare(
-            reference, compare.as_observed(compare.merged(run["first"], run["log"], name), run["logs"], name)
-        )
-        for name in args.control
-    }
+    reference = compare.merged(run["first"], run["log"], kind=kind)
+    compared = compare.compare(reference, run["observed"], run["first"], run["log"], only_appends, kind=kind)
+    controls = {}
+    for name in args.control:
+        broken = compare.merged(run["first"], run["log"], name, kind=kind)
+        controls[name] = compare.compare(reference, compare.as_observed(broken, run["logs"], name, kind=kind), kind=kind)
     log(f"reference merged and compared in {time.perf_counter() - checking:.1f}s")
 
     readings = run["readings"]
@@ -458,6 +459,7 @@ def main(argv=None) -> int:
     }
     values = {
         "update_to_peer_p95_ms": (percentile(run["latency_s"], 0.95) or 0.0) * 1000.0,
+        "update_to_peer_p50_ms": (percentile(run["latency_s"], 0.5) or 0.0) * 1000.0,
         "updates_delivered_per_s": run["delivered_in_window"] / seconds,
         "setup_s": run["setup_s"],
     }

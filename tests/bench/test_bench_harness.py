@@ -27,7 +27,10 @@ import compare  # noqa: E402
 import roofline  # noqa: E402
 import seeded  # noqa: E402
 import tracereduce  # noqa: E402
-from manifest import Manifest  # noqa: E402
+from kinds import load as load_kind  # noqa: E402
+from manifest import Manifest, ManifestError  # noqa: E402
+
+TEXT = load_kind("text")
 
 RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device", "compared"]
 
@@ -58,6 +61,43 @@ def test_manifest_names_files_and_what_each_cell_reports():
             assert callable(manifest.reader(metric["name"]))
 
 
+MEDIAN_CELLS = [
+    name for name in Manifest().cells
+    if "update_to_peer_p95_ms" not in {m["name"] for m in Manifest().metrics_of(name, "end_to_end")}
+]
+
+
+def test_a_metric_without_a_list_goes_to_the_cells_that_report_what_it_moves():
+    manifest = Manifest()
+    assert MEDIAN_CELLS  # the host's stalls leave these cells' tail unbounded (PERF.md section 2)
+    universal = [m for m in manifest.data["per_layer"] if "workloads" not in m]
+    for name in manifest.cells:
+        end_to_end = {m["name"] for m in manifest.metrics_of(name, "end_to_end")}
+        reported = {m["name"] for m in manifest.metrics_of(name, "per_layer")}
+        for metric in universal:
+            assert (metric["name"] in reported) == (metric["moves"] in end_to_end), (name, metric["name"])
+
+
+@pytest.mark.parametrize("cell", MEDIAN_CELLS)
+def test_a_cell_that_reports_the_median_keeps_every_quantity_and_its_tail(cell):
+    manifest = Manifest()
+    assert {m["name"] for m in manifest.metrics_of(cell, "end_to_end")} == {"update_to_peer_p50_ms", "setup_s"}
+    per_layer = manifest.metrics_of(cell, "per_layer")
+    assert all(m["moves"] == "update_to_peer_p50_ms" and m["workloads"] == [cell] for m in per_layer)
+    # every quantity the other open-loop cells read, under a twin that moves the median
+    for metric in manifest.data["per_layer"]:
+        if "workloads" not in metric:
+            twin = manifest.reported_as(cell, metric["name"])
+            assert {k: v for k, v in twin.items() if k not in ("name", "moves", "workloads")} == {
+                k: v for k, v in metric.items() if k not in ("name", "moves")
+            }
+    tail = manifest.reported_as(cell, "update_to_peer_p95_ms")
+    assert tail["source"] == "host_clock" and tail["unit"] == "ms" and tail["better"] == "lower"
+    latency = [0.001 * (i % 100) for i in range(1000)]  # 0..99 ms, ten of each
+    assert manifest.reader(tail["name"])({"latency_s": latency}) == pytest.approx(94.0)
+    assert manifest.reader(tail["name"])({"latency_s": []}) is None
+
+
 def test_a_cell_a_configuration_and_a_metric_are_added_as_new_files_only(tmp_path):
     shutil.copytree(BENCH, tmp_path / "bench")
     data = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
@@ -67,8 +107,9 @@ def test_a_cell_a_configuration_and_a_metric_are_added_as_new_files_only(tmp_pat
         for path in (os.path.join(folder, f) for f in files)
     }
     config = json.load(open(tmp_path / "bench/configs/text-100k-10kb.json"))
-    config.update(name="text-later", driven_docs_per_plane=2)
+    config.update(name="text-later", driven_docs_per_plane=2, document="later")
     (tmp_path / "bench/configs/text-later.json").write_text(json.dumps(config))
+    (tmp_path / "bench/documents/later.py").write_text('VIEWS = "trees"\n')
     mix = json.load(open(tmp_path / "bench/traffic/typing-append.json"))
     mix["rate_updates_per_s"] = 7
     (tmp_path / "bench/traffic/typing-later.json").write_text(json.dumps(mix))
@@ -103,6 +144,8 @@ def test_a_cell_a_configuration_and_a_metric_are_added_as_new_files_only(tmp_pat
     manifest.check_names()
     cell = manifest.cell("later")
     assert manifest.config(cell["config"])["driven_docs_per_plane"] == 2
+    assert manifest.kind(manifest.config(cell["config"])).VIEWS == "trees"
+    assert manifest.kind(manifest.config("text-100k-10kb")).VIEWS == "texts"  # no "document": the kind text
     assert manifest.traffic(cell["traffic"])["rate_updates_per_s"] == 7
     generators = importlib.util.spec_from_file_location("later_clients", tmp_path / "bench/lib/clients.py")
     later_clients = importlib.util.module_from_spec(generators)
@@ -118,6 +161,12 @@ def test_a_cell_a_configuration_and_a_metric_are_added_as_new_files_only(tmp_pat
          ("/device:TPU:0", [("XLA Ops", [("fusion", ms, ms)])])], 0.01)
     assert manifest.reader("later_span_ms")({"trace": trace}) == pytest.approx(7.0)
     assert manifest.reader("later_span_ms")({"trace": {"span_seconds": {}}}) is None
+    # a configuration that names a kind with no file is refused by name
+    (tmp_path / "bench/configs/text-sooner.json").write_text(json.dumps({**config, "document": "sooner"}))
+    data["configs"].append({**data["configs"][0], "name": "text-sooner", "file": "bench/configs/text-sooner.json"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(data))
+    with pytest.raises(ManifestError, match="'text-sooner' names the document kind 'sooner', which has no file"):
+        Manifest(str(tmp_path)).check_names()
     assert all(open(path, "rb").read() == content for path, content in before.items())
 
 
@@ -136,7 +185,118 @@ def test_no_chip_is_a_failed_run_and_a_rehearsal_names_no_metric():
         assert metric["name"] not in rehearsal.stdout + rehearsal.stderr
     assert all(value <= limit for value, limit in result["compared"].values())
     assert "compared: device_texts_differing = 0 (limit 0)" in rehearsal.stderr
-    assert set(result["compared"]) == set(compare.LIMITS)
+    assert list(result["compared"]) == list(compare.limits(TEXT))
+    # every end-to-end metric of the cell is read, under no name
+    (numbers,) = [line for line in rehearsal.stderr.splitlines() if "rehearsal numbers, not metrics: " in line]
+    read = json.loads(numbers.split("rehearsal numbers, not metrics: ", 1)[1])
+    assert len(read) == len(Manifest().metrics_of("typing-append", "end_to_end"))
+
+
+# SHA-256 of the first updates and of the log that `Served.write_log` wrote
+# for the rehearsal sizes, before the harness read them through a kind
+TEXT_DIGESTS = {
+    ("text-1k-10clients", 0): (
+        "5acffa3aca6e715c6f18cab5ec6ab30a34b7393377c42cfdcce1481a3cd37a41",
+        "0341c039d278b15897d9e588a014ebdc11c5521bd307e3000d95851e0ae67469",
+    ),
+    ("text-1k-10clients", 1): (
+        "2fe9c0d170b1ae709fcfa8b3112239c59ee1590bd5e796f1b5588d65051d3eeb",
+        "2d536db90a5ed5bebc9bd13c260791511aa73be846783cda09c50e30145711d8",
+    ),
+    ("text-b4-paper-105k", 0): (
+        "5142cd91e94a05e005ea03ebdfe9c6af116f73d4dbd7f98311dfa53c10a07fa5",
+        "de0b155dcbcc0c8c184df41f59450a890847f6e1cb7e402db6a0ebe5a08f65bd",
+    ),
+    ("text-b4-paper-105k", 1): (
+        "cb7d62fd9cd8d07cfbd301348d5a0333f68207ef983be89319c948251c7a80e8",
+        "b991f607de2c09fbbc56c211d24a12ea5f5192c74b7cd63f84d2513c655e9b70",
+    ),
+}
+
+
+@pytest.mark.parametrize("config_name, seed", list(TEXT_DIGESTS))
+def test_the_text_kind_writes_the_same_first_updates_and_log(config_name, seed, tmp_path):
+    import asyncio
+    import hashlib
+    import types
+
+    from serve import Served
+
+    config = Manifest().config(config_name)
+    config = {**config, **config["rehearse"]}
+    flags = config["flags"]
+    docs = int(flags[flags.index("--tpu-shards") + 1]) * int(config["resident_docs_per_plane"])
+    firsts = TEXT.first_states(seed, docs, config)
+    writes = [update for first in firsts for _client, update in TEXT.first_writes(first)]
+    updates = hashlib.sha256(b"".join(len(u).to_bytes(4, "little") + u for u in writes))
+    names = [f"bench-{seed}-{i}" for i in range(docs)]
+    asyncio.run(Served.write_log(types.SimpleNamespace(wal_dir=str(tmp_path)), names, firsts, TEXT))
+    log = hashlib.sha256()
+    for folder, folders, files in sorted(os.walk(tmp_path)):
+        folders.sort()
+        for file in sorted(files):
+            path = os.path.join(folder, file)
+            log.update(os.path.relpath(path, tmp_path).encode() + b"\0" + open(path, "rb").read())
+    assert (updates.hexdigest(), log.hexdigest()) == TEXT_DIGESTS[(config_name, seed)]
+    assert [TEXT.first_view(f) for f in firsts] == seeded.first_texts(seed, docs, int(config["doc_units"]))
+
+
+def test_the_device_update_is_the_plane_s_joiner_serve(capsys):
+    """`Served.device_update` on a small server booted on the CPU: a tree
+    document (three paragraphs, one with a bold mark and an attribute), made
+    by the program's CPU crdt, and a text document of the kind `text`, both
+    recovered from the log. The plane cannot materialise the tree, and the
+    update it serves a joiner gives the server's fragment; on the text the
+    update, read by the kind's reference, agrees with `plane.text`."""
+    import asyncio
+    import types
+
+    from hocuspocus_tpu.crdt import Doc, YXmlElement, YXmlText, apply_update, encode_state_as_update
+    from serve import Served
+
+    tree = Doc()
+    tree.client_id = 7
+    fragment = tree.get_xml_fragment("prosemirror")
+    fragment.insert(0, [YXmlElement("paragraph") for _ in range(3)])
+    for paragraph, words in zip(fragment.to_array(), ("one", "two bold", "three")):
+        paragraph.insert(0, [YXmlText()])
+        paragraph.get(0).insert(0, words)
+    fragment.get(1).get(0).format(4, 4, {"bold": True})
+    fragment.get(1).set_attribute("textAlign", "center")
+    text = TEXT.first_states(5, 1, {"doc_units": 64})[0]
+
+    async def read() -> dict:
+        served = Served(["--tpu-serve", "--tpu-docs", "8", "--tpu-capacity", "256"])
+        try:
+            await served.boot()
+            # the tree's first state is its one update, written by client 7
+            tree_kind = types.SimpleNamespace(first_writes=list)
+            await served.write_log(["tree"], [[(7, encode_state_as_update(tree))]], tree_kind)
+            await served.write_log(["text"], [text], TEXT)
+            await served.recover(["tree", "text"])
+            return {
+                "server tree": served.server.hocuspocus.documents["tree"].get_xml_fragment("prosemirror").to_string(),
+                "plane tree": await served.on_plane("tree", lambda plane: plane.text("tree")),
+                "tree": await served.device_update("tree"),
+                "text": await served.device_update("text"),
+                "device text": await TEXT.device_view(served, "text"),
+                "none": await served.device_update("never-loaded"),
+            }
+        finally:
+            await served.close()
+
+    got = asyncio.run(read())
+    capsys.readouterr()
+    assert got["server tree"] == fragment.to_string()
+    assert '<paragraph textAlign="center">two <bold>bold</bold></paragraph>' in got["server tree"]
+    assert got["plane tree"] is None and got["none"] is None  # byte-served only
+    joiner = Doc()
+    apply_update(joiner, got["tree"])
+    assert joiner.get_xml_fragment("prosemirror").to_string() == got["server tree"]
+    assert got["device text"] == TEXT.first_view(text)
+    reference = TEXT.Reference()
+    reference.apply_updates([got["text"]])
+    assert TEXT.reference_view(reference) == got["device text"]
 
 
 def test_trace_reduction_gives_known_busy_and_idle():
@@ -244,7 +404,7 @@ def test_the_reference_reads_what_the_program_reads_and_each_control_does_not(se
     # the log as the program would leave it: the first update in the document's
     # own segment, the rest there too, and the newest also in the commit journal
     names = [f"doc/{n}" for n in range(len(first))]
-    seeded.write_wal(str(tmp_path), names, [seeded.text_update(*f) for f in first])
+    seeded.write_wal(str(tmp_path), names, [[seeded.text_update(*f)] for f in first])
     os.mkdir(tmp_path / "journal%")
     for doc, name in enumerate(names):
         mine = [entry[1] for entry in log if entry[0] == doc]
@@ -265,7 +425,7 @@ def test_the_reference_reads_what_the_program_reads_and_each_control_does_not(se
         assert judged[number][0] > 0
     # an update that does not say what its client meant is the encoder's fault, and is counted
     doc, update, client, run, cut = log[0]
-    assert compare.not_as_meant([(doc, update, client, run + "z", cut), (doc, update, client, run, cut + 1), log[1]]) == 2
+    assert TEXT.not_as_meant([(doc, update, client, run + "z", cut), (doc, update, client, run, cut + 1), log[1]]) == 2
 
 
 def _unchanged_state(monkeypatch, armed):
@@ -339,6 +499,14 @@ def _lost_log_record(monkeypatch, armed):
     monkeypatch.setattr(WalManager, "append", append)
 
 
+def _altered_answer_of_a_named_kind(monkeypatch, armed):
+    """`_altered_answer`, in a run whose configuration names its kind of
+    document, `"document": "text"`, where the others leave it to the default."""
+    config = Manifest.config
+    monkeypatch.setattr(Manifest, "config", lambda self, name: {**config(self, name), "document": "text"})
+    _altered_answer(monkeypatch, armed)
+
+
 @pytest.mark.parametrize(
     "fault, number",
     [
@@ -347,6 +515,7 @@ def _lost_log_record(monkeypatch, armed):
         (_half_the_batch, "updates_undelivered"),
         (_altered_answer, "device_texts_differing"),
         (_lost_log_record, "wal_texts_differing"),
+        (_altered_answer_of_a_named_kind, "device_texts_differing"),
     ],
 )
 def test_a_run_with_the_timed_path_broken_is_not_correct(fault, number, monkeypatch, capsys):

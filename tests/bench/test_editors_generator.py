@@ -22,6 +22,7 @@ sys.path.insert(0, os.path.join(BENCH, "lib"))
 import clients  # noqa: E402
 import compare  # noqa: E402
 import seeded  # noqa: E402
+from kinds import load as load_kind  # noqa: E402
 from manifest import Manifest  # noqa: E402
 from reference import decode_update  # noqa: E402
 
@@ -112,7 +113,7 @@ class Wired:
 
         self.apply_update = apply_update
         spec = {
-            "mix": mix, "url": "", "seed": seed, "seconds": 1.0, "all_docs": 1,
+            "mix": mix, "document": "text", "url": "", "seed": seed, "seconds": 1.0, "all_docs": 1,
             "clients_per_doc": 2, "writers_per_doc": 1, "docs": [{"index": 0, "name": "d"}],
         }
         self.generator = generator = editors.Generator(spec)
@@ -142,7 +143,7 @@ def test_logged_updates_say_one_unit_by_their_own_client(editors, mix):
     for nth in range(400):
         generator.send(0, 0, float(nth))
     log = generator.log
-    assert len(log) == 400 and compare.not_as_meant(log) == 0
+    assert len(log) == 400 and load_kind("text").not_as_meant(log) == 0
     writer = generator.client_ids[0][0]
     kinds = set()
     for _doc, update, client, run, cut in log:
@@ -180,7 +181,7 @@ def test_a_delete_counts_as_applied_when_the_peer_holds_its_tombstone(editors, m
 
 
 def test_editors_take_one_writer_a_document(editors, mix):
-    spec = {"mix": mix, "url": "", "seed": 1, "seconds": 1.0, "all_docs": 1, "clients_per_doc": 3,
+    spec = {"mix": mix, "document": "text", "url": "", "seed": 1, "seconds": 1.0, "all_docs": 1, "clients_per_doc": 3,
             "writers_per_doc": 2, "docs": []}
     with pytest.raises(ValueError, match="one writer a document"):
         editors.Generator(spec)

@@ -109,7 +109,7 @@ def test_every_cell_reports_them(manifest, name, layer):
     }
     manifest.check_names()
     for cell in manifest.cells:
-        assert name in {m["name"] for m in manifest.metrics_of(cell, "per_layer")}
+        assert_reported(manifest, cell, entry)
 
 
 def test_device_idle_split_by_loop_stage_sums_to_the_idle_time():
@@ -138,3 +138,14 @@ def test_device_idle_split_by_loop_stage_sums_to_the_idle_time():
     assert idle["plane.flush_turn"] == pytest.approx(0.003)
     assert idle["bench.loop_asleep"] == pytest.approx(0.020)
     assert "connection.dispatch" not in idle  # a child names no gap: its stage does
+
+
+def assert_reported(manifest, cell, entry):
+    """The cell reports the quantity: under the entry itself, or where it
+    reports another end-to-end metric than the entry moves, under its twin
+    that lists the cell and moves what the cell reports."""
+    reported = manifest.reported_as(cell, entry["name"])
+    assert reported["moves"] in {m["name"] for m in manifest.metrics_of(cell, "end_to_end")}
+    same = {key: value for key, value in entry.items() if key not in ("name", "moves")}
+    assert {key: value for key, value in reported.items() if key not in ("name", "moves", "workloads")} == same
+    assert reported["name"] == entry["name"] or reported["workloads"] == [cell]

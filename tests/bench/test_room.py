@@ -24,6 +24,7 @@ sys.path.insert(1, ROOT)
 import clients  # noqa: E402
 import room  # noqa: E402
 import seeded  # noqa: E402
+from kinds import load as load_kind  # noqa: E402
 from manifest import Manifest  # noqa: E402
 
 PAPER = "text-b4-paper-105k"
@@ -162,7 +163,7 @@ def hottest_document(generator_name: str, mix: dict, docs: int, seconds: float, 
     hottest = max(range(docs), key=rates.__getitem__)
     events = writers.open_schedule(mix, docs, [hottest], seconds, seed)
     spec = {
-        "mix": {**mix, "doc_units": units}, "url": "", "seed": seed, "seconds": seconds, "all_docs": docs,
+        "mix": {**mix, "doc_units": units}, "document": "text", "url": "", "seed": seed, "seconds": seconds, "all_docs": docs,
         "clients_per_doc": 1, "writers_per_doc": 1, "docs": [{"index": hottest, "name": "d"}],
     }
     driven = generator.Generator(spec)
@@ -197,8 +198,9 @@ def test_a_row_of_the_first_text_s_entry_and_the_bound_never_overflows(manifest,
     mix = {**manifest.traffic(cell), "rate_updates_per_s": 48, "warmup_seconds": 1, **mix_change}
     first, updates, bound = hottest_document(generator, mix, 6, 3.0, seed, 1536)
     assert 40 <= len(updates) <= bound  # the hottest of six documents: three times the mean rate, for 4 s
-    entries, overflow = entries_after([first, *updates], room.FIRST_TEXT_ENTRIES + bound)
-    assert not overflow and len(updates) // 2 < entries - room.FIRST_TEXT_ENTRIES <= bound
+    first_entries = load_kind("text").first_in_row({"doc_units": 1536}, "rle")
+    entries, overflow = entries_after([first, *updates], first_entries + bound)
+    assert not overflow and len(updates) // 2 < entries - first_entries <= bound
     # and the kernel does flag a row that is too short for what the document took
     assert entries_after([first, *updates], entries - 1)[1]
 

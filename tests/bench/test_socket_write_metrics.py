@@ -52,7 +52,7 @@ def test_every_cell_reports_them_under_the_loop_layer(manifest, name, better):
     }
     manifest.check_names()
     for cell in manifest.cells:
-        assert name in {m["name"] for m in manifest.metrics_of(cell, "per_layer")}
+        assert_reported(manifest, cell, entry)
 
 
 async def test_the_program_opens_the_spans_the_readers_read():
@@ -84,3 +84,14 @@ async def test_the_program_opens_the_spans_the_readers_read():
         tracer.enabled = was
         tracer.clear()  # the ring is the process's: leave it as found for the next test
         transport.abort()
+
+
+def assert_reported(manifest, cell, entry):
+    """The cell reports the quantity: under the entry itself, or where it
+    reports another end-to-end metric than the entry moves, under its twin
+    that lists the cell and moves what the cell reports."""
+    reported = manifest.reported_as(cell, entry["name"])
+    assert reported["moves"] in {m["name"] for m in manifest.metrics_of(cell, "end_to_end")}
+    same = {key: value for key, value in entry.items() if key not in ("name", "moves")}
+    assert {key: value for key, value in reported.items() if key not in ("name", "moves", "workloads")} == same
+    assert reported["name"] == entry["name"] or reported["workloads"] == [cell]

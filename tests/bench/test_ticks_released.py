@@ -44,7 +44,7 @@ def test_every_cell_reports_it_under_the_log_layer(manifest):
     }
     manifest.check_names()
     for cell in manifest.cells:
-        assert METRIC in {m["name"] for m in manifest.metrics_of(cell, "per_layer")}
+        assert_reported(manifest, cell, entry)
 
 
 def test_the_log_keeps_the_counter_the_reader_reads(tmp_path):
@@ -55,3 +55,14 @@ def test_the_log_keeps_the_counter_the_reader_reads(tmp_path):
 
     stats = WalManager(str(tmp_path)).stats
     assert stats["ticks_released"] == 0 and stats["commit_batches"] == 0
+
+
+def assert_reported(manifest, cell, entry):
+    """The cell reports the quantity: under the entry itself, or where it
+    reports another end-to-end metric than the entry moves, under its twin
+    that lists the cell and moves what the cell reports."""
+    reported = manifest.reported_as(cell, entry["name"])
+    assert reported["moves"] in {m["name"] for m in manifest.metrics_of(cell, "end_to_end")}
+    same = {key: value for key, value in entry.items() if key not in ("name", "moves")}
+    assert {key: value for key, value in reported.items() if key not in ("name", "moves", "workloads")} == same
+    assert reported["name"] == entry["name"] or reported["workloads"] == [cell]

@@ -40,16 +40,20 @@ def test_the_reader_on_a_hand_made_run(manifest, name, wal_delta, expected):
 
 
 def test_every_cell_reports_it_under_the_log_layer(manifest):
-    """One counter, two entries: a cell that reports a per-layer metric has to
-    report the end-to-end metric it moves, so the share moves the throughput in
-    the closed loop and the tail in the open loops, read by the same file."""
+    """One counter, three entries: a cell that reports a per-layer metric has
+    to report the end-to-end metric it moves, so the share moves the throughput
+    in the closed loop, the tail in the open loops that bound it and the median
+    in `typing-append`, read by the same file."""
     entries = {m["name"]: m for m in manifest.data["per_layer"] if m["name"].split(".")[0] == METRIC}
     common = {"unit": "%", "better": "higher", "source": "program_counter", "layer": "write-ahead log"}
     assert entries == {
         METRIC: {"name": METRIC, **common, "moves": "updates_delivered_per_s", "workloads": ["conflict-midinsert"]},
         METRIC + ".open": {
             "name": METRIC + ".open", **common, "moves": "update_to_peer_p95_ms",
-            "workloads": ["typing-append", "cells4-typing", "paper-cursor-edit", "paper-cursor-edit-rle"],
+            "workloads": ["cells4-typing", "paper-cursor-edit", "paper-cursor-edit-rle"],
+        },
+        METRIC + ".typing": {
+            "name": METRIC + ".typing", **common, "moves": "update_to_peer_p50_ms", "workloads": ["typing-append"],
         },
     }
     manifest.check_names()
