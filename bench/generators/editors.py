@@ -39,6 +39,10 @@ from clients import load_generator  # bench/lib, on the path of every process th
 
 writers = load_generator("writers")
 
+# the open loop's schedule, which this generator's runs follow, is writers' own
+open_schedule = writers.open_schedule
+doc_rates = writers.doc_rates
+
 
 def most_units_added(mix: dict, all_docs: int, seconds: float) -> int:
     """The most units one document can grow by in a run: one unit an update,

@@ -440,7 +440,9 @@ def main(argv=None) -> int:
     import compare
 
     checking = time.perf_counter()
-    only_appends = set(mix["position_mix"]) == {"end"} and not mix["replace_share"]
+    # a mix without `position_mix` is not one of pure appends; one without
+    # `replace_share` types over nothing
+    only_appends = set(mix.get("position_mix", ())) == {"end"} and not mix.get("replace_share")
     reference = compare.merged(run["first"], run["log"], kind=kind)
     compared = compare.compare(reference, run["observed"], run["first"], run["log"], only_appends, kind=kind)
     controls = {}

@@ -217,12 +217,15 @@ def test_the_cell_its_configuration_and_its_metrics_as_the_manifest_has_them(man
     assert reported["slow_path_mid_row_share"]["layer"] == "merge plane batching"
     assert reported["integrate_device_ms_per_batch"]["layer"] == "kernels"
     for name in split + ["slow_path_mid_row_share", "integrate_device_ms_per_batch"]:
-        assert reported[name]["workloads"] == [CELL] and reported[name]["moves"] == "update_to_peer_p95_ms"
+        assert reported[name]["workloads"] == [CELL] and reported[name]["moves"] == "update_to_peer_p50_ms"
     for other in ("typing-append", "conflict-midinsert", "cells4-typing"):  # the accepted cells report none of them
         assert not set(split) & {m["name"] for m in manifest.metrics_of(other, "per_layer")}
         assert "slow_path_mid_row_share" not in {m["name"] for m in manifest.metrics_of(other, "per_layer")}
-    assert {m["name"] for m in manifest.metrics_of(CELL, "end_to_end")} == {"update_to_peer_p95_ms", "setup_s"}
-    assert {"fast_path_share", "device_idle_share", "loop_apply_share", "executor_flush_share"} <= set(reported)
+    # judged on the median: the host's stalls leave its tail unbounded (PERF.md section 2); the tail is per layer
+    assert {m["name"] for m in manifest.metrics_of(CELL, "end_to_end")} == {"update_to_peer_p50_ms", "setup_s"}
+    assert "update_to_peer_p95_ms.b4" in reported
+    for quantity in ("fast_path_share", "device_idle_share", "loop_apply_share", "executor_flush_share"):
+        assert manifest.reported_as(CELL, quantity)["name"] == quantity + ".b4"  # the twin that moves the median
 
 
 def test_the_mid_row_share_reads_its_counter_and_none_without_it(manifest):

@@ -1,5 +1,6 @@
-"""The open loop's schedule (bench/generators/writers.py `open_schedule`,
-`doc_rates`) for every open-loop cell of BENCHMARK.json, at the cell's own
+"""The open loop's schedule for every open-loop cell of BENCHMARK.json, as the
+generator its mix names makes it (`open_schedule`, `doc_rates`: those of
+bench/generators/writers.py, which `editors` re-exports), at the cell's own
 size: every seed offers the same due times and the same numbers of updates,
 deals them to the documents in another order, and loads each plane alike."""
 
@@ -38,8 +39,8 @@ def cell_mix(name: str) -> "tuple[dict, int, int]":
 @pytest.mark.parametrize("cell", OPEN_CELLS)
 def test_every_seed_offers_the_same_due_times(cell):
     mix, docs, _planes = cell_mix(cell)
-    writers = clients.load_generator("writers")
-    schedules = [writers.open_schedule(mix, docs, list(range(docs)), SECONDS, seed) for seed in SEEDS]
+    generator = clients.load_generator(mix["generator"])
+    schedules = [generator.open_schedule(mix, docs, list(range(docs)), SECONDS, seed) for seed in SEEDS]
     dues = [[due for due, _doc in events] for events in schedules]
     assert dues[0] == sorted(dues[0]) and all(d == dues[0] for d in dues)
     assert -mix["warmup_seconds"] <= dues[0][0] and dues[0][-1] < SECONDS
@@ -49,17 +50,17 @@ def test_every_seed_offers_the_same_due_times(cell):
     assert owners[0] != owners[1] != owners[2]
     # a client process that drives some of the documents sends just their share
     mine = list(range(0, docs, 3))
-    assert writers.open_schedule(mix, docs, mine, SECONDS, SEEDS[1]) == [e for e in schedules[1] if e[1] in set(mine)]
+    assert generator.open_schedule(mix, docs, mine, SECONDS, SEEDS[1]) == [e for e in schedules[1] if e[1] in set(mine)]
 
 
 @pytest.mark.parametrize("cell", OPEN_CELLS)
 def test_each_plane_is_loaded_alike(cell):
     mix, docs, planes = cell_mix(cell)
-    writers = clients.load_generator("writers")
+    generator = clients.load_generator(mix["generator"])
     per_plane = docs // planes
     loads, dealt = [], []
     for seed in SEEDS:
-        rates = writers.doc_rates(mix, docs, seed)
+        rates = generator.doc_rates(mix, docs, seed)
         dealt.append(rates)
         loads.append([sum(rates[p * per_plane : (p + 1) * per_plane]) for p in range(planes)])
         assert sum(loads[-1]) == pytest.approx(mix["rate_updates_per_s"])
@@ -68,4 +69,4 @@ def test_each_plane_is_loaded_alike(cell):
     assert all(sorted(each) == pytest.approx(sorted(loads[0])) for each in loads)
     assert dealt[0] != dealt[1] != dealt[2]
     ungrouped = {key: value for key, value in mix.items() if key != "docs_per_plane"}
-    assert sorted(writers.doc_rates(ungrouped, docs, SEEDS[0])) == pytest.approx(sorted(writers.doc_rates(mix, docs, SEEDS[0])))
+    assert sorted(generator.doc_rates(ungrouped, docs, SEEDS[0])) == pytest.approx(sorted(generator.doc_rates(mix, docs, SEEDS[0])))

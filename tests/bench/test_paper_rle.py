@@ -84,7 +84,7 @@ def test_the_cell_differs_from_its_twin_by_the_configuration_alone(manifest):
     }
     for metric in manifest.data["per_layer"]:
         if metric["name"] in ours - theirs:
-            assert metric["workloads"] == [CELL] and metric["moves"] == "update_to_peer_p95_ms"
+            assert metric["workloads"] == [CELL] and metric["moves"] == "update_to_peer_p50_ms"
 
 
 def test_the_cell_rehearses_correct_and_both_controls_do_not(tmp_path):
